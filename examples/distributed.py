@@ -22,7 +22,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 # Off-TPU demo: 4 virtual CPU devices per process. Must run before any
-# jax device query; harmless if a TPU plugin owns the platform already.
+# jax device query.
 if os.environ.get("MPI_TPU_DEMO_CPU", "1") == "1":
     from mpi_tpu.utils.platform import force_platform
 

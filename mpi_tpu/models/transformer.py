@@ -69,7 +69,8 @@ class TransformerConfig:
     # (zigzag layout + flash chunks) | "ulysses" (all-to-all head/seq
     # reshard, parallel.ulysses) | "ulysses_flash" (same, Pallas kernel
     # per head group). The ring/zigzag/ulysses family needs a mesh
-    # with 'sp'.
+    # with 'sp'; "flash" on a mesh runs per (dp, tp) shard and refuses
+    # sp > 1.
     attention_impl: str = "dense"
     # Decode-time (KV-cache) attention: "dense" (jnp einsum chain, the
     # oracle) | "flash" (Pallas flash-decode kernel — one VMEM pass
@@ -274,7 +275,22 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     if impl == "flash":
         from ..ops import flash_attention
 
-        ctx = flash_attention(q, k, v, cfg.causal)
+        if mesh is None or mesh.size == 1:
+            ctx = flash_attention(q, k, v, cfg.causal)
+        else:
+            # GSPMD cannot partition a Mosaic kernel, so on a real mesh
+            # the kernel runs per shard like the ring/ulysses family:
+            # batch over dp, heads (q and grouped kv alike) over tp.
+            if mesh.shape.get("sp", 1) > 1:
+                raise ValueError(
+                    "attention_impl='flash' attends within one device's "
+                    "sequence; a mesh with sp > 1 needs ring_flash|"
+                    "zigzag_flash|ulysses_flash")
+            spec = sanitize_spec(P("dp", None, "tp", None), mesh)
+            ctx = jax.shard_map(
+                lambda q_, k_, v_: flash_attention(q_, k_, v_, cfg.causal),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False)(q, k, v)
     elif impl == "blockwise":
         from ..ops import blockwise_attention
 

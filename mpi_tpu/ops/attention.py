@@ -35,6 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "flash_attention_with_lse", "flash_chunk_bwd",
@@ -318,14 +320,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - jax always ships pallas here
-    _HAVE_PALLAS = False
-
-
 def _pick_block(n: int, preferred: int) -> int:
     """Largest power-of-two ≤ preferred that divides n (n itself if none —
     one full block beats a degenerate 1-element grid)."""
@@ -416,39 +410,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Flash attention: Pallas TPU kernels, forward and backward.
 
     ``interpret=None`` auto-selects interpreter mode off-TPU so tests run
-    on CPU against the same kernel code. Falls back to
-    :func:`blockwise_attention` when Pallas is unavailable. Block sizes
-    of ``None`` take the process-wide defaults
-    (:func:`flash_block_defaults` — 256x512 from a v5e sweep unless the
+    on CPU against the same kernel code. Block sizes of ``None`` take
+    the process-wide defaults (:func:`flash_block_defaults` — 256x512 from a v5e sweep unless the
     :mod:`mpi_tpu.ops.autotune` sweep picked better for this shape);
     :func:`_pick_block` shrinks them to fit short sequences.
     """
     itp = _should_interpret() if interpret is None else interpret
-    if not _HAVE_PALLAS:  # pragma: no cover
-        _, bk = _resolve_blocks(block_q, block_k)
-        k, v = _expand_grouped_kv(q, k, v)
-        return blockwise_attention(q, k, v, causal=causal, block_k=bk)
     # Same kernel as the residual-saving forward; the (b*h, 1, s) lse
     # output is dead here and DCE'd by XLA.
     return _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k,
                                  itp)[0]
-
-
-def _expand_grouped_kv(q, k, v):
-    """Repeat grouped (GQA) kv heads for paths without native grouped
-    support (the no-Pallas blockwise fallback only). Enforces the same
-    divisibility contract as :func:`_gqa_layout` so all builds raise
-    the same error."""
-    h, hk = q.shape[2], k.shape[2]
-    if h % hk or v.shape[2] != hk:
-        raise ValueError(
-            f"mpi_tpu: flash attention kv heads ({hk}/{v.shape[2]}) must "
-            f"divide query heads ({h})")
-    group = h // hk
-    if group > 1:
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-    return k, v
 
 
 def _gqa_layout(q, k, v):
@@ -650,26 +621,12 @@ def flash_chunk_bwd(q, k, v, out, lse, g, causal: bool = False,
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
     itp = _should_interpret() if interpret is None else interpret
-    if not _HAVE_PALLAS:  # pragma: no cover
-        ke, ve = _expand_grouped_kv(q, k, v)
-        out = blockwise_attention(q, ke, ve, causal=causal,
-                                  block_k=_resolve_blocks(None, block_k)[1])
-        return out, (q, k, v, None, None)
     out, lse = _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, itp)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    if out is None:  # pragma: no cover - pallas-less fallback
-        def ref(q_, k_, v_):
-            ke, ve = _expand_grouped_kv(q_, k_, v_)
-            return blockwise_attention(
-                q_, ke, ve, causal=causal,
-                block_k=_resolve_blocks(None, block_k)[1])
-
-        _, vjp = jax.vjp(ref, q, k, v)
-        return vjp(g)
     itp = _should_interpret() if interpret is None else interpret
     return _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q,
                              block_k, itp)

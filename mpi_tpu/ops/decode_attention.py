@@ -25,10 +25,8 @@ shapes stay static.
 Used by the decode path when ``TransformerConfig.decode_attention =
 "flash"`` (models/generate.py); the dense jnp path remains the default
 and the correctness oracle. Off-TPU the kernel runs in interpreter
-mode, so tests cover it everywhere; on builds without pallas it
-degrades to an equivalent jnp fold (same numerics contract as
-``attention.flash_attention``'s fallback). No reference analogue
-(btracey/mpi has no models).
+mode, so tests cover it everywhere. No reference analogue (btracey/mpi
+has no models).
 """
 
 from __future__ import annotations
@@ -40,15 +38,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF, _pick_block, _should_interpret
-
-try:  # pallas ships with jax; guard exotic builds like attention.py does
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - jax always ships pallas here
-    _HAVE_PALLAS = False
 
 __all__ = ["flash_decode_attention"]
 
@@ -108,30 +101,6 @@ def _decode_kernel(n_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0, 0] = m_scr[:, 0] + jnp.log(l)
 
 
-def _jnp_fallback(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                  n_valid: jax.Array, group: int):
-    """Pallas-less equivalent (also the shape-semantics oracle).
-    Returns (out, lse) like the kernel's with_lse mode. For a fully
-    masked row (n_valid < 0, the cache-parallel empty-shard case) the
-    ctx is an artifact of exp(-inf - -inf) but its lse is ~-1e30, so
-    the shard merge weights it to zero — same contract as the kernel's
-    all-blocks-skipped zero output."""
-    b, h, hd = q.shape
-    kv = k_cache.shape[2]
-    qg = q.reshape(b, kv, group, hd)
-    scale = 1.0 / math.sqrt(hd)
-    logits = jnp.einsum("bKgk,btKk->bKgt", qg, k_cache) * scale
-    col = lax.broadcasted_iota(jnp.int32, logits.shape, 3)
-    logits = jnp.where(col <= n_valid, logits, NEG_INF).astype(jnp.float32)
-    m = jnp.max(logits, axis=-1)
-    l = jnp.maximum(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1),
-                    1e-30)
-    probs = jnp.exp(logits - m[..., None]) / l[..., None]
-    ctx = jnp.einsum("bKgt,btKk->bKgk", probs.astype(q.dtype), v_cache)
-    lse = (m + jnp.log(l)).reshape(b, h)
-    return ctx.reshape(b, h, hd), lse
-
-
 def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
                            v_cache: jax.Array, n_valid: jax.Array,
                            block_k: int = 512,
@@ -153,10 +122,6 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
         raise ValueError(f"mpi_tpu: n_heads {h} not divisible by "
                          f"kv_heads {kv}")
     group = h // kv
-    if not _HAVE_PALLAS:
-        out, lse = _jnp_fallback(q, k_cache, v_cache,
-                                 jnp.asarray(n_valid, jnp.int32), group)
-        return (out, lse) if with_lse else out
     rows = max(group, _MIN_ROWS)
     itp = _should_interpret() if interpret is None else interpret
     # A divisor block size (like the flash kernel's _pick_block) keeps

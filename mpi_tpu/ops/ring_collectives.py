@@ -24,6 +24,13 @@ Both are per-device bodies to be traced inside ``shard_map`` over the
 ring axis; ``*_sharded`` wrappers handle that. On non-TPU backends the
 kernels run in the Pallas interpreter (exact same code path the tests
 exercise on the virtual CPU mesh).
+
+Status: the v5e compiler accepts both kernels
+(tests/test_tpu_compile.py); neither has executed on hardware. They
+take no start barrier (``pltpu.get_barrier_semaphore``, which is what
+``CompilerParams(collective_id=...)`` exists for) — whether a device may
+DMA into a neighbour that has not yet entered the kernel without one is
+open (ROADMAP S4).
 """
 
 from __future__ import annotations
@@ -98,8 +105,7 @@ def ring_allgather(x: jax.Array, axis_name: str = "rank",
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=pltpu.CompilerParams(has_side_effects=True,
-                                             collective_id=0),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=itp,
     )(x)
 
@@ -169,8 +175,7 @@ def ring_allreduce(x: jax.Array, axis_name: str = "rank", op: str = "sum",
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=pltpu.CompilerParams(has_side_effects=True,
-                                             collective_id=1),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=itp,
     )(x)
 

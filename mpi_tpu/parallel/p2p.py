@@ -192,11 +192,12 @@ def _sendrecv_kernel(x_ref, out_ref, send_sem, recv_sem, *,
 
 def pallas_sendrecv(x: jax.Array, perm: Sequence[Pair],
                     axis_name: str = RANK_AXIS,
-                    interpret: Optional[bool] = None,
-                    collective_id: int = 2) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Per-device body: the static pattern ``perm`` executed as remote
     DMA pushes. Semantics match :func:`exchange` (non-receivers get
-    zeros). Call inside ``shard_map`` over ``axis_name``."""
+    zeros). Call inside ``shard_map`` over ``axis_name``. Compiles for
+    the v5e (tests/test_tpu_compile.py), never executed on hardware;
+    like the ring kernels it takes no start barrier (ROADMAP S4)."""
     perm = tuple(_check_pattern(perm))
     itp = _should_interpret() if interpret is None else interpret
     kernel = functools.partial(_sendrecv_kernel, perm=perm,
@@ -208,8 +209,7 @@ def pallas_sendrecv(x: jax.Array, perm: Sequence[Pair],
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=pltpu.CompilerParams(has_side_effects=True,
-                                             collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=itp,
     )(x)
 

@@ -55,7 +55,7 @@ def _run_cli(args, timeout=90):
 @pytest.mark.integration
 class TestEndToEnd:
     def test_helloworld_4_ranks(self):
-        # BASELINE.md config 1: helloworld, 4 ranks, TCP backend, CPU only.
+        # BASELINE.json config 1: helloworld, 4 ranks, TCP backend, CPU only.
         port = _free_port_block(4)
         res = _run_cli(["--port-base", str(port), "--timeout", "30",
                         "4", "examples/helloworld.py"])

