@@ -1,0 +1,16 @@
+"""Model FLOP/s utilization of the traced steps: operations the forward
+and backward passes require (``flops.train_flops_per_step``; recomputed
+operations do not count) x steps / host time of those steps / the
+published bf16 peak of the chips used."""
+
+import flops
+
+
+def read(run):
+    rec, peaks = run["record"], run["peaks"]
+    stamps = rec.get("step_stamps")
+    if not stamps or len(stamps) < 2 or not peaks:
+        return None
+    need = flops.train_flops_per_step(rec["model"], rec["batch"], rec["seq"])
+    rate = need * (len(stamps) - 1) / (stamps[-1] - stamps[0])
+    return 100.0 * rate / (peaks["bf16_tflops"] * 1e12 * run["chips"])
