@@ -418,19 +418,17 @@ def send(data: Any, dest: int, tag: int) -> None:
     from .observe import flight
     from .utils import trace
 
-    tracing = trace.enabled()
-    if not tracing and not flight.enabled:
-        return impl.send(data, dest, tag)
     nbytes = _payload_bytes(data)
+    # The span is entered always: it is also a host event of whatever
+    # jax.profiler trace is open (utils/trace.py), and nearly free when
+    # nothing listens. Counters and the recorder stay behind their flags.
     tok = flight.begin("send", dest, tag, nbytes) if flight.enabled \
         else None
     try:
-        if tracing:
+        if trace.enabled():
             trace.count("comm.send.calls")
             trace.count("comm.send.bytes", nbytes)
-            with trace.span("mpi.send", dest=dest, tag=tag, bytes=nbytes):
-                impl.send(data, dest, tag)
-        else:
+        with trace.span("mpi.send", dest=dest, tag=tag, bytes=nbytes):
             impl.send(data, dest, tag)
     except BaseException as exc:
         if tok is not None:
@@ -453,18 +451,13 @@ def receive(source: int, tag: int, out: Optional[Any] = None) -> Any:
     from .observe import flight
     from .utils import trace
 
-    tracing = trace.enabled()
-    if not tracing and not flight.enabled:
-        return impl.receive(source, tag, out=out)
     tok = flight.begin("receive", source, tag) if flight.enabled else None
     try:
-        if tracing:
-            with trace.span("mpi.receive", source=source, tag=tag):
-                result = impl.receive(source, tag, out=out)
+        with trace.span("mpi.receive", source=source, tag=tag):
+            result = impl.receive(source, tag, out=out)
+        if trace.enabled():
             trace.count("comm.receive.calls")
             trace.count("comm.receive.bytes", _payload_bytes(result))
-        else:
-            result = impl.receive(source, tag, out=out)
     except BaseException as exc:
         if tok is not None:
             flight.end(tok, f"error:{type(exc).__name__}")
@@ -720,8 +713,6 @@ def sendrecv(data: Any, dest: int, source: int, tag: int,
     from .utils import trace
 
     tracing = trace.enabled()
-    if not tracing and not flight.enabled:
-        return exchange(impl, data, dest, source, tag, out=out)
     tok = flight.begin("sendrecv", dest, tag, _payload_bytes(data)) \
         if flight.enabled else None
     try:
@@ -733,12 +724,10 @@ def sendrecv(data: Any, dest: int, source: int, tag: int,
             trace.count("comm.send.calls")
             trace.count("comm.send.bytes", _payload_bytes(data))
             trace.count("comm.receive.calls")
-            with trace.span("mpi.sendrecv", dest=dest, source=source,
-                            tag=tag):
-                result = exchange(impl, data, dest, source, tag, out=out)
-            trace.count("comm.receive.bytes", _payload_bytes(result))
-        else:
+        with trace.span("mpi.sendrecv", dest=dest, source=source, tag=tag):
             result = exchange(impl, data, dest, source, tag, out=out)
+        if tracing:
+            trace.count("comm.receive.bytes", _payload_bytes(result))
     except BaseException as exc:
         if tok is not None:
             flight.end(tok, f"error:{type(exc).__name__}")
@@ -789,15 +778,14 @@ def _collective(name: str, *args: Any, **kwargs: Any) -> Any:
     from .utils import trace
 
     tracing = trace.enabled()
-    if not tracing and not flight.enabled:
-        return call()
-    # Straggler substrate: every rank stamps its local arrival at this
-    # collective; the in-process drivers report exact skew, and the
-    # finalize-time merge computes cross-process skew from the
-    # clock-aligned stamps (mpi_tpu.observe.collect).
-    from .observe import metrics as _metrics
+    if tracing or flight.enabled:
+        # Straggler substrate: every rank stamps its local arrival at
+        # this collective; the in-process drivers report exact skew, and
+        # the finalize-time merge computes cross-process skew from the
+        # clock-aligned stamps (mpi_tpu.observe.collect).
+        from .observe import metrics as _metrics
 
-    _metrics.note_collective_entry(name)
+        _metrics.note_collective_entry(name)
     tok = flight.begin(name, -1, -1,
                        _payload_bytes(args[0]) if args else 0) \
         if flight.enabled else None
@@ -806,9 +794,7 @@ def _collective(name: str, *args: Any, **kwargs: Any) -> Any:
             trace.count(f"comm.{name}.calls")
             if args:
                 trace.count(f"comm.{name}.bytes", _payload_bytes(args[0]))
-            with trace.span(f"mpi.{name}"):
-                result = call()
-        else:
+        with trace.span(f"mpi.{name}"):
             result = call()
     except BaseException as exc:
         if tok is not None:

@@ -45,7 +45,8 @@ if TYPE_CHECKING:
 
 import numpy as np
 
-from ..api import MpiError
+from ..api import MpiError, _payload_bytes
+from ..utils import trace
 from .rendezvous import ReceiveCancelled, Rendezvous
 
 __all__ = ["XlaNetwork", "run_spmd"]
@@ -55,6 +56,11 @@ def _jax():
     import jax
 
     return jax
+
+
+def _nbytes(payloads: Sequence[Any]) -> int:
+    """Bytes of the array payloads among ``payloads`` (span attribute)."""
+    return sum(_payload_bytes(p) for p in payloads)
 
 
 # --------------------------------------------------------------------------
@@ -125,10 +131,12 @@ class _CollectiveSession:
         # share one clock, so the barrier winner reads EXACT skew —
         # the straggler-detection source for the in-process drivers.
         self._arrivals: List[int] = [0] * n
+        # The collective the leader is running: the ``op=`` of the stage
+        # spans its helpers record (one leader at a time).
+        self.op = "collective"
 
     def _note_skew(self, name: str) -> None:
         from ..observe import flight, metrics
-        from ..utils import trace
 
         if not (flight.enabled or trace.enabled()):
             return
@@ -144,19 +152,23 @@ class _CollectiveSession:
         self._slots[rank] = value
         self._arrivals[rank] = time.perf_counter_ns()
         try:
-            arrival = self._barrier.wait()
+            with trace.span("xla.coll.arrive_wait", op=name):
+                arrival = self._barrier.wait()
         except threading.BrokenBarrierError as exc:
             raise MpiError(
                 "mpi_tpu: collective aborted (another rank failed)") from exc
         if arrival == 0:
             self._note_skew(name)
+            self.op = name
             try:
-                self._results = leader(list(self._slots))
+                with trace.span("xla.coll.leader", op=name):
+                    self._results = leader(list(self._slots))
                 self._error = None
             except BaseException as exc:  # noqa: BLE001 - re-raised on all ranks
                 self._error = exc
         try:
-            self._barrier.wait()
+            with trace.span("xla.coll.release_wait", op=name):
+                self._barrier.wait()
         except threading.BrokenBarrierError as exc:
             raise MpiError(
                 "mpi_tpu: collective aborted (another rank failed)") from exc
@@ -226,6 +238,23 @@ class _MeshCollectives:
                 f"jax.config.update('jax_enable_x64', True)) or send "
                 f"32-bit data")
 
+    # The stages of a compiled collective, as spans under the session's
+    # ``xla.coll.leader`` (docs/OBSERVABILITY.md): host_read (payloads
+    # device -> host), device_put (host -> device, the global array),
+    # launch (dispatch; returns before the device ends), read_back (wait
+    # for the device, results device -> host). They live in these helpers
+    # so that every collective has them; the two copy stages stay round
+    # whatever host copy is left when payloads stop crossing the host.
+
+    def _stage(self, stage: str, nbytes: int):
+        return trace.span("xla.coll." + stage, op=self._coll.op,
+                          bytes=nbytes)
+
+    def _host_arrays(self, slots: List[Any]) -> List[np.ndarray]:
+        """Every payload as an ndarray on the host."""
+        with self._stage("host_read", _nbytes(slots)):
+            return [np.asarray(s) for s in slots]
+
     def _global_array(self, slots: List[np.ndarray]):
         """Stack per-rank payloads into one mesh-sharded global array
         (shard i on device i) — the input format XLA collectives want."""
@@ -234,18 +263,32 @@ class _MeshCollectives:
 
         shape = slots[0].shape
         sharding = NamedSharding(self._mesh, P("rank"))
-        shards = [
-            jax.device_put(np.asarray(s)[None], d)
-            for s, d in zip(slots, self._devices)
-        ]
-        return jax.make_array_from_single_device_arrays(
-            (self._n, *shape), sharding, shards)
+        with self._stage("device_put", _nbytes(slots)):
+            shards = [
+                jax.device_put(np.asarray(s)[None], d)
+                for s, d in zip(slots, self._devices)
+            ]
+            return jax.make_array_from_single_device_arrays(
+                (self._n, *shape), sharding, shards)
+
+    def _launch(self, garr, kind: str, op: str = "",
+                deterministic: bool = False, root: int = 0):
+        """Dispatch the compiled ``kind`` program on the global array."""
+        fn = self._collective_fn(kind, op, deterministic, root)
+        with self._stage("launch", garr.nbytes):
+            return fn(garr)
+
+    def _read_back(self, out) -> np.ndarray:
+        """A replicated result on the host (waits for the device)."""
+        with self._stage("read_back", out.nbytes):
+            return np.asarray(out)
 
     def _per_rank(self, global_arr) -> List[np.ndarray]:
         """Split a (n, ...) mesh-sharded result back into per-rank arrays."""
-        shards = sorted(global_arr.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        return [np.asarray(s.data)[0] for s in shards]
+        with self._stage("read_back", global_arr.nbytes):
+            shards = sorted(global_arr.addressable_shards,
+                            key=lambda s: s.index[0].start or 0)
+            return [np.asarray(s.data)[0] for s in shards]
 
     def _collective_fn(self, kind: str, op: str = "",
                        deterministic: bool = False, root: int = 0):
@@ -346,7 +389,8 @@ class _MeshCollectives:
         if self._mesh is None or not isinstance(
                 payload, (np.ndarray, jax.Array)):
             return None
-        arr = np.asarray(payload)
+        with self._stage("host_read", payload.nbytes):
+            arr = np.asarray(payload)
         if arr.ndim < 1:
             return None
         try:
@@ -382,7 +426,7 @@ class _MeshCollectives:
         me = self._myrank()
 
         def leader(slots: List[Any]) -> List[Any]:
-            np_slots = [np.asarray(s) for s in slots]
+            np_slots = self._host_arrays(slots)
             if np_slots[0].dtype.kind not in "fiubc":
                 raise MpiError(
                     f"mpi_tpu: allreduce requires numeric payloads, got "
@@ -402,7 +446,7 @@ class _MeshCollectives:
                 per = [total.copy() for _ in range(self._n)]
             else:
                 garr = self._global_array(np_slots)
-                out = self._collective_fn("allreduce", op, det)(garr)
+                out = self._launch(garr, "allreduce", op, det)
                 per = self._per_rank(out)
             if scalar:
                 per = [p[()] for p in per]
@@ -436,16 +480,17 @@ class _MeshCollectives:
                         for i in range(self._n)]
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            shards = [
-                jax.device_put(arr[None], d) if i == root
-                else self._filler_shard(d, arr.shape, arr.dtype)
-                for i, d in enumerate(self._devices)
-            ]
-            garr = jax.make_array_from_single_device_arrays(
-                (self._n, *arr.shape),
-                NamedSharding(self._mesh, P("rank")), shards)
-            out = self._collective_fn("bcast", root=root)(garr)
-            rows = np.asarray(out)[0]
+            with self._stage("device_put", arr.nbytes):
+                shards = [
+                    jax.device_put(arr[None], d) if i == root
+                    else self._filler_shard(d, arr.shape, arr.dtype)
+                    for i, d in enumerate(self._devices)
+                ]
+                garr = jax.make_array_from_single_device_arrays(
+                    (self._n, *arr.shape),
+                    NamedSharding(self._mesh, P("rank")), shards)
+            out = self._launch(garr, "bcast", root=root)
+            rows = self._read_back(out)[0]
             return [rows for _ in range(self._n)]
 
         return self._coll.run(self._myrank(), data, leader, name="bcast")
@@ -463,8 +508,7 @@ class _MeshCollectives:
                 return [list(slots) if i == root else None
                         for i in range(self._n)]
             garr = self._global_array(np_slots)
-            out = self._collective_fn("allgather")(garr)
-            rows = np.asarray(out)
+            rows = self._read_back(self._launch(garr, "allgather"))
             gathered = [rows[i] for i in range(self._n)]
             return [gathered if i == root else None
                     for i in range(self._n)]
@@ -487,8 +531,7 @@ class _MeshCollectives:
             if np_slots is None:
                 return [list(slots) for _ in range(self._n)]
             garr = self._global_array(np_slots)
-            out = self._collective_fn("allgather", "", False)(garr)
-            rows = np.asarray(out)
+            rows = self._read_back(self._launch(garr, "allgather"))
             gathered = [rows[i] for i in range(self._n)]
             # Fresh list per rank (elements may alias; the containers must
             # not — same contract as the fallback path).
@@ -519,8 +562,9 @@ class _MeshCollectives:
                 return list(items)
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            out = jax.device_put(np.stack(np_items),
-                                 NamedSharding(self._mesh, P("rank")))
+            with self._stage("device_put", _nbytes(np_items)):
+                out = jax.device_put(np.stack(np_items),
+                                     NamedSharding(self._mesh, P("rank")))
             return self._per_rank(out)
 
         return self._coll.run(self._myrank(), data, leader, name="scatter")
@@ -543,7 +587,7 @@ class _MeshCollectives:
             stacked = [np.stack(np_flat[i * n:(i + 1) * n])
                        for i in range(n)]  # (n, *shape) per source rank
             garr = self._global_array(stacked)          # (n, n, *shape)
-            out = self._collective_fn("alltoall", "", False)(garr)
+            out = self._launch(garr, "alltoall")
             return [list(row) for row in self._per_rank(out)]
 
         return self._coll.run(self._myrank(), data, leader,
@@ -568,7 +612,7 @@ class _MeshCollectives:
         check_op(op)
 
         def leader(slots: List[Any]) -> List[Any]:
-            np_slots = [np.asarray(s) for s in slots]
+            np_slots = self._host_arrays(slots)
             self._validate_payloads(np_slots)
             shape = np_slots[0].shape
             if len(shape) < 1 or shape[0] % self._n:
@@ -582,7 +626,7 @@ class _MeshCollectives:
                 return [total[i * m:(i + 1) * m].copy()
                         for i in range(self._n)]
             garr = self._global_array(np_slots)
-            out = self._collective_fn("reduce_scatter", op, det)(garr)
+            out = self._launch(garr, "reduce_scatter", op, det)
             return self._per_rank(out)
 
         return self._coll.run(self._myrank(), data, leader,
@@ -631,8 +675,8 @@ class _MeshCollectives:
                     return [None] + prefixes
                 return prefixes + [acc]
             self._validate_payloads(np_slots)
-            fn = self._collective_fn("prefix", op, exclusive)
-            per = self._per_rank(fn(self._global_array(np_slots)))
+            per = self._per_rank(self._launch(
+                self._global_array(np_slots), "prefix", op, exclusive))
             if exclusive:
                 per = [None] + list(per[1:])  # rank 0: MPI_Exscan contract
             return per
@@ -778,14 +822,8 @@ class XlaNetwork:
         me = self._myrank()
         self._check_rank(dest)
         jax = _jax()
-        from ..utils import trace
-
-        tracing = trace.enabled()
         if isinstance(data, jax.Array):
-            if tracing:
-                with trace.span("xla.transfer", dest=dest, tag=tag):
-                    payload = self._device_transfer(data, dest)
-            else:
+            with trace.span("xla.transfer", dest=dest, tag=tag):
                 payload = self._device_transfer(data, dest)
         elif isinstance(data, np.ndarray):
             payload = data.copy()
@@ -794,14 +832,10 @@ class XlaNetwork:
             payload = data  # immutable
         else:
             payload = copy.deepcopy(data)
-        if tracing:
-            from ..api import _payload_bytes
-
+        if trace.enabled():
             trace.count(f"wire.xla.tx.bytes.peer{dest}",
                         _payload_bytes(data))
-            with trace.span("xla.rendezvous_send", dest=dest, tag=tag):
-                self._pair(me, dest).send(tag, payload)
-        else:
+        with trace.span("xla.rendezvous_send", dest=dest, tag=tag):
             self._pair(me, dest).send(tag, payload)
 
     def _device_transfer(self, data, dest: int):
@@ -830,17 +864,11 @@ class XlaNetwork:
     def receive(self, source: int, tag: int, out: Optional[Any] = None) -> Any:
         me = self._myrank()
         self._check_rank(source)
-        from ..utils import trace
-
+        with trace.span("xla.recv_wait", source=source, tag=tag):
+            payload = self._pair(source, me).receive(tag)
         if trace.enabled():
-            from ..api import _payload_bytes
-
-            with trace.span("xla.recv_wait", source=source, tag=tag):
-                payload = self._pair(source, me).receive(tag)
             trace.count(f"wire.xla.rx.bytes.peer{source}",
                         _payload_bytes(payload))
-        else:
-            payload = self._pair(source, me).receive(tag)
         if out is not None and isinstance(out, np.ndarray) \
                 and isinstance(payload, np.ndarray) \
                 and out.shape == payload.shape and out.dtype == payload.dtype:

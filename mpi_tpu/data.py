@@ -24,6 +24,8 @@ from typing import Any, Callable, Iterator, Optional, Union
 
 import numpy as np
 
+from .utils import trace
+
 __all__ = ["SyntheticLM", "from_token_array", "from_token_file",
            "ShardedLoader"]
 
@@ -189,16 +191,19 @@ class ShardedLoader:
         """The device-placed batch for ``step`` (pure, thread-safe)."""
         import jax
 
-        host = self._process_slice(self.source(step))
-        if self._sharding is not None:
-            if jax.process_count() > 1:
-                # Each process holds only its slice; assemble the global
-                # array from per-process local data (device_put with a
-                # global sharding would misread the slice as the whole).
-                return jax.make_array_from_process_local_data(
-                    self._sharding, host)
-            return jax.device_put(host, self._sharding)
-        return jax.device_put(host)
+        with trace.span("data.source", step=step):
+            host = self._process_slice(self.source(step))
+        with trace.span("data.device_put", step=step, bytes=host.nbytes):
+            if self._sharding is not None:
+                if jax.process_count() > 1:
+                    # Each process holds only its slice; assemble the
+                    # global array from per-process local data (device_put
+                    # with a global sharding would misread the slice as
+                    # the whole).
+                    return jax.make_array_from_process_local_data(
+                        self._sharding, host)
+                return jax.device_put(host, self._sharding)
+            return jax.device_put(host)
 
     def _process_slice(self, global_batch: np.ndarray) -> np.ndarray:
         import jax
@@ -221,7 +226,9 @@ class ShardedLoader:
         if self.prefetch == 0:
             step = self.start_step
             while True:
-                yield self.batch_at(step)
+                with trace.span("data.batch", step=step):
+                    batch = self.batch_at(step)
+                yield batch
                 step += 1
             return
 
@@ -242,7 +249,8 @@ class ShardedLoader:
 
             while not stop.is_set():
                 try:
-                    item = self.batch_at(step)
+                    with trace.span("data.batch", step=step):
+                        item = self.batch_at(step)
                 except BaseException as exc:  # noqa: BLE001 - handed to consumer
                     put(("error", exc))
                     return
@@ -254,7 +262,8 @@ class ShardedLoader:
         t.start()
         try:
             while True:
-                kind, item = q.get()
+                with trace.span("data.wait"):
+                    kind, item = q.get()
                 if kind == "error":
                     raise item
                 yield item

@@ -380,14 +380,19 @@ def block_body(x, blk, cfg: TransformerConfig,
     (:func:`forward_with_aux`) and the pipelined stages
     (:mod:`mpi_tpu.models.pipeline_lm`), so the two paths cannot
     drift. Returns ``(x, aux_loss)``."""
-    h = _layernorm(x, blk["ln1"]["scale"].astype(x.dtype),
-                   blk["ln1"]["bias"].astype(x.dtype))
-    x = x + _attention(h, blk, cfg, mesh)
+    # The named scopes here and in forward_with_aux / token_xent / the
+    # train step are what a profiler trace's device ops are grouped by
+    # (docs/OBSERVABILITY.md): metadata only, the program is unchanged.
+    with jax.named_scope("attn"):
+        h = _layernorm(x, blk["ln1"]["scale"].astype(x.dtype),
+                       blk["ln1"]["bias"].astype(x.dtype))
+        x = x + _attention(h, blk, cfg, mesh)
     x = _act_constraint(x, mesh)
-    h = _layernorm(x, blk["ln2"]["scale"].astype(x.dtype),
-                   blk["ln2"]["bias"].astype(x.dtype))
-    y, blk_aux = _ffn(h, blk, cfg, mesh)
-    x = x + y
+    with jax.named_scope("ffn"):
+        h = _layernorm(x, blk["ln2"]["scale"].astype(x.dtype),
+                       blk["ln2"]["bias"].astype(x.dtype))
+        y, blk_aux = _ffn(h, blk, cfg, mesh)
+        x = x + y
     return _act_constraint(x, mesh), blk_aux
 
 
@@ -395,11 +400,12 @@ def token_xent(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean next-token cross-entropy as ``logsumexp - target_logit`` —
     the fused form that never materialises the (b, s, vocab) float32
     log-softmax. Shared by the sequential and pipelined losses."""
-    logits32 = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits32, axis=-1)
-    tgt = jnp.take_along_axis(logits32, targets[..., None],
-                              axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
+    with jax.named_scope("logits_loss"):
+        logits32 = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits32, axis=-1)
+        tgt = jnp.take_along_axis(logits32, targets[..., None],
+                                  axis=-1)[..., 0]
+        return jnp.mean(lse - tgt)
 
 
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
@@ -409,9 +415,10 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
     """tokens (batch, seq) int32 → (logits (batch, seq, vocab), aux_loss).
     ``aux_loss`` is the summed MoE load-balance penalty (0 for dense)."""
     _, s = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if not cfg.rope:
-        x = x + params["pos"].astype(cfg.dtype)[:s][None]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if not cfg.rope:
+            x = x + params["pos"].astype(cfg.dtype)[:s][None]
     x = _act_constraint(x, mesh)
     aux = jnp.zeros((), jnp.float32)
 
@@ -421,9 +428,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
     for blk in params["blocks"]:
         x, blk_aux = block(x, blk)
         aux = aux + blk_aux
-    x = _layernorm(x, params["final_ln"]["scale"].astype(x.dtype),
-                   params["final_ln"]["bias"].astype(x.dtype))
-    return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype)), aux
+    with jax.named_scope("logits_loss"):
+        x = _layernorm(x, params["final_ln"]["scale"].astype(x.dtype),
+                       params["final_ln"]["bias"].astype(x.dtype))
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"].astype(x.dtype))
+    return logits, aux
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array,
@@ -641,15 +651,18 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             params0 = constrain_params(state["params"], fspecs, mesh)
             loss, grads = accumulate(params0, tokens)
             grads = constrain_params(grads, fspecs, mesh)
-            updates, new_opt = opt.update(grads, state["opt"], params0)
-            new_params = constrain_params(
-                optax.apply_updates(params0, updates), fspecs, mesh)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = opt.update(grads, state["opt"], params0)
+                new_params = optax.apply_updates(params0, updates)
+            new_params = constrain_params(new_params, fspecs, mesh)
             zspecs = zero1_specs(state["params"], fspecs, new_opt, mesh)
             new_opt = constrain_opt_state(new_opt, zspecs, mesh)
             return {"params": new_params, "opt": new_opt}, loss
         loss, grads = accumulate(state["params"], tokens)
-        updates, new_opt = opt.update(grads, state["opt"], state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = opt.update(grads, state["opt"],
+                                          state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
         if zero1:
             from ..parallel.zero import constrain_opt_state, zero1_specs
 

@@ -492,6 +492,7 @@ def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
@@ -534,6 +535,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     # dk/dv: grid (b*hk, nk, group*nq) — ki owns the accumulation, the
@@ -564,6 +566,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     unflat_q = lambda x: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731

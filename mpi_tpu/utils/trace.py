@@ -1,33 +1,42 @@
-"""Tracing / profiling — spans, comm counters, and jax.profiler hooks.
+"""Tracing — spans (into a buffer and into any open jax.profiler trace) and
+comm counters.
 
 The reference has no tracing subsystem at all — its only instrument is the
 bounce example's manual ``time.Now()`` deltas (SURVEY.md §5; bounce.go:
 90-101). This module supplies the idiomatic tpu equivalents:
 
   * **spans** — wall-clock regions (``with span("allreduce", bytes=n)``)
-    recorded into a bounded process-local buffer (events beyond the cap
-    are dropped and counted — see :func:`dropped`) and exportable as a
-    chrome://tracing / Perfetto JSON trace (``dump_chrome_trace``);
+    with two sinks. (1) A bounded process-local buffer (events beyond the
+    cap are dropped and counted — see :func:`dropped`), exportable as a
+    chrome://tracing / Perfetto JSON trace (``dump_chrome_trace``) and
+    gathered job-wide by :mod:`mpi_tpu.observe`: on when
+    ``MPI_TPU_TRACE=1`` or after :func:`enable`. (2) Whatever
+    ``jax.profiler`` trace is open: every span is also a
+    ``jax.profiler.TraceAnnotation`` with the same name and attributes,
+    so inside ``with jax.profiler.trace(dir):`` (or the benchmark's
+    profiler context) the program's stages lie on ``/host:CPU`` on the
+    clock of the runtime's events and the device's ops. That sink needs
+    no flag; it exists once jax is imported (this module never imports
+    jax — the socket drivers run without it);
   * **counters** — monotonically accumulated values (bytes sent/received
-    per peer, collective invocations), queryable for bench harnesses;
-  * **device profiling** — :func:`profile` wraps ``jax.profiler.trace``
-    so a region's XLA/TPU activity lands in TensorBoard-compatible
-    traces alongside the host spans.
+    per peer, collective invocations), queryable for bench harnesses.
 
-Off by default and cheap when off (one attribute check per call site);
-enable with ``MPI_TPU_TRACE=1`` or :func:`enable`. The facade
-(:mod:`mpi_tpu.api`) instruments send/receive/collectives through this
-module, so any backend gets comm accounting for free.
+With no profiler session and recording off a span allocates no event and
+costs well under a microsecond. :func:`add_span` records a span that has
+already ended, which the profiler's annotation cannot express: it reaches
+the buffer only. The facade (:mod:`mpi_tpu.api`) instruments
+send/receive/collectives through this module, so any backend gets comm
+accounting for free.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 __all__ = [
     "enable",
@@ -45,7 +54,6 @@ __all__ = [
     "set_stream",
     "stream",
     "flush_stream",
-    "profile",
 ]
 
 _MAX_EVENTS = 100_000
@@ -110,24 +118,73 @@ def enabled() -> bool:
     return _tracer.enabled
 
 
-@contextmanager
-def span(name: str, **attrs: Any) -> Iterator[None]:
-    """Record a wall-clock region. No-op (one bool check) when disabled."""
-    if not _tracer.enabled:
-        yield
-        return
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        t1 = time.perf_counter_ns()
-        _tracer.add_event({
-            "name": name,
-            "ts_us": t0 / 1e3,
-            "dur_us": (t1 - t0) / 1e3,
-            "thread": threading.current_thread().name,
-            **attrs,
-        })
+_annotation: Any = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _profiling() -> bool:
+    """Whether a profiler session is collecting host events right now.
+    False without importing anything while jax is not in the process."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+        if _annotation is None:
+            return False
+    return _annotation.is_enabled()
+
+
+class _NoSpan:
+    """The span of a process that neither records nor profiles."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("name", "attrs", "annotation", "t0")
+
+    def __init__(self, name: str, attrs: Dict[str, Any], profiling: bool):
+        self.name, self.attrs = name, attrs
+        self.annotation = _annotation(name, **attrs) if profiling else None
+        self.t0 = 0
+
+    def __enter__(self) -> None:
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        if _tracer.enabled:
+            self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc: Any) -> bool:
+        if self.t0:
+            t1 = time.perf_counter_ns()
+            _tracer.add_event({
+                "name": self.name,
+                "ts_us": self.t0 / 1e3,
+                "dur_us": (t1 - self.t0) / 1e3,
+                "thread": threading.current_thread().name,
+                **self.attrs,
+            })
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        return False
+
+
+def span(name: str, **attrs: Any):
+    """A wall-clock region, as a context manager: a host event of the
+    open ``jax.profiler`` trace, if there is one, and an event of the
+    buffer, if recording is on. Two flag checks when neither is."""
+    profiling = _profiling()
+    if not profiling and not _tracer.enabled:
+        return _NO_SPAN
+    return _Span(name, attrs, profiling)
 
 
 def add_span(name: str, ts_us: float, dur_us: float, **attrs: Any) -> None:
@@ -240,20 +297,3 @@ def dump_chrome_trace(path: str) -> int:
     with open(path, "w") as f:
         json.dump(trace, f)
     return len(evs)
-
-
-@contextmanager
-def profile(logdir: str, host_spans: bool = True) -> Iterator[None]:
-    """Capture a jax.profiler device trace (TensorBoard format) for the
-    region, optionally enabling host span recording too."""
-    import jax
-
-    prev = _tracer.enabled
-    if host_spans:
-        _tracer.enabled = True
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        _tracer.enabled = prev

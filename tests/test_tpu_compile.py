@@ -72,10 +72,20 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(attention, "_should_interpret", lambda: False)
 
 
-def _compile(fn, *args):
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+LAYER_SCOPES = ("(attn)/", "(ffn)/", "(logits_loss)/", "/optimizer/")
+
+
+def _compile(fn, *args, names=()):
+    """Compile for the described chip; ``names`` are what a profiler
+    trace of the program is read by (kernel names as the instructions'
+    names, layer scopes inside ``op_name``) and must be in its text."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, \
         "no Mosaic kernel in the compiled program"
+    missing = [n for n in names if n not in text]
+    assert not missing, f"not named in the compiled program: {missing}"
     return compiled
 
 
@@ -89,7 +99,8 @@ def test_flash_forward_compiles(one_chip):
 
     q = _qkv(one_chip)
     _compile(lambda q, k, v: flash_attention(q, k, v, True, None, None,
-                                             False), q, q, q)
+                                             False), q, q, q,
+             names=["%flash_fwd"])
 
 
 def test_flash_forward_backward_compiles(one_chip):
@@ -100,7 +111,8 @@ def test_flash_forward_backward_compiles(one_chip):
         return jnp.sum(out.astype(jnp.float32))
 
     q = _qkv(one_chip)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+             names=FLASH_KERNELS)
 
 
 def _flagship_step_args(mesh):
@@ -135,7 +147,8 @@ def test_flagship_train_step_compiles_one_chip(topo, compiled_kernels):
     from mpi_tpu.models import make_mesh_nd
 
     mesh = make_mesh_nd(1, devices=topo.devices[:1])
-    _compile(*_flagship_step_args(mesh))
+    _compile(*_flagship_step_args(mesh),
+             names=FLASH_KERNELS + LAYER_SCOPES)
 
 
 def test_flagship_train_step_compiles_four_chips(topo, compiled_kernels):
@@ -146,7 +159,8 @@ def test_flagship_train_step_compiles_four_chips(topo, compiled_kernels):
 
     mesh = make_mesh_nd(4, axes=("dp", "tp"), devices=topo.devices)
     assert dict(mesh.shape) == {"dp": 2, "tp": 2}
-    compiled = _compile(*_flagship_step_args(mesh))
+    compiled = _compile(*_flagship_step_args(mesh),
+                        names=FLASH_KERNELS + LAYER_SCOPES)
     assert "all-reduce" in compiled.as_text()  # dp grads / tp partials
 
 
