@@ -205,9 +205,10 @@ def rank_payload(rank: int, n: int):
 
 def exchange_main():
     """Reference-style rank program: ring p2p of a committed device
-    array, then allreduce / bcast / allgather of numpy payloads, each
-    checked against numpy in rank order. Returns what this rank saw of
-    the driver's state, for the caller to judge."""
+    array, then allreduce / bcast / allgather of numpy payloads and one
+    allreduce of a committed device array, each checked against numpy
+    in rank order. Returns what this rank saw of the driver's state, for
+    the caller to judge."""
     import jax
     import numpy as np
 
@@ -252,6 +253,20 @@ def exchange_main():
         for r in range(size):
             np.testing.assert_array_equal(gathered[r], everyone[r])
 
+        # A committed device array goes through the compiled allreduce as
+        # the shard it is and comes back on this rank's chip, with the
+        # same bits the host tree gives.
+        on_chip = [rank_payload(r, P2P_ELEMS) for r in range(size)]
+        total = mpi_tpu.allreduce(jax.device_put(on_chip[rank], mine))
+        assert isinstance(total, jax.Array), type(total)
+        assert total.devices() == {mine}, (total.devices(), mine)
+        tree = canonical_combine(on_chip, "sum")
+        if net.deterministic_collectives:
+            np.testing.assert_array_equal(np.asarray(total), tree)
+        else:
+            np.testing.assert_allclose(np.asarray(total), tree,
+                                       rtol=1e-5, atol=1e-6)
+
         mpi_tpu.barrier()  # every rank's sends are in before the census
         pipe = net._pipe
         return {
@@ -291,7 +306,8 @@ def judge_exchange(label: str, seen, n: int, deterministic: bool) -> None:
         f"devices; compiled collectives {first['collective_programs']}; "
         f"{len(ring)} DevicePipe program(s); all results equal the numpy "
         f"oracle ({'bitwise' if deterministic else 'float32 tolerance'} "
-        f"for allreduce, bitwise otherwise)")
+        f"for allreduce, bitwise otherwise); the allreduce of a 1 MiB "
+        f"device array came back as a jax.Array on each rank's own device")
 
 
 def message_phase(n: int) -> None:

@@ -629,9 +629,16 @@ class _HybridGroupEngine:
         # DCN-analogue leader tier would be indistinguishable from
         # local noise (bench reads these spans; span() is a one-bool
         # check when tracing is off).
+        import jax
+        import numpy as np
+
         with trace.span("hybrid.allreduce.local_reduce"):
             local_total = self._inner.allreduce(data, op=op)
-        import numpy as np
+            # The xla driver hands a device payload's result back on the
+            # device; this driver's results are host arrays (the leader
+            # leg sends them over sockets), so read it once, here.
+            if isinstance(local_total, jax.Array):
+                local_total = np.asarray(local_total)
 
         if len(self._hosts) > 1 \
                 and isinstance(local_total, np.ndarray) \

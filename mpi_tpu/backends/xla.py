@@ -16,7 +16,18 @@ moves bytes over TCP (network.go), this driver maps **rank → device** on a
     over the mesh (``mpi_tpu.parallel.collectives``), which rides ICI.
     ``deterministic=True`` uses the canonical binomial tree for
     bitwise-identical results to the TCP driver. Object payloads
-    (strings, dicts, ...) use in-process handoff.
+    (strings, dicts, ...) use in-process handoff. The payload's type
+    picks the way, and nothing else does: a ``jax.Array`` with
+    ``ndim >= 1`` is its rank's shard of the global array as it is
+    (never read to the host), and ``allreduce`` / ``reduce`` /
+    ``reduce_scatter`` / ``scan`` / ``exscan`` hand the rank its result
+    as a ``jax.Array`` committed to its own device, as soon as the
+    program is dispatched; numpy, lists and scalars (0-d arrays too) are
+    placed on the devices, and the result is read back into numpy.
+    Ranks of one call may differ: each gets the type it gave.
+    ``bcast`` / ``gather`` / ``allgather`` take a device payload in the
+    same way but return numpy (their result is replicated);
+    ``alltoall`` / ``scatter`` stack their lists on the host.
 
 Programming model. The reference is SPMD-by-processes: one binary, N
 processes, behavior branches on ``Rank()`` (mpi.go:8-14). Here the same
@@ -148,7 +159,11 @@ class _CollectiveSession:
 
     def run(self, rank: int, value: Any,
             leader: Callable[[List[Any]], List[Any]],
-            name: str = "collective") -> Any:
+            name: str = "collective",
+            path: Optional[Callable[[List[Any]], str]] = None) -> Any:
+        """``path(slots)``, where given, names the way the payloads take
+        into the program (``device`` / ``host`` / ``mixed``): an
+        attribute of the leader's span."""
         self._slots[rank] = value
         self._arrivals[rank] = time.perf_counter_ns()
         try:
@@ -161,7 +176,8 @@ class _CollectiveSession:
             self._note_skew(name)
             self.op = name
             try:
-                with trace.span("xla.coll.leader", op=name):
+                attrs = {} if path is None else {"path": path(self._slots)}
+                with trace.span("xla.coll.leader", op=name, **attrs):
                     self._results = leader(list(self._slots))
                 self._error = None
             except BaseException as exc:  # noqa: BLE001 - re-raised on all ranks
@@ -219,10 +235,11 @@ class _MeshCollectives:
 
 
     @staticmethod
-    def _validate_payloads(slots: List[np.ndarray]) -> None:
+    def _validate_payloads(slots: List[Any]) -> None:
         """Cross-rank shape/dtype agreement + the float64-downcast guard.
         Enforced identically on the mesh and oversubscribed paths so a
-        program's behavior never depends on the rank/device ratio."""
+        program's behavior never depends on the rank/device ratio. Reads
+        ``.shape`` / ``.dtype`` only: a device payload stays where it is."""
         jax = _jax()
         shape, dtype = slots[0].shape, slots[0].dtype
         for i, s in enumerate(slots):
@@ -238,38 +255,78 @@ class _MeshCollectives:
                 f"jax.config.update('jax_enable_x64', True)) or send "
                 f"32-bit data")
 
-    # The stages of a compiled collective, as spans under the session's
-    # ``xla.coll.leader`` (docs/OBSERVABILITY.md): host_read (payloads
-    # device -> host), device_put (host -> device, the global array),
-    # launch (dispatch; returns before the device ends), read_back (wait
-    # for the device, results device -> host). They live in these helpers
-    # so that every collective has them; the two copy stages stay round
-    # whatever host copy is left when payloads stop crossing the host.
+    # How payloads reach a compiled collective, and the stages of one as
+    # spans under the session's ``xla.coll.leader``
+    # (docs/OBSERVABILITY.md). The payload's type decides: a *device
+    # payload* (a ``jax.Array`` with ``ndim >= 1``) becomes its rank's
+    # shard of the mesh-global input as it is — moved device to device
+    # first if it is not committed to the rank's device — and where the
+    # program's output is sharded over the ranks too (allreduce, reduce,
+    # reduce_scatter, scan, exscan) that rank gets its result as the
+    # ``jax.Array`` on its own device; every other payload is read into
+    # numpy, placed, and its rank's result read back into numpy. The
+    # global array's leading axis is the payload's: shape
+    # ``(n * shape[0], *shape[1:])`` over ``P("rank")``, so the payloads
+    # are the input's shards and the output's shards are the results, with
+    # no program to add or drop an axis. Stages: host_read (payloads ->
+    # numpy), device_put (the assembly of the global array, with whatever
+    # placement it needs), launch (dispatch; returns before the device
+    # ends), read_back (wait for the device, results device -> host).
+    # host_read and read_back are entered only where a payload or a
+    # result takes that way, so a call of device payloads has neither.
 
     def _stage(self, stage: str, nbytes: int):
         return trace.span("xla.coll." + stage, op=self._coll.op,
                           bytes=nbytes)
 
-    def _host_arrays(self, slots: List[Any]) -> List[np.ndarray]:
-        """Every payload as an ndarray on the host."""
-        with self._stage("host_read", _nbytes(slots)):
-            return [np.asarray(s) for s in slots]
+    @staticmethod
+    def _on_device(payload: Any) -> bool:
+        """Whether ``payload`` is a device payload (see above)."""
+        return isinstance(payload, _jax().Array) and payload.ndim >= 1
 
-    def _global_array(self, slots: List[np.ndarray]):
-        """Stack per-rank payloads into one mesh-sharded global array
-        (shard i on device i) — the input format XLA collectives want."""
+    def _path(self, slots: List[Any], host_tree: bool = False) -> str:
+        """The leader span's ``path=``: ``device`` when every payload of
+        the call stays on the device, ``host`` when none does."""
+        staying = 0 if host_tree else sum(map(self._on_device, slots))
+        return "device" if staying == len(slots) \
+            else "mixed" if staying else "host"
+
+    def _arrays(self, slots: List[Any], on_host: bool = False) -> List[Any]:
+        """Every payload as an array: device payloads as they are (unless
+        ``on_host``), the others read into numpy."""
+        stays = [not on_host and self._on_device(s) for s in slots]
+        if trace.enabled():
+            trace.count("xla.coll.device_payloads", sum(stays))
+            trace.count("xla.coll.host_payloads", len(stays) - sum(stays))
+        if all(stays):
+            return list(slots)
+        with self._stage("host_read", _nbytes(
+                [s for s, stay in zip(slots, stays) if not stay])):
+            return [s if stay else np.asarray(s)
+                    for s, stay in zip(slots, stays)]
+
+    def _global_array(self, arrays: List[Any]):
+        """One mesh-sharded global array whose shard on device i is
+        ``arrays[i]`` — the input format XLA collectives want. A device
+        payload already committed to its rank's device is taken as it
+        is; ``None`` entries (bcast's non-roots) become cached zero
+        blocks that are never read."""
         jax = _jax()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        shape = slots[0].shape
-        sharding = NamedSharding(self._mesh, P("rank"))
-        with self._stage("device_put", _nbytes(slots)):
+        first = next(a for a in arrays if a is not None)
+        shape, dtype = first.shape, first.dtype
+        with self._stage("device_put", _nbytes(arrays)):
             shards = [
-                jax.device_put(np.asarray(s)[None], d)
-                for s, d in zip(slots, self._devices)
+                self._filler_shard(d, shape, dtype) if a is None
+                else a if isinstance(a, jax.Array) and a.committed
+                and a.devices() == {d}
+                else jax.device_put(a, d)
+                for a, d in zip(arrays, self._devices)
             ]
             return jax.make_array_from_single_device_arrays(
-                (self._n, *shape), sharding, shards)
+                (self._n * shape[0], *shape[1:]),
+                NamedSharding(self._mesh, P("rank")), shards)
 
     def _launch(self, garr, kind: str, op: str = "",
                 deterministic: bool = False, root: int = 0):
@@ -283,12 +340,22 @@ class _MeshCollectives:
         with self._stage("read_back", out.nbytes):
             return np.asarray(out)
 
-    def _per_rank(self, global_arr) -> List[np.ndarray]:
-        """Split a (n, ...) mesh-sharded result back into per-rank arrays."""
-        with self._stage("read_back", global_arr.nbytes):
-            shards = sorted(global_arr.addressable_shards,
-                            key=lambda s: s.index[0].start or 0)
-            return [np.asarray(s.data)[0] for s in shards]
+    def _per_rank(self, global_arr, like: Sequence[Any] = ()) -> List[Any]:
+        """A mesh-sharded result as per-rank results: rank i's shard as
+        the ``jax.Array`` on its device where ``like[i]`` is one (its
+        payload stayed on the device; nobody waits for the program),
+        read back into numpy otherwise."""
+        jax = _jax()
+        by_device = {s.device: s.data for s in global_arr.addressable_shards}
+        per = [by_device[d] for d in self._devices]
+        stays = [isinstance(a, jax.Array) for a in like] or [False] * self._n
+        to_host = [i for i, stay in enumerate(stays) if not stay]
+        if to_host:
+            with self._stage("read_back",
+                             sum(per[i].nbytes for i in to_host)):
+                for i in to_host:
+                    per[i] = np.asarray(per[i])
+        return per
 
     def _collective_fn(self, kind: str, op: str = "",
                        deterministic: bool = False, root: int = 0):
@@ -303,32 +370,32 @@ class _MeshCollectives:
 
         from ..parallel import collectives as C
 
+        # Every per_shard sees its rank's payload as it is: ``shape``, no
+        # leading axis of 1 (the helpers' comment block above).
         if kind == "allreduce":
             def per_shard(x):
-                # x: (1, *shape) block; reduce over the mesh axis.
                 return C.allreduce(x, "rank", op=op,
                                    deterministic=deterministic)
 
             out_specs = P("rank")
         elif kind == "allgather":
             def per_shard(x):
-                # x: (1, *shape) block; gather the full (n, *shape) stack,
-                # replicated on every device.
-                return C.allgather(x, "rank", axis=0, tiled=True)
+                # The full (n, *shape) stack, replicated on every device.
+                return C.allgather(x, "rank", axis=0)
 
             out_specs = P()
         elif kind == "alltoall":
             def per_shard(x):
-                # x: (1, n, *shape) — row j is my payload for rank j;
-                # after the exchange, slot j holds rank j's payload to me.
-                return C.alltoall(x, "rank", split_axis=1, concat_axis=1)
+                # x: (n, *shape) — row j is my payload for rank j; after
+                # the exchange, row j holds rank j's payload to me.
+                return C.alltoall(x, "rank", split_axis=0, concat_axis=0)
 
             out_specs = P("rank")
         elif kind == "bcast":
             def per_shard(x):
-                # x: (1, *shape) block, real data only on root's shard
-                # (fillers elsewhere); the all_gather + static index is
-                # XLA's broadcast idiom over ICI.
+                # Real data only on root's shard (fillers elsewhere); the
+                # all_gather + static index is XLA's broadcast idiom over
+                # ICI.
                 return C.bcast(x, root, "rank")
 
             out_specs = P()
@@ -337,21 +404,18 @@ class _MeshCollectives:
             # ``deterministic`` slot carries ``exclusive`` for this kind
             # (the order is always the fixed left fold).
             def per_shard(x):
-                # x: (1, *shape) block; prefix over the mesh axis.
-                return C.prefix_reduce(x[0], "rank", op=op,
-                                       exclusive=deterministic)[None]
+                return C.prefix_reduce(x, "rank", op=op,
+                                       exclusive=deterministic)
 
             out_specs = P("rank")
         elif kind == "reduce_scatter":
             def per_shard(x):
-                # x: (1, L, *shape); each rank keeps its reduced L/n block.
-                y = x[0]
+                # x: (L, *shape); each rank keeps its reduced L/n block.
                 # deterministic → canonical size-selected order; the
                 # ring/tree choice lives in parallel.collectives next
                 # to allreduce's so the rule can never fork.
-                out = C.reduce_scatter(y, "rank", op=op,
-                                       deterministic=deterministic)
-                return out[None]
+                return C.reduce_scatter(x, "rank", op=op,
+                                        deterministic=deterministic)
 
             out_specs = P("rank")
         else:  # pragma: no cover - future kinds
@@ -374,45 +438,41 @@ class _MeshCollectives:
         if arr is not None:
             self._fillers.move_to_end(key)
             return arr
-        arr = _jax().device_put(np.zeros((1, *shape), dtype), device)
+        arr = _jax().device_put(np.zeros(shape, dtype), device)
         self._fillers[key] = arr
         while len(self._fillers) > self._FILLER_CACHE:
             self._fillers.popitem(last=False)
         return arr
 
-    def _canonical_array(self, payload) -> Optional[np.ndarray]:
-        """``payload`` as an ndarray if it can ride a compiled path:
-        array-typed, ndim >= 1, and a dtype XLA will not rewrite
-        (int64/float64 without x64 fall back to the object path, which
-        returns payloads untouched)."""
+    def _rides_compiled(self, payload) -> bool:
+        """Whether ``payload`` can ride a compiled path: array-typed,
+        ndim >= 1, and a dtype XLA will not rewrite (int64/float64
+        without x64 fall back to the object path, which returns payloads
+        untouched). Looks at the payload's type, ``ndim`` and ``dtype``
+        only."""
         jax = _jax()
         if self._mesh is None or not isinstance(
-                payload, (np.ndarray, jax.Array)):
-            return None
-        with self._stage("host_read", payload.nbytes):
-            arr = np.asarray(payload)
-        if arr.ndim < 1:
-            return None
+                payload, (np.ndarray, jax.Array)) or payload.ndim < 1:
+            return False
         try:
-            if jax.dtypes.canonicalize_dtype(arr.dtype) != arr.dtype:
-                return None
+            return jax.dtypes.canonicalize_dtype(payload.dtype) \
+                == payload.dtype
         except TypeError:
-            return None
-        return arr
+            return False
 
-    def _uniform_arrays(self, slots: List[Any]) -> Optional[List[np.ndarray]]:
-        """All payloads canonical arrays of one shape/dtype, else None."""
-        np_slots = []
-        for s in slots:
-            arr = self._canonical_array(s)
-            if arr is None:
-                return None
-            np_slots.append(arr)
-        first = np_slots[0]
-        if not all(s.shape == first.shape and s.dtype == first.dtype
-                   for s in np_slots):
-            return None
-        return np_slots
+    def _uniform(self, slots: List[Any]) -> bool:
+        """Whether all payloads can ride a compiled path and have one
+        shape/dtype."""
+        first = slots[0]
+        return all(self._rides_compiled(s) and s.shape == first.shape
+                   and s.dtype == first.dtype for s in slots)
+
+    def _uniform_arrays(self, slots: List[Any],
+                        on_host: bool = False) -> Optional[List[Any]]:
+        """The payloads as arrays (:meth:`_arrays`) if uniform, else
+        None."""
+        return self._arrays(slots, on_host) if self._uniform(slots) \
+            else None
 
     def allreduce(self, data: Any, op: "OpLike" = "sum",
                   deterministic: Optional[bool] = None) -> Any:
@@ -420,20 +480,29 @@ class _MeshCollectives:
 
         Payloads must be numeric (anything ``np.asarray`` maps to a
         numeric dtype, matching what the generic driver can reduce);
-        a non-numeric payload raises on every rank."""
+        a non-numeric payload raises on every rank.
+
+        The result has the type of this rank's payload: a ``jax.Array``
+        with ``ndim >= 1`` stays on the device all the way and comes back
+        as a ``jax.Array`` committed to this rank's device, same shape
+        and dtype, returned as soon as the program is dispatched (jax's
+        own idiom: whatever uses it waits for it; the payload is not
+        donated). Anything else comes back as numpy, after the device
+        has ended. Ranks may differ in one call."""
         det = (self.deterministic_collectives if deterministic is None
                else deterministic)
         me = self._myrank()
+        host_tree = self._mesh is None or callable(op)
 
         def leader(slots: List[Any]) -> List[Any]:
-            np_slots = self._host_arrays(slots)
+            np_slots = self._arrays(slots, on_host=host_tree)
             if np_slots[0].dtype.kind not in "fiubc":
                 raise MpiError(
                     f"mpi_tpu: allreduce requires numeric payloads, got "
                     f"dtype {np_slots[0].dtype}")
             scalar = np_slots[0].ndim == 0
             self._validate_payloads(np_slots)
-            if self._mesh is None or callable(op):
+            if host_tree:
                 # Oversubscribed ranks share devices → no mesh; user
                 # callable ops (MPI_Op_create analogue) are host
                 # functions XLA cannot compile. Either way reduce on
@@ -445,17 +514,21 @@ class _MeshCollectives:
                 total = canonical_combine(np_slots, op)
                 per = [total.copy() for _ in range(self._n)]
             else:
-                garr = self._global_array(np_slots)
+                # 0-d payloads are host payloads: one element a rank.
+                garr = self._global_array(
+                    [s[None] for s in np_slots] if scalar else np_slots)
                 out = self._launch(garr, "allreduce", op, det)
-                per = self._per_rank(out)
+                per = self._per_rank(out, like=np_slots)
             if scalar:
-                per = [p[()] for p in per]
+                per = [p.reshape(())[()] for p in per]
             return per
 
         from ..collectives_generic import check_op
 
         check_op(op)
-        return self._coll.run(me, data, leader, name="allreduce")
+        return self._coll.run(
+            me, data, leader, name="allreduce",
+            path=lambda slots: self._path(slots, host_tree))
 
     def barrier(self) -> None:
         self._coll.run(self._myrank(), None,
@@ -470,27 +543,16 @@ class _MeshCollectives:
         alias across ranks — treat them as read-only, as with
         ``allgather``."""
         self._check_rank(root)
-        jax = _jax()
 
         def leader(slots: List[Any]) -> List[Any]:
             payload = slots[root]
-            arr = self._canonical_array(payload)
-            if arr is None:
+            if not self._rides_compiled(payload):
                 return [payload if i == root else copy.deepcopy(payload)
                         for i in range(self._n)]
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            with self._stage("device_put", arr.nbytes):
-                shards = [
-                    jax.device_put(arr[None], d) if i == root
-                    else self._filler_shard(d, arr.shape, arr.dtype)
-                    for i, d in enumerate(self._devices)
-                ]
-                garr = jax.make_array_from_single_device_arrays(
-                    (self._n, *arr.shape),
-                    NamedSharding(self._mesh, P("rank")), shards)
-            out = self._launch(garr, "bcast", root=root)
-            rows = self._read_back(out)[0]
+            arr, = self._arrays([payload])
+            garr = self._global_array(
+                [arr if i == root else None for i in range(self._n)])
+            rows = self._read_back(self._launch(garr, "bcast", root=root))
             return [rows for _ in range(self._n)]
 
         return self._coll.run(self._myrank(), data, leader, name="bcast")
@@ -557,13 +619,13 @@ class _MeshCollectives:
                 raise MpiError(
                     f"mpi_tpu: scatter root needs a list of exactly "
                     f"{self._n} payloads")
-            np_items = self._uniform_arrays(list(items))
+            np_items = self._uniform_arrays(list(items), on_host=True)
             if np_items is None:
                 return list(items)
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             with self._stage("device_put", _nbytes(np_items)):
-                out = jax.device_put(np.stack(np_items),
+                out = jax.device_put(np.concatenate(np_items),
                                      NamedSharding(self._mesh, P("rank")))
             return self._per_rank(out)
 
@@ -579,14 +641,14 @@ class _MeshCollectives:
 
         def leader(slots: List[List[Any]]) -> List[List[Any]]:
             flat = [p for row in slots for p in row]
-            np_flat = self._uniform_arrays(flat)
+            np_flat = self._uniform_arrays(flat, on_host=True)
             if np_flat is None:
                 return [[slots[src][dst] for src in range(self._n)]
                         for dst in range(self._n)]
             n = self._n
             stacked = [np.stack(np_flat[i * n:(i + 1) * n])
                        for i in range(n)]  # (n, *shape) per source rank
-            garr = self._global_array(stacked)          # (n, n, *shape)
+            garr = self._global_array(stacked)          # (n * n, *shape)
             out = self._launch(garr, "alltoall")
             return [list(row) for row in self._per_rank(out)]
 
@@ -604,15 +666,17 @@ class _MeshCollectives:
         the payload's leading axis splits into ``size`` equal blocks and
         rank ``i`` returns reduced block ``i`` — one compiled
         ``psum_scatter`` (or the binomial tree + slice when
-        ``deterministic``) over the mesh."""
+        ``deterministic``) over the mesh. The block has the type of this
+        rank's payload, as in :meth:`allreduce`."""
         det = (self.deterministic_collectives if deterministic is None
                else deterministic)
         from ..collectives_generic import canonical_combine, check_op
 
         check_op(op)
+        host_tree = self._mesh is None or callable(op)
 
         def leader(slots: List[Any]) -> List[Any]:
-            np_slots = self._host_arrays(slots)
+            np_slots = self._arrays(slots, on_host=host_tree)
             self._validate_payloads(np_slots)
             shape = np_slots[0].shape
             if len(shape) < 1 or shape[0] % self._n:
@@ -621,23 +685,25 @@ class _MeshCollectives:
                     f"{shape or 'scalar'} must divide into {self._n} "
                     f"equal blocks")
             m = shape[0] // self._n
-            if self._mesh is None or callable(op):
+            if host_tree:
                 total = canonical_combine(np_slots, op)
                 return [total[i * m:(i + 1) * m].copy()
                         for i in range(self._n)]
             garr = self._global_array(np_slots)
             out = self._launch(garr, "reduce_scatter", op, det)
-            return self._per_rank(out)
+            return self._per_rank(out, like=np_slots)
 
-        return self._coll.run(self._myrank(), data, leader,
-                              name="reduce_scatter")
+        return self._coll.run(
+            self._myrank(), data, leader, name="reduce_scatter",
+            path=lambda slots: self._path(slots, host_tree))
 
     def scan(self, data: Any, op: "OpLike" = "sum") -> Any:
         """Inclusive prefix reduction in rank order, as ONE compiled
         program (``parallel.collectives.prefix_reduce`` — the jittable
         MPI_Scan whose left-fold order is the cross-backend bitwise
         contract); scalars, objects, and callable ops fold on the host
-        in the same order."""
+        in the same order. On the compiled path the prefix has the type
+        of this rank's payload, as in :meth:`allreduce`."""
         return self._prefix(data, op, exclusive=False)
 
     def exscan(self, data: Any, op: "OpLike" = "sum") -> Optional[Any]:
@@ -649,16 +715,18 @@ class _MeshCollectives:
 
         check_op(op)
 
-        def leader(slots: List[Any]) -> List[Any]:
-            np_slots = self._uniform_arrays(slots)
+        def host_fold(slots: List[Any]) -> bool:
             # The compiled path is float/int/uint only: jnp's
             # add/multiply/minimum/maximum reject bool and complex in
             # ways numpy's don't, and prefix_reduce's exclusive identity
             # doesn't exist for them either — those (plus scalars,
             # objects, callable ops, oversubscription) take the host
             # fold, identical order.
-            if np_slots is None or callable(op) or self._mesh is None \
-                    or np_slots[0].dtype.kind not in "fiu":
+            return callable(op) or not self._uniform(slots) \
+                or slots[0].dtype.kind not in "fiu"
+
+        def leader(slots: List[Any]) -> List[Any]:
+            if host_fold(slots):
                 # Raw slots (combine() normalizes operands), so rank 0's
                 # inclusive result stays the caller's own payload type —
                 # matching collectives_generic.scan. One running left
@@ -674,15 +742,19 @@ class _MeshCollectives:
                 if exclusive:
                     return [None] + prefixes
                 return prefixes + [acc]
+            np_slots = self._arrays(slots)
             self._validate_payloads(np_slots)
             per = self._per_rank(self._launch(
-                self._global_array(np_slots), "prefix", op, exclusive))
+                self._global_array(np_slots), "prefix", op, exclusive),
+                like=np_slots)
             if exclusive:
                 per = [None] + list(per[1:])  # rank 0: MPI_Exscan contract
             return per
 
-        return self._coll.run(self._myrank(), data, leader,
-                              name="exscan" if exclusive else "scan")
+        return self._coll.run(
+            self._myrank(), data, leader,
+            name="exscan" if exclusive else "scan",
+            path=lambda slots: self._path(slots, host_fold(slots)))
 
 
 class XlaNetwork:

@@ -85,6 +85,34 @@ def test_allreduce_hierarchical_sum():
         np.testing.assert_array_equal(v, want)
 
 
+def test_allreduce_device_payload_gives_host_result():
+    """The inner xla driver returns a device payload's result on the
+    device; the hybrid driver's contract stays host-side: numpy out,
+    the same bits as for the numpy payload."""
+    import jax
+
+    def fn_for(net):
+        def fn():
+            net.init()
+            x = np.arange(6, dtype=np.float32) * 0.1 + net.rank()
+            on_device = jax.device_put(x, net._inner.device())
+            out = (net.allreduce(on_device), net.allreduce(x),
+                   net.reduce_scatter(jax.device_put(
+                       np.arange(8, dtype=np.float32) + net.rank(),
+                       net._inner.device())))
+            net.finalize()
+            return out
+        return fn
+
+    got = run_world(fn_for)
+    for me, (from_device, from_host, block) in enumerate(got):
+        assert type(from_device) is np.ndarray and type(block) is np.ndarray
+        np.testing.assert_array_equal(from_device, from_host)
+        np.testing.assert_array_equal(
+            block, (np.arange(8, dtype=np.float32) * WORLD
+                    + sum(range(WORLD)))[2 * me:2 * me + 2])
+
+
 @pytest.mark.parametrize("root", [0, 3])
 def test_bcast_from_either_host(root):
     def fn_for(net):

@@ -884,3 +884,364 @@ def test_bench_flash_tune_path_runs_on_cpu(monkeypatch, tmp_path):
     assert r["train_tokens_per_s"] > 0
     # the sweep table came through (interpret-mode kernel on CPU)
     assert any(k.startswith("flash_tune") for k in r)
+
+
+# --------------------------------------------------------------------------
+# Device payloads (ISSUE 28): a jax.Array with ndim >= 1 goes into the
+# compiled collective as the shard it is, and the rank gets its result as
+# a jax.Array on its own device; everything else takes the numpy path.
+# --------------------------------------------------------------------------
+
+DEV_RANKS = 4
+
+
+def _payload(rank, elems=8):
+    """Float32 noise whose sum depends on the order of the additions."""
+    return np.random.default_rng(100 + rank).standard_normal(
+        elems).astype(np.float32) * np.float32(1 + rank)
+
+
+def _oracle(kind, op, values, me, root=1):
+    """What rank ``me`` of ``len(values)`` gets, by numpy in the canonical
+    order (``collectives_generic``)."""
+    from mpi_tpu.collectives_generic import canonical_combine, combine
+
+    n = len(values)
+    if kind in ("allreduce", "reduce"):
+        total = canonical_combine(values, op)
+        return total if kind == "allreduce" or me == root else None
+    if kind == "reduce_scatter":
+        m = values[0].shape[0] // n
+        return canonical_combine(values, op)[me * m:(me + 1) * m]
+    upto = me if kind == "exscan" else me + 1
+    if upto == 0:
+        return None
+    acc = values[0]
+    for v in values[1:upto]:
+        acc = combine(acc, v, op)
+    return acc
+
+
+class _SpyNumpy:
+    """``numpy`` for ``backends/xla.py`` that notes every ``asarray`` of a
+    ``jax.Array``: the device-to-host reads of the driver."""
+
+    def __init__(self):
+        self.reads = []
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kwargs):
+        import jax
+
+        if isinstance(a, jax.Array):
+            self.reads.append(a.shape)
+        return np.asarray(a, *args, **kwargs)
+
+
+@pytest.fixture
+def host_reads(monkeypatch):
+    from mpi_tpu.backends import xla as xla_mod
+
+    spy = _SpyNumpy()
+    monkeypatch.setattr(xla_mod, "np", spy)
+    return spy.reads
+
+
+@pytest.fixture
+def recording():
+    from mpi_tpu.utils import trace
+
+    was = trace.enabled()
+    trace.clear()
+    trace.enable()
+    yield trace
+    if not was:
+        trace.disable()
+    trace.clear()
+
+
+def _device_program(kind, op, engine, how="own"):
+    """A rank program: the collective ``kind`` three times over the same
+    values — device payloads on every rank, numpy payloads on every rank,
+    and mixed (odd ranks device, even ranks numpy)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpi_tpu.comm import comm_world
+
+    def main():
+        mpi_tpu.init()
+        try:
+            net = api.registered()
+            world = comm_world()
+            comm = world if engine == "world" \
+                else world.split(color=world.rank() % 2)
+            me, dev = comm.rank(), net.device()
+            x = _payload(world.rank())
+            if how == "own":
+                on_device = jax.device_put(x, dev)
+            elif how == "other_device":
+                on_device = jax.device_put(
+                    x, net.device((world.rank() + 1) % world.size()))
+            else:  # uncommitted: wherever jax's default device is
+                on_device = jnp.asarray(x)
+                assert not on_device.committed
+            kw = {"op": op, **({"root": 1} if kind == "reduce" else {})}
+            call = getattr(comm, kind)
+            got = {"device": call(on_device, **kw),
+                   "numpy": call(x, **kw),
+                   "mixed": call(on_device if me % 2 else x, **kw)}
+            # No donation: the caller's payload is still there, unchanged.
+            np.testing.assert_array_equal(np.asarray(on_device), x)
+            return {"me": me, "members": comm.members, "device": dev,
+                    "got": got}
+        finally:
+            mpi_tpu.finalize()
+
+    return main
+
+
+def _check_device_results(seen, kind, op):
+    import jax
+
+    for s in seen:
+        me, dev = s["me"], s["device"]
+        values = [_payload(w) for w in s["members"]]
+        want = _oracle(kind, op, values, me)
+        for how, got in s["got"].items():
+            stays = how == "device" or (how == "mixed" and me % 2 == 1)
+            if want is None:
+                assert got is None, (kind, how, me)
+                continue
+            if stays:
+                assert isinstance(got, jax.Array), (kind, how, type(got))
+                assert got.committed and got.devices() == {dev}, \
+                    (kind, how, got.devices(), dev)
+            else:
+                assert type(got) is np.ndarray, (kind, how, type(got))
+            assert got.shape == want.shape and got.dtype == want.dtype
+            # Bitwise: the program for a device payload is the program
+            # for the same numpy payload, and both replay the host tree.
+            np.testing.assert_array_equal(np.asarray(got), want,
+                                          err_msg=f"{kind} {how} {me}")
+
+
+DEVICE_KINDS = [("allreduce", "sum"), ("allreduce", "max"),
+                ("allreduce", "min"), ("allreduce", "prod"),
+                ("reduce", "sum"), ("reduce_scatter", "sum"),
+                ("scan", "sum"), ("exscan", "prod")]
+
+
+class TestDevicePayloads:
+    @pytest.mark.parametrize("engine", ["world", "group"])
+    @pytest.mark.parametrize("kind,op", DEVICE_KINDS)
+    def test_result_follows_payload_type_bitwise(self, kind, op, engine):
+        """Device in -> jax.Array out on the rank's own device; numpy in
+        -> numpy out; mixed ranks -> each by its own payload; all three
+        bitwise equal to the canonical host order."""
+        n = DEV_RANKS if engine == "world" else 2 * DEV_RANKS
+        seen = run_spmd(_device_program(kind, op, engine), net=XlaNetwork(
+            n=n, deterministic_collectives=True))
+        assert all(len(s["members"]) == DEV_RANKS for s in seen)
+        _check_device_results(seen, kind, op)
+
+    @pytest.mark.parametrize("how", ["other_device", "uncommitted"])
+    def test_payload_elsewhere_is_moved_to_the_ranks_device(self, how,
+                                                            host_reads):
+        seen = run_spmd(_device_program("allreduce", "sum", "world", how),
+                        net=XlaNetwork(n=DEV_RANKS,
+                                       deterministic_collectives=True))
+        _check_device_results(seen, "allreduce", "sum")
+        # Moved device to device: the only host reads are the numpy
+        # ranks' results (the all-numpy call, then the mixed call's
+        # even ranks).
+        assert len(host_reads) == DEV_RANKS + DEV_RANKS // 2, host_reads
+
+    @pytest.mark.parametrize("kind,op", [("allreduce", "sum"),
+                                         ("reduce_scatter", "sum"),
+                                         ("scan", "max")])
+    def test_device_call_reads_nothing_to_the_host(self, kind, op,
+                                                   host_reads):
+        import jax
+
+        def main():
+            mpi_tpu.init()
+            try:
+                x = jax.device_put(_payload(mpi_tpu.rank()),
+                                   api.registered().device())
+                return getattr(mpi_tpu, kind)(x, op=op)
+            finally:
+                mpi_tpu.finalize()
+
+        out = run_spmd(main, net=XlaNetwork(n=DEV_RANKS))
+        assert host_reads == []
+        assert all(isinstance(o, jax.Array) for o in out)
+
+    def test_zero_d_device_array_keeps_the_numpy_answer(self):
+        import jax.numpy as jnp
+
+        def main():
+            mpi_tpu.init()
+            try:
+                r = mpi_tpu.rank()
+                return (mpi_tpu.allreduce(jnp.float32(r + 0.5)),
+                        mpi_tpu.allreduce(np.float32(r + 0.5)))
+            finally:
+                mpi_tpu.finalize()
+
+        for from_device, from_numpy in run_spmd(
+                main, net=XlaNetwork(n=DEV_RANKS)):
+            assert type(from_device) is type(from_numpy) is np.float32
+            assert from_device == from_numpy == 8.0
+
+    @pytest.mark.parametrize("fault", ["shape", "float64_without_x64"])
+    def test_bad_device_payloads_raise_everywhere_unread(self, fault,
+                                                         host_reads):
+        import jax
+
+        def main():
+            mpi_tpu.init()
+            r = mpi_tpu.rank()
+            dev = api.registered().device()
+            if fault == "shape":
+                x = jax.device_put(
+                    np.ones(3 if r != 2 else 4, np.float32), dev)
+            else:
+                x = jax.device_put(np.ones(3, np.float64), dev)
+                assert x.dtype == np.float64
+                mpi_tpu.barrier()  # every rank has its float64 payload
+                if r == 0:
+                    jax.config.update("jax_enable_x64", False)
+                mpi_tpu.barrier()
+            try:
+                mpi_tpu.allreduce(x)
+                return None
+            except mpi_tpu.MpiError as exc:
+                return str(exc)
+            finally:
+                mpi_tpu.barrier()
+                if r == 0:
+                    jax.config.update("jax_enable_x64", True)
+
+        try:
+            out = run_spmd(main, net=XlaNetwork(n=DEV_RANKS))
+        finally:
+            jax.config.update("jax_enable_x64", True)  # conftest's setting
+        word = "mismatch" if fault == "shape" else "downcast"
+        assert all(o is not None and word in o for o in out), out
+        assert host_reads == []
+
+    @pytest.mark.parametrize("payloads", ["device", "numpy", "mixed"])
+    def test_spans_and_counters_by_path(self, payloads, recording):
+        import jax
+
+        def main():
+            mpi_tpu.init()
+            try:
+                r = mpi_tpu.rank()
+                x = _payload(r)
+                if payloads == "device" or (payloads == "mixed" and r % 2):
+                    x = jax.device_put(x, api.registered().device())
+                return mpi_tpu.allreduce(x)
+            finally:
+                mpi_tpu.finalize()
+
+        run_spmd(main, net=XlaNetwork(n=DEV_RANKS))
+        spans = [e for e in recording.events()
+                 if e.get("op") == "allreduce"]
+        names = [e["name"] for e in spans]
+        leader, = [e for e in spans if e["name"] == "xla.coll.leader"]
+        assert leader["path"] == {"numpy": "host"}.get(payloads, payloads)
+        assert names.count("xla.coll.device_put") == 1
+        assert names.count("xla.coll.launch") == 1
+        copies = 0 if payloads == "device" else 1
+        assert names.count("xla.coll.host_read") == copies
+        assert names.count("xla.coll.read_back") == copies
+        on_device = {"device": DEV_RANKS, "numpy": 0,
+                     "mixed": DEV_RANKS // 2}[payloads]
+        counters = recording.counters()
+        assert counters.get("xla.coll.device_payloads", 0) == on_device
+        assert counters.get("xla.coll.host_payloads", 0) \
+            == DEV_RANKS - on_device
+
+    @pytest.mark.parametrize("why", ["oversubscribed", "callable_op"])
+    def test_host_tree_keeps_numpy_results(self, why, recording):
+        """No mesh, or an op XLA cannot compile: the host tree, as
+        before, whatever the payload's type."""
+        import jax
+
+        def main():
+            mpi_tpu.init()
+            try:
+                x = _payload(mpi_tpu.rank())
+                op = (lambda a, b: a + b) if why == "callable_op" else "sum"
+                return (mpi_tpu.allreduce(jax.device_put(
+                    x, api.registered().device()), op=op),
+                    mpi_tpu.allreduce(x, op=op))
+            finally:
+                mpi_tpu.finalize()
+
+        n = 12 if why == "oversubscribed" else DEV_RANKS
+        out = run_spmd(main, net=XlaNetwork(n=n, oversubscribe=True))
+        from mpi_tpu.collectives_generic import canonical_combine
+
+        want = canonical_combine([_payload(r) for r in range(n)], "sum")
+        for from_device, from_numpy in out:
+            assert type(from_device) is type(from_numpy) is np.ndarray
+            np.testing.assert_array_equal(from_device, want)
+            np.testing.assert_array_equal(from_numpy, want)
+        leaders = [e for e in recording.events()
+                   if e["name"] == "xla.coll.leader"
+                   and e.get("op") == "allreduce"]
+        assert [e["path"] for e in leaders] == ["host", "host"]
+
+    @pytest.mark.parametrize("kind", ["allgather", "gather", "bcast",
+                                      "alltoall", "scatter"])
+    def test_replicated_and_list_collectives_keep_numpy_results(
+            self, kind, host_reads):
+        """Not this PR's collectives: a device payload gives what it gave
+        (numpy), though allgather / gather / bcast no longer read it to
+        the host on the way in."""
+        import jax
+
+        n = DEV_RANKS
+
+        def main():
+            mpi_tpu.init()
+            try:
+                r, dev = mpi_tpu.rank(), api.registered().device()
+                if kind in ("allgather", "gather"):
+                    got = getattr(mpi_tpu, kind)(
+                        jax.device_put(_payload(r), dev))
+                    if kind == "gather" and r != 0:
+                        assert got is None
+                        return []
+                    return got
+                if kind == "bcast":
+                    return [mpi_tpu.bcast(jax.device_put(_payload(2), dev)
+                                          if r == 2 else None, root=2)]
+                if kind == "alltoall":
+                    return mpi_tpu.alltoall([
+                        jax.device_put(_payload(r * n + j), dev)
+                        for j in range(n)])
+                return [mpi_tpu.scatter([
+                    jax.device_put(_payload(j), dev) for j in range(n)]
+                    if r == 1 else None, root=1)]
+            finally:
+                mpi_tpu.finalize()
+
+        out = run_spmd(main, net=XlaNetwork(n=n))
+        for r, got in enumerate(out):
+            assert all(type(g) is np.ndarray for g in got), kind
+            want = {"allgather": [_payload(i) for i in range(n)],
+                    "gather": [_payload(i) for i in range(n)],
+                    "bcast": [_payload(2)],
+                    "alltoall": [_payload(i * n + r) for i in range(n)],
+                    "scatter": [_payload(r)]}[kind]
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(g, w)
+        if kind in ("allgather", "gather", "bcast"):
+            # Only the replicated result crosses to the host, once.
+            assert len(host_reads) == 1, host_reads
