@@ -87,6 +87,7 @@ def _forward_cached(params, tokens, cache, n_valid, cfg: TransformerConfig):
     """Run ``tokens`` (b, s) starting at absolute position ``n_valid``,
     writing their k/v into the cache. Returns (logits, new_cache)."""
     b, s = tokens.shape
+    _check_cfg(cfg)
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     if not cfg.rope:
         pos_emb = lax.dynamic_slice_in_dim(
@@ -112,6 +113,23 @@ def _forward_cached(params, tokens, cache, n_valid, cfg: TransformerConfig):
                    params["final_ln"]["bias"].astype(x.dtype))
     logits = logits_matmul(x, params["embed"])
     return logits, new_cache
+
+
+def _check_cfg(cfg: TransformerConfig) -> None:
+    """Refuse, by name, what the cached forward pass below cannot do."""
+    if cfg.attention_impl == "eva":
+        raise NotImplementedError(
+            "mpi_tpu: generate cannot decode attention_impl='eva': it "
+            "needs a cache of the current window's keys and values beside "
+            "the chunk summaries (K_c, V_c) of every earlier window, and "
+            "this cache holds one key and value per position; train with "
+            "make_train_step, score with forward")
+    beyond = cfg.beyond_classic_block()
+    if beyond:
+        raise NotImplementedError(
+            f"mpi_tpu: generate does not handle {', '.join(beyond)}: its "
+            f"cached forward pass norms, sums and projects the classic "
+            f"block's way")
 
 
 def _empty_cache(cfg: TransformerConfig, batch: int):

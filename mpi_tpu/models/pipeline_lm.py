@@ -60,6 +60,16 @@ def _check_cfg(cfg: TransformerConfig, pp: int) -> None:
         raise ValueError(
             "mpi_tpu: MoE routes over the 'ep' axis — use the sharded "
             "(non-pp) path for expert parallelism")
+    beyond = cfg.beyond_classic_block()
+    if beyond:
+        # The pipeline has its own embedding, final norm and tied logits
+        # round block_body: it would norm, sum and project the classic
+        # way whatever the configuration says.
+        raise ValueError(
+            f"mpi_tpu: the pipelined train step does not handle "
+            f"{', '.join(beyond)} (its embedding, final norm and logits "
+            f"are the classic block's); train such a model with "
+            f"make_train_step")
     if cfg.attention_impl not in ("dense", "flash", "blockwise"):
         raise ValueError(
             f"mpi_tpu: pipeline stages need a per-device attention impl "

@@ -70,8 +70,15 @@ class TransformerConfig:
     # reshard, parallel.ulysses) | "ulysses_flash" (same, Pallas kernel
     # per head group). The ring/zigzag/ulysses family needs a mesh
     # with 'sp'; "flash" on a mesh runs per (dp, tp) shard and refuses
-    # sp > 1.
+    # sp > 1. "eva" (ops/eva_attention.py) is chunked linear attention:
+    # exact causal softmax inside aligned windows of ``eva_window``
+    # positions plus one learned-pooled summary per ``eva_chunk``
+    # positions of every earlier window, one softmax over both; its two
+    # pooling vectors a head (``eva_phi``, ``eva_mu``) are block
+    # parameters. Runs per (dp, tp) shard like "flash".
     attention_impl: str = "dense"
+    eva_window: int = 2048
+    eva_chunk: int = 16
     # Decode-time (KV-cache) attention: "dense" (jnp einsum chain, the
     # oracle) | "flash" (Pallas flash-decode kernel — one VMEM pass
     # over the cache per step, ops/decode_attention.py). Applies to
@@ -112,6 +119,68 @@ class TransformerConfig:
     # (the work-balance trick assumes the triangular mask) and raise
     # at the ring layer.
     causal: bool = True
+    # Normalisation: "layernorm" (scale and bias) | "rmsnorm_unit_offset"
+    # (x * rsqrt(mean(x^2) + 1e-5) * (1 + scale), scale starting at
+    # zero, no bias).
+    norm: str = "layernorm"
+    # Dense FFN: "gelu" (two matrices, w2(gelu(w1 x))) | "swiglu" (three,
+    # w2(silu(w1 x) * (w3 x))).
+    ffn: str = "gelu"
+    # Output head: the embedding's transpose (tied), or an untied matrix
+    # ``head`` of ``n_pred_heads * vocab`` rows whose logits are float32.
+    # With ``n_pred_heads`` = P > 1, head p at position t predicts token
+    # t + 1 + p (multi-token prediction): ``forward`` returns
+    # (batch, seq, P, vocab) and the loss is the mean over the heads of
+    # each head's mean cross-entropy over the positions whose target
+    # exists.
+    tie_embeddings: bool = True
+    n_pred_heads: int = 1
+    # dtype of the residual stream between blocks (a dtype or its name);
+    # None = the compute dtype. "float32" keeps the sum x + f(norm(x)) in
+    # float32 while matmuls and attention run in ``dtype``.
+    residual_dtype: Any = None
+
+    def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm_unit_offset"):
+            raise ValueError(
+                f"mpi_tpu: unknown norm {self.norm!r}: expected "
+                f"layernorm|rmsnorm_unit_offset")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(
+                f"mpi_tpu: unknown ffn {self.ffn!r}: expected gelu|swiglu")
+        if self.ffn != "gelu" and self.n_experts > 0:
+            raise ValueError(
+                f"mpi_tpu: ffn={self.ffn!r} is the dense FFN's; the experts "
+                f"of models/moe.py are two-matrix GELU")
+        if self.n_pred_heads < 1 or (self.n_pred_heads > 1
+                                     and self.tie_embeddings):
+            raise ValueError(
+                f"mpi_tpu: n_pred_heads={self.n_pred_heads} needs "
+                f"tie_embeddings=False (each head has its own output rows)")
+        if self.attention_impl == "eva" and not self.causal:
+            raise ValueError("mpi_tpu: attention_impl='eva' is causal only")
+
+    @property
+    def stream_dtype(self):
+        """dtype of the residual stream."""
+        return jnp.dtype(self.dtype if self.residual_dtype is None
+                         else self.residual_dtype)
+
+    def beyond_classic_block(self) -> Tuple[str, ...]:
+        """The settings, as ``name=value``, that leave the block this
+        module began with (LayerNorm, two-matrix GELU FFN, tied embedding,
+        one prediction head, one dtype throughout, attention over the
+        whole prefix): what a caller with its own copy of the embedding,
+        the norms or the logits (``generate``, ``pipeline_lm``) must
+        refuse by name until it handles them."""
+        classic = TransformerConfig()
+        names = ["norm", "ffn", "tie_embeddings", "n_pred_heads",
+                 "residual_dtype"]
+        out = [f"{n}={getattr(self, n)!r}" for n in names
+               if getattr(self, n) != getattr(classic, n)]
+        if self.attention_impl == "eva":
+            out.append("attention_impl='eva'")
+        return tuple(out)
 
     @property
     def head_dim(self) -> int:
@@ -144,10 +213,15 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     params: Dict[str, Any] = {
         "embed": _dense_init(keys[0], (cfg.vocab, cfg.d_model), pd,
                              cfg.d_model),
-        "final_ln": {"scale": jnp.ones((cfg.d_model,), pd),
-                     "bias": jnp.zeros((cfg.d_model,), pd)},
+        "final_ln": _norm_init(cfg),
         "blocks": [],
     }
+    if not cfg.tie_embeddings:
+        # Keys for the leaves newer than the classic block are folded in,
+        # not split off, so a classic configuration draws what it drew.
+        params["head"] = _dense_init(
+            jax.random.fold_in(keys[0], 1),
+            (cfg.n_pred_heads * cfg.vocab, cfg.d_model), pd, cfg.d_model)
     if not cfg.rope:  # rope needs no learned position table
         params["pos"] = _dense_init(keys[1], (cfg.max_seq, cfg.d_model),
                                     pd, cfg.d_model)
@@ -156,8 +230,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         h, d, f = cfg.n_heads, cfg.d_model, cfg.d_ff
         hd, kv = cfg.head_dim, cfg.kv_heads
         blk = {
-            "ln1": {"scale": jnp.ones((d,), pd), "bias": jnp.zeros((d,), pd)},
-            "ln2": {"scale": jnp.ones((d,), pd), "bias": jnp.zeros((d,), pd)},
+            "ln1": _norm_init(cfg),
+            "ln2": _norm_init(cfg),
             "wq": _dense_init(ks[0], (d, h, hd), pd, d),
             "wk": _dense_init(ks[1], (d, kv, hd), pd, d),
             "wv": _dense_init(ks[2], (d, kv, hd), pd, d),
@@ -170,8 +244,26 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         else:
             blk["w1"] = _dense_init(ks[4], (d, f), pd, d)
             blk["w2"] = _dense_init(ks[5], (f, d), pd, f)
+            if cfg.ffn == "swiglu":
+                blk["w3"] = _dense_init(jax.random.fold_in(keys[2 + i], 6),
+                                        (d, f), pd, d)
+        if cfg.attention_impl == "eva":
+            # Unit normal, not zero: with keys and queries of unit scale
+            # the pooling weights are uneven and the summaries' offset
+            # moves their scores, so both weigh in every comparison.
+            blk["eva_phi"] = jax.random.normal(
+                jax.random.fold_in(keys[2 + i], 7), (h, hd)).astype(pd)
+            blk["eva_mu"] = jax.random.normal(
+                jax.random.fold_in(keys[2 + i], 8), (h, hd)).astype(pd)
         params["blocks"].append(blk)
     return params
+
+
+def _norm_init(cfg: TransformerConfig) -> Dict[str, Any]:
+    d, pd = cfg.d_model, cfg.param_dtype
+    if cfg.norm == "layernorm":
+        return {"scale": jnp.ones((d,), pd), "bias": jnp.zeros((d,), pd)}
+    return {"scale": jnp.zeros((d,), pd)}   # the weight is 1 + scale
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -181,9 +273,11 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     row-parallel; w1 column-, w2 row-parallel over d_ff. Everything small
     (layernorms, biases, positional table) is replicated. The embedding is
     vocab-sharded over tp (the logits matmul then reduces over tp)."""
+    norm = ({"scale": P(), "bias": P()} if cfg.norm == "layernorm"
+            else {"scale": P()})
     blk = {
-        "ln1": {"scale": P(), "bias": P()},
-        "ln2": {"scale": P(), "bias": P()},
+        "ln1": dict(norm),
+        "ln2": dict(norm),
         "wq": P(None, "tp", None),
         "wk": P(None, "tp", None),
         "wv": P(None, "tp", None),
@@ -196,11 +290,18 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     else:
         blk["w1"] = P(None, "tp")
         blk["w2"] = P("tp", None)
+        if cfg.ffn == "swiglu":
+            blk["w3"] = P(None, "tp")
+    if cfg.attention_impl == "eva":
+        blk["eva_phi"] = P("tp", None)
+        blk["eva_mu"] = P("tp", None)
     specs = {
         "embed": P("tp", None),
-        "final_ln": {"scale": P(), "bias": P()},
+        "final_ln": dict(norm),
         "blocks": [dict(blk) for _ in range(cfg.n_layers)],
     }
+    if not cfg.tie_embeddings:
+        specs["head"] = P("tp", None)
     if not cfg.rope:
         specs["pos"] = P()
     return specs
@@ -210,10 +311,25 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
 # Forward
 # --------------------------------------------------------------------------
 
-def _layernorm(x, scale, bias, eps=1e-5):
+_NORM_EPS = 1e-5   # both norms; every configuration run so far states it
+
+
+def _layernorm(x, scale, bias, eps=_NORM_EPS):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     return (x - mu) * lax.rsqrt(var + eps) * scale + bias
+
+
+def _norm(x, p, cfg: TransformerConfig):
+    """The configured normalisation of the residual stream ``x``, computed
+    in the stream's dtype and handed on in the compute dtype."""
+    if cfg.norm == "layernorm":
+        y = _layernorm(x, p["scale"].astype(x.dtype),
+                       p["bias"].astype(x.dtype))
+    else:
+        ms = jnp.mean(x * x, axis=-1, keepdims=True)
+        y = x * lax.rsqrt(ms + _NORM_EPS) * (1 + p["scale"].astype(x.dtype))
+    return y.astype(cfg.dtype)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
@@ -275,22 +391,21 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     if impl == "flash":
         from ..ops import flash_attention
 
-        if mesh is None or mesh.size == 1:
-            ctx = flash_attention(q, k, v, cfg.causal)
-        else:
-            # GSPMD cannot partition a Mosaic kernel, so on a real mesh
-            # the kernel runs per shard like the ring/ulysses family:
-            # batch over dp, heads (q and grouped kv alike) over tp.
-            if mesh.shape.get("sp", 1) > 1:
-                raise ValueError(
-                    "attention_impl='flash' attends within one device's "
-                    "sequence; a mesh with sp > 1 needs ring_flash|"
-                    "zigzag_flash|ulysses_flash")
-            spec = sanitize_spec(P("dp", None, "tp", None), mesh)
-            ctx = jax.shard_map(
-                lambda q_, k_, v_: flash_attention(q_, k_, v_, cfg.causal),
-                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_vma=False)(q, k, v)
+        ctx = _kernel_per_shard(
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, cfg.causal),
+            mesh, impl, "needs ring_flash|zigzag_flash|ulysses_flash",
+            (q, k, v))
+    elif impl == "eva":
+        from ..ops import eva_attention
+
+        def eva(q_, k_, v_, phi, mu):
+            return eva_attention(q_, k_, v_, phi.astype(q_.dtype),
+                                 mu.astype(q_.dtype), cfg.eva_window,
+                                 cfg.eva_chunk)
+
+        ctx = _kernel_per_shard(
+            eva, mesh, impl, "is not supported", (q, k, v),
+            per_head=(blk["eva_phi"], blk["eva_mu"]))
     elif impl == "blockwise":
         from ..ops import blockwise_attention
 
@@ -327,8 +442,29 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         raise ValueError(
             f"unknown attention_impl {impl!r}: expected dense|flash|"
             f"blockwise|ring|ring_flash|zigzag|zigzag_flash|ulysses|"
-            f"ulysses_flash")
+            f"ulysses_flash|eva")
     return jnp.einsum("bshk,hkd->bsd", ctx, blk["wo"].astype(x.dtype))
+
+
+def _kernel_per_shard(fn, mesh: Optional[Mesh], impl: str, sp_advice: str,
+                      qkv, per_head=()):
+    """Run an attention kernel ``fn(q, k, v, *per_head)`` that attends
+    within one device's sequence. GSPMD cannot partition a Mosaic kernel,
+    so on a real mesh it runs per shard like the ring/ulysses family:
+    batch over dp, heads (q, grouped kv and the ``(heads, hd)`` vectors in
+    ``per_head`` alike) over tp; one chip and ``mesh=None`` call it
+    directly."""
+    if mesh is None or mesh.size == 1:
+        return fn(*qkv, *per_head)
+    if mesh.shape.get("sp", 1) > 1:
+        raise ValueError(
+            f"attention_impl={impl!r} attends within one device's "
+            f"sequence; a mesh with sp > 1 {sp_advice}")
+    spec = sanitize_spec(P("dp", None, "tp", None), mesh)
+    vec = sanitize_spec(P("tp", None), mesh)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec,) * 3 + (vec,) * len(per_head),
+        out_specs=spec, check_vma=False)(*qkv, *per_head)
 
 
 def sanitize_spec(spec: P, mesh: Optional[Mesh]) -> P:
@@ -368,7 +504,12 @@ def _ffn(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh]):
         return moe_ffn(x, blk["moe"], cfg.n_experts,
                        capacity_factor=cfg.capacity_factor, mesh=mesh,
                        top_k=cfg.moe_top_k)
-    h = jax.nn.gelu(jnp.einsum("bsd,df->bsf", x, blk["w1"].astype(x.dtype)))
+    h = jnp.einsum("bsd,df->bsf", x, blk["w1"].astype(x.dtype))
+    if cfg.ffn == "swiglu":
+        h = jax.nn.silu(h) * jnp.einsum("bsd,df->bsf", x,
+                                        blk["w3"].astype(x.dtype))
+    else:
+        h = jax.nn.gelu(h)
     y = jnp.einsum("bsf,fd->bsd", h, blk["w2"].astype(x.dtype))
     return y, jnp.zeros((), jnp.float32)
 
@@ -383,16 +524,16 @@ def block_body(x, blk, cfg: TransformerConfig,
     # The named scopes here and in forward_with_aux / token_xent / the
     # train step are what a profiler trace's device ops are grouped by
     # (docs/OBSERVABILITY.md): metadata only, the program is unchanged.
+    # ``x`` is the residual stream (``cfg.stream_dtype``); the norms hand
+    # the compute dtype to the matmuls and the sums are the stream's.
     with jax.named_scope("attn"):
-        h = _layernorm(x, blk["ln1"]["scale"].astype(x.dtype),
-                       blk["ln1"]["bias"].astype(x.dtype))
-        x = x + _attention(h, blk, cfg, mesh)
+        h = _norm(x, blk["ln1"], cfg)
+        x = x + _attention(h, blk, cfg, mesh).astype(x.dtype)
     x = _act_constraint(x, mesh)
     with jax.named_scope("ffn"):
-        h = _layernorm(x, blk["ln2"]["scale"].astype(x.dtype),
-                       blk["ln2"]["bias"].astype(x.dtype))
+        h = _norm(x, blk["ln2"], cfg)
         y, blk_aux = _ffn(h, blk, cfg, mesh)
-        x = x + y
+        x = x + y.astype(x.dtype)
     return _act_constraint(x, mesh), blk_aux
 
 
@@ -408,17 +549,40 @@ def token_xent(logits: jax.Array, targets: jax.Array) -> jax.Array:
         return jnp.mean(lse - tgt)
 
 
+def pred_heads_xent(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """Multi-token-prediction loss. ``logits`` ``(b, s, P, vocab)`` are
+    those of the inputs ``tokens[:, :s]``; head ``p`` at position ``t``
+    predicts ``tokens[:, t + 1 + p]``. Each head's cross-entropy is
+    averaged over the positions whose target exists (``t + 1 + p <= s``,
+    ``tokens`` being ``s + 1`` long), and the heads weigh equally."""
+    with jax.named_scope("logits_loss"):
+        _, s, heads, _ = logits.shape
+        at = (jnp.arange(s)[:, None] + 1 + jnp.arange(heads)[None, :])
+        exists = at <= s
+        targets = tokens[:, jnp.minimum(at, s)]               # (b, s, P)
+        logits32 = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits32, axis=-1)
+        tgt = jnp.take_along_axis(logits32, targets[..., None],
+                                  axis=-1)[..., 0]
+        nll = jnp.where(exists[None], lse - tgt, 0.0)
+        per_head = nll.sum(axis=(0, 1)) / (
+            tokens.shape[0] * exists.sum(axis=0))
+        return jnp.mean(per_head)
+
+
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
                      cfg: TransformerConfig,
                      mesh: Optional[Mesh] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """tokens (batch, seq) int32 → (logits (batch, seq, vocab), aux_loss).
-    ``aux_loss`` is the summed MoE load-balance penalty (0 for dense)."""
-    _, s = tokens.shape
+    ``aux_loss`` is the summed MoE load-balance penalty (0 for dense).
+    With ``cfg.n_pred_heads`` = P > 1 the logits are (batch, seq, P,
+    vocab); those of an untied head are float32."""
+    b, s = tokens.shape
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = params["embed"].astype(cfg.stream_dtype)[tokens]
         if not cfg.rope:
-            x = x + params["pos"].astype(cfg.dtype)[:s][None]
+            x = x + params["pos"].astype(cfg.stream_dtype)[:s][None]
     x = _act_constraint(x, mesh)
     aux = jnp.zeros((), jnp.float32)
 
@@ -429,10 +593,16 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
         x, blk_aux = block(x, blk)
         aux = aux + blk_aux
     with jax.named_scope("logits_loss"):
-        x = _layernorm(x, params["final_ln"]["scale"].astype(x.dtype),
-                       params["final_ln"]["bias"].astype(x.dtype))
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(x.dtype))
+        x = _norm(x, params["final_ln"], cfg)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(x.dtype))
+        else:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["head"].astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+            if cfg.n_pred_heads > 1:
+                logits = logits.reshape(b, s, cfg.n_pred_heads, cfg.vocab)
     return logits, aux
 
 
@@ -452,6 +622,8 @@ def loss_fn(params, tokens, cfg: TransformerConfig,
     log-prob tensor never exists, saving its HBM round-trips at large
     vocab (the backward of logsumexp produces the softmax directly)."""
     logits, aux = forward_with_aux(params, tokens[:, :-1], cfg, mesh)
+    if cfg.n_pred_heads > 1:
+        return pred_heads_xent(logits, tokens) + cfg.moe_aux_coef * aux
     return token_xent(logits, tokens[:, 1:]) + cfg.moe_aux_coef * aux
 
 

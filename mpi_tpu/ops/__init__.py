@@ -19,6 +19,7 @@ from .attention import (
 )
 from .autotune import tune_flash_blocks
 from .decode_attention import flash_decode_attention
+from .eva_attention import eva_attention, eva_summaries
 from .ring_collectives import (
     ring_allgather,
     ring_allgather_sharded,
@@ -33,6 +34,8 @@ __all__ = [
     "flash_attention_with_lse",
     "flash_block_defaults",
     "flash_decode_attention",
+    "eva_attention",
+    "eva_summaries",
     "set_flash_block_defaults",
     "tune_flash_blocks",
     "flash_chunk_bwd",

@@ -144,8 +144,15 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # --------------------------------------------------------------------------
 
 def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
-                seq_k: int):
-    """The (block_q, block_k) validity mask for grid cell (qi, ki)."""
+                seq_k: int, prefix=None):
+    """The (block_q, block_k) validity mask for grid cell (qi, ki).
+
+    ``prefix=(q_per, k_per)`` is the window-level mask EVA's summaries
+    need (:mod:`mpi_tpu.ops.eva_attention`): rows come in groups of
+    ``q_per``, columns in groups of ``k_per``, and a row sees the columns
+    of strictly earlier groups, ``col // k_per < row // q_per``.
+    ``block_q`` divides ``q_per`` (:func:`_prefix_end`), so the block's
+    rows share one group and the test is one compare with a scalar."""
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     col = ki * block_k + lax.broadcasted_iota(
@@ -153,11 +160,38 @@ def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
     valid = col < seq_k
     if causal:
         valid &= row >= col
+    if prefix is not None:
+        valid &= col < _prefix_end(qi, block_q, prefix)
     return valid
 
 
+def _prefix_end(qi, block_q: int, prefix):
+    """First column the rows of query block ``qi`` do NOT see under the
+    prefix mask ``(q_per, k_per)``: their group's index times ``k_per``."""
+    q_per, k_per = prefix
+    assert q_per % block_q == 0, (q_per, block_q)
+    return (qi * block_q) // q_per * k_per
+
+
+def _when_live(live, qi, ki, block_q: int, block_k: int, prefix, compute):
+    """Run ``compute`` unless the mask leaves grid cell (qi, ki) empty.
+    ``live`` is the kernel's own causal test (``None`` without a causal
+    mask: blocks above the diagonal are empty); a prefix mask adds the
+    blocks that start at or past the last column the rows see.
+    Skipping saves the cell's MXU matmuls; the mask math keeps the
+    skipped state consistent."""
+    if prefix is not None:
+        before = ki * block_k < _prefix_end(qi, block_q, prefix)
+        live = before if live is None else live & before
+    if live is None:
+        compute()
+    else:
+        pl.when(live)(compute)
+
+
 def _block_probs(q_ref, k_ref, lse_ref, qi, ki, *, causal: bool,
-                 scale: float, block_q: int, block_k: int, seq_k: int):
+                 scale: float, block_q: int, block_k: int, seq_k: int,
+                 prefix=None):
     """Backward-pass helper: rebuild this block's softmax probabilities
     from (q, k, lse) — the FlashAttention-2 trick that replaces O(s²)
     stored residuals. Returns (q, k) in their stored dtype (bf16 dots
@@ -168,7 +202,7 @@ def _block_probs(q_ref, k_ref, lse_ref, qi, ki, *, causal: bool,
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k)
+    valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k, prefix)
     p = jnp.where(valid, jnp.exp(logits - lse_ref[0, 0][:, None]), 0.0)
     return q, k, p
 
@@ -176,7 +210,7 @@ def _block_probs(q_ref, k_ref, lse_ref, qi, ki, *, causal: bool,
 def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
                           m_scr, l_scr, acc_scr, *, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          seq_k: int):
+                          seq_k: int, prefix=None):
     """Forward kernel that also emits the log-sum-exp rows — the only
     residual the backward kernels need (FlashAttention-2 scheme: softmax
     is reconstructed from (q, k, lse), never stored)."""
@@ -202,7 +236,7 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k)
+        valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k, prefix)
         logits = jnp.where(valid, logits, _NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
@@ -214,14 +248,9 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        # Blocks entirely above the diagonal contribute nothing — skip the
-        # two MXU matmuls (the mask math keeps skipped-state consistent).
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    # Blocks the mask leaves empty contribute nothing.
+    _when_live(ki * block_k <= qi * block_q + block_q - 1 if causal else None,
+               qi, ki, block_q, block_k, prefix, compute)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -232,7 +261,8 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal: bool, scale: float,
-                         block_q: int, block_k: int, seq_k: int):
+                         block_q: int, block_k: int, seq_k: int,
+                         prefix=None):
     """dq = Σ_k  ds·K  with ds = P ∘ (dP − δ), P rebuilt from (q, k, lse).
     Grid (bh, nq, nk): each (bh, qi) accumulates over the key blocks."""
     qi = pl.program_id(1)
@@ -248,7 +278,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         g = g_ref[0]
         _, k, p = _block_probs(q_ref, k_ref, lse_ref, qi, ki,
                                causal=causal, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_k=seq_k)
+                               block_k=block_k, seq_k=seq_k, prefix=prefix)
         dp = jax.lax.dot_general(
             g, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -257,12 +287,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _when_live(ki * block_k <= qi * block_q + block_q - 1 if causal else None,
+               qi, ki, block_q, block_k, prefix, compute)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -272,7 +298,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          seq_k: int, nq: int):
+                          seq_k: int, nq: int, prefix=None):
     """dv = Σ_q Pᵀ·dO and dk = Σ_q dsᵀ·Q. Grid (b·kv_heads, nk, G·nq):
     each (bh, ki) accumulates over the query blocks of EVERY query head
     in the kv head's group (G = n_heads / kv_heads; 1 for MHA) — the
@@ -293,7 +319,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         g = g_ref[0]
         q, _, p = _block_probs(q_ref, k_ref, lse_ref, qi, ki,
                                causal=causal, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_k=seq_k)
+                               block_k=block_k, seq_k=seq_k, prefix=prefix)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -305,14 +331,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        # Query blocks entirely above the diagonal see nothing of this
-        # key block — skip all four MXU matmuls.
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            compute()
-    else:
-        compute()
+    # Query blocks that see nothing of this key block skip all four MXU
+    # matmuls.
+    _when_live(qi * block_q + block_q - 1 >= ki * block_k if causal else None,
+               qi, ki, block_q, block_k, prefix, compute)
 
     @pl.when(t == nt - 1)
     def _():
@@ -446,7 +468,20 @@ def _gqa_layout(q, k, v):
     return qf, kf, vf, kv_index, group
 
 
-def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret):
+def _query_block(s: int, block_q: int, prefix) -> int:
+    """The query block: a divisor of the sequence and, under a prefix
+    mask, of its row groups too, so no block straddles two groups."""
+    if prefix is None:
+        return _pick_block(s, block_q)
+    if s % prefix[0]:
+        raise ValueError(
+            f"mpi_tpu: a prefix mask's row groups of {prefix[0]} must "
+            f"divide the {s} query rows")
+    return _pick_block(prefix[0], block_q)
+
+
+def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret,
+                          prefix=None):
     """Forward + log-sum-exp residuals: (out, lse).
 
     ``out`` is ``(b, s, h, d)``; ``lse`` stays in the kernels'
@@ -458,13 +493,13 @@ def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret):
     b, s, h, d = q.shape
     t = k.shape[1]
     block_q, block_k = _resolve_blocks(block_q, block_k, s, t)
-    bq = _pick_block(s, block_q)
+    bq = _query_block(s, block_q, prefix)
     bk = _pick_block(t, block_k)
     qf, kf, vf, kv_index, _ = _gqa_layout(q, k, v)
     grid = (b * h, s // bq, t // bk)
     kernel = functools.partial(
         _flash_kernel_fwd_res, causal=causal, scale=_scale(q), block_q=bq,
-        block_k=bk, seq_k=t)
+        block_k=bk, seq_k=t, prefix=prefix)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -492,13 +527,13 @@ def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if prefix is None else "eva_remote_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
-                      interpret):
+                      interpret, prefix=None):
     """FlashAttention-2 backward: two Pallas passes (dq over key blocks;
     dk/dv over query blocks), probabilities rebuilt from lse — no O(s²)
     residuals, float32 accumulation throughout. Grouped (GQA) k/v are
@@ -510,7 +545,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     t = k.shape[1]
     hk = k.shape[2]
     block_q, block_k = _resolve_blocks(block_q, block_k, s, t)
-    bq = _pick_block(s, block_q)
+    bq = _query_block(s, block_q, prefix)
     bk = _pick_block(t, block_k)
     qf, kf, vf, kv_index, group = _gqa_layout(q, k, v)
     gf = g.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -521,7 +556,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
                     -1)[:, None, :]
 
     common = dict(causal=causal, scale=_scale(q), block_q=bq, block_k=bk,
-                  seq_k=t)
+                  seq_k=t, prefix=prefix)
     qspec = pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))
     kspec = pl.BlockSpec((1, bk, d),
                          lambda bh, qi, ki: (kv_index(bh), ki, 0))
@@ -535,7 +570,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if prefix is None else "eva_remote_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     # dk/dv: grid (b*hk, nk, group*nq) — ki owns the accumulation, the
@@ -566,7 +601,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if prefix is None else "eva_remote_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     unflat_q = lambda x: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
@@ -577,7 +612,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
-                             interpret: Optional[bool] = None):
+                             interpret: Optional[bool] = None,
+                             prefix=None):
     """Forward flash attention that also returns the per-row log-sum-exp.
 
     ``(out, lse)`` with ``out`` shaped like ``q`` and ``lse`` ``(b, h, s)``
@@ -585,10 +621,17 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     (out, lse) pairs (:func:`merge_attention_chunks`) — the primitive ring
     attention builds on: each ring step runs this kernel on the visiting
     kv chunk and merges. Forward-only (no vjp is registered here); ring
-    attention supplies its own backward via :func:`flash_chunk_bwd`."""
+    attention supplies its own backward via :func:`flash_chunk_bwd`.
+
+    ``prefix=(q_per, k_per)`` masks at the level of groups: query row
+    ``i`` sees key ``j`` iff ``j // k_per < i // q_per`` (EVA's chunk
+    summaries of earlier windows, :mod:`mpi_tpu.ops.eva_attention`). A
+    row that sees nothing comes back as zeros with ``lse`` ~ NEG_INF,
+    which :func:`merge_attention_chunks` gives no weight."""
     itp = _should_interpret() if interpret is None else interpret
     b, s, h, d = q.shape
-    out, lse = _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, itp)
+    out, lse = _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, itp,
+                                     prefix)
     return out, lse.reshape(b, h, s)
 
 
@@ -609,17 +652,18 @@ def merge_attention_chunks(o1, lse1, o2, lse2):
 def flash_chunk_bwd(q, k, v, out, lse, g, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None, prefix=None):
     """FA-2 backward for ONE (query-chunk, kv-chunk) pair against the
     *global* softmax: ``out``/``lse`` are the full-attention result rows
     (after every chunk was merged), so the rebuilt probabilities
     ``exp(qk - lse)`` are the true global ones and the returned
     ``(dq, dk, dv)`` are this pair's exact additive contributions. Ring
-    attention calls this once per ring step."""
+    attention calls this once per ring step; EVA once for the window's
+    own keys and once, under ``prefix``, for the summaries."""
     itp = _should_interpret() if interpret is None else interpret
     b, s, h, _ = q.shape
     return _flash_bwd_pallas(q, k, v, out, lse.reshape(b * h, 1, s), g,
-                             causal, block_q, block_k, itp)
+                             causal, block_q, block_k, itp, prefix)
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):

@@ -164,6 +164,31 @@ def test_flagship_train_step_compiles_four_chips(topo, compiled_kernels):
     assert "all-reduce" in compiled.as_text()  # dp grads / tp partials
 
 
+EVA_KERNELS = ("%eva_remote_fwd", "%eva_remote_bwd_dq", "%eva_remote_bwd_dkv")
+
+
+def test_eva_attention_compiles_at_evabyte_shapes(one_chip):
+    """The EVA op, forward and backward, at the shapes of the benchmark's
+    ``evabyte-L4.pretrain-16k-b1`` cell (one sequence of 16,384 bytes, 32
+    heads of 128, windows of 2,048, chunks of 16): the window's keys
+    through the causal flash kernels with the windows folded into the
+    batch, the 1,024 summaries through the same kernels under the prefix
+    mask, and the scopes a trace splits the op by."""
+    from mpi_tpu.ops import eva_attention
+
+    def loss(q, k, v, phi, mu):
+        out = eva_attention(q, k, v, phi, mu, 2048, 16, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((32, 128), jnp.bfloat16, sharding=one_chip)
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), q, q, q, vec, vec,
+             names=FLASH_KERNELS + EVA_KERNELS + (
+                 "eva.summarize", "/eva.local/", "/eva.remote/",
+                 "/eva.merge/"))
+
+
 @pytest.fixture(scope="module")
 def ring_mesh(topo):
     return Mesh(np.asarray(topo.devices), ("rank",))
