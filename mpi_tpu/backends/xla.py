@@ -122,24 +122,49 @@ def _deactivate_inheritance(net: "XlaNetwork") -> None:
             threading.Thread.start = _orig_thread_start
 
 
+class _CollectiveAborted(MpiError):
+    """A rank's collective ended because ``_CollectiveSession.abort`` was
+    called: collateral of whichever rank failed first."""
+
+    def __init__(self) -> None:
+        super().__init__("mpi_tpu: collective aborted (another rank failed)")
+
+
 class _CollectiveSession:
     """Rank-thread synchronization for native collectives.
 
-    Every rank contributes its payload, a barrier fires, the leader (one
-    arbitrary barrier winner) runs the combined computation once, a second
-    barrier releases everyone to read their result. Reusable across
-    sequential collectives (threading.Barrier auto-resets); collectives
-    must be invoked in the same order by all ranks — the standard MPI
-    requirement the generic layer documents too."""
+    One rendezvous a collective. Every rank stores its payload and
+    counts itself in; the rank that completes the count leads at once, on
+    the thread it is on (it is awake and holds the GIL, so nobody is woken
+    for the combined computation to begin), publishes the results and
+    opens the other ranks' gates. Every other rank sleeps once, on its own
+    gate (a lock acquired in C, the GIL dropped), from its arrival until
+    the results are there, and wakes once.
+
+    Reusable across sequential collectives with no second rendezvous: a
+    rank enters collective g+1 only after it has read its result of g,
+    and g+1 has a leader only when all n have entered, so nothing of g
+    is overwritten under a reader and no gate is opened twice.
+    Collectives must be invoked in the same order by all ranks — the
+    standard MPI requirement the generic layer documents too."""
 
     def __init__(self, n: int):
         self._n = n
-        self._barrier = threading.Barrier(n)
+        self._lock = threading.Lock()
+        self._count = 0
+        self._aborted = False
+        # One gate a rank, held shut between collectives. ``_asleep[r]``
+        # says rank r is behind its gate: whoever clears it (the leader
+        # or ``abort``, under ``_lock``) opens the gate, so once only.
+        self._gates = [threading.Lock() for _ in range(n)]
+        for gate in self._gates:
+            gate.acquire()
+        self._asleep = [False] * n
         self._slots: List[Any] = [None] * n
         self._results: List[Any] = [None] * n
         self._error: Optional[BaseException] = None
         # Per-collective arrival stamps (perf ns): all rank threads
-        # share one clock, so the barrier winner reads EXACT skew —
+        # share one clock, so the leader reads EXACT skew —
         # the straggler-detection source for the in-process drivers.
         self._arrivals: List[int] = [0] * n
         # The collective the leader is running: the ``op=`` of the stage
@@ -157,6 +182,22 @@ class _CollectiveSession:
             return
         metrics.note_session_skew(name, (hi - lo) / 1e3, arr.index(hi))
 
+    def _release(self, abort: bool = False) -> bool:
+        """Open the gate of every sleeping rank; returns whether the
+        session is aborted."""
+        with self._lock:
+            self._aborted = self._aborted or abort
+            for r, asleep in enumerate(self._asleep):
+                if asleep:
+                    self._asleep[r] = False
+                    self._gates[r].release()
+            return self._aborted
+
+    def abort(self) -> None:
+        """Fail every rank asleep in a collective, and every rank that
+        enters one from now on, with the ``collective aborted`` error."""
+        self._release(abort=True)
+
     def run(self, rank: int, value: Any,
             leader: Callable[[List[Any]], List[Any]],
             name: str = "collective",
@@ -166,34 +207,40 @@ class _CollectiveSession:
         attribute of the leader's span."""
         self._slots[rank] = value
         self._arrivals[rank] = time.perf_counter_ns()
-        try:
-            with trace.span("xla.coll.arrive_wait", op=name):
-                arrival = self._barrier.wait()
-        except threading.BrokenBarrierError as exc:
-            raise MpiError(
-                "mpi_tpu: collective aborted (another rank failed)") from exc
-        if arrival == 0:
+        with self._lock:
+            if self._aborted:
+                raise _CollectiveAborted()
+            self._count += 1
+            leads = self._count == self._n
+            if leads:
+                self._count = 0
+            else:
+                self._asleep[rank] = True
+        if leads:
+            slots = list(self._slots)
             self._note_skew(name)
             self.op = name
             try:
-                attrs = {} if path is None else {"path": path(self._slots)}
+                attrs = {} if path is None else {"path": path(slots)}
                 with trace.span("xla.coll.leader", op=name, **attrs):
-                    self._results = leader(list(self._slots))
+                    self._results = leader(slots)
                 self._error = None
             except BaseException as exc:  # noqa: BLE001 - re-raised on all ranks
                 self._error = exc
-        try:
+            aborted = self._release()
+        else:
+            if trace.enabled():
+                trace.count("xla.coll.sleeps")
             with trace.span("xla.coll.release_wait", op=name):
-                self._barrier.wait()
-        except threading.BrokenBarrierError as exc:
+                self._gates[rank].acquire()
+            aborted = self._aborted
+        if aborted:
+            raise _CollectiveAborted()
+        error, result = self._error, self._results[rank]
+        if error is not None:
             raise MpiError(
-                "mpi_tpu: collective aborted (another rank failed)") from exc
-        if self._error is not None:
-            raise MpiError(
-                f"mpi_tpu: collective failed on leader: {self._error}"
-            ) from self._error
-        return self._results[rank]
-
+                f"mpi_tpu: collective failed on leader: {error}") from error
+        return result
 
 
 class _MeshCollectives:
@@ -1040,7 +1087,7 @@ class XlaNetwork:
             # only evicts least-recently-used engines, which are safe to
             # drop unless more than _GROUP_ENGINE_CACHE communicators are
             # *concurrently* mid-collective (an evicted-but-live group
-            # would re-create its engine and lose barrier pairing).
+            # would re-create its engine and split its rendezvous).
             while len(self._group_colls) > self._GROUP_ENGINE_CACHE:
                 self._group_colls.popitem(last=False)
         return eng
@@ -1056,13 +1103,12 @@ class XlaNetwork:
             self._group_colls.pop(key, None)
 
     def abort_collectives(self) -> None:
-        """Break every collective barrier (world + group engines) so rank
+        """Abort every collective session (world + group engines) so rank
         threads blocked in a collective fail fast when a sibling dies."""
-        self._world_coll._coll._barrier.abort()
         with self._pairs_lock:
-            engines = list(self._group_colls.values())
+            engines = [self._world_coll, *self._group_colls.values()]
         for e in engines:
-            e._coll._barrier.abort()
+            e._coll.abort()
 
 
 
@@ -1131,8 +1177,8 @@ def drive_rank_threads(fn: Callable[[], Any], *, nranks: int,
     for e in errors:
         if e is None:
             continue
-        if isinstance(e, MpiError) and \
-                isinstance(e.__cause__, threading.BrokenBarrierError):
+        if isinstance(e, _CollectiveAborted) or isinstance(e, MpiError) \
+                and isinstance(e.__cause__, threading.BrokenBarrierError):
             secondary = secondary or e
             continue
         raise e

@@ -137,11 +137,32 @@ def test_profiler_holds_stage_inside_a_leader(profiled, op, stage):
         assert int(attrs["bytes"]) > 0
 
 
+def _waits_inside(profiled, op, leads):
+    """How many ``release_wait`` spans lie inside each facade span of
+    ``op``: of the calls that held the leader (``leads``) or the rest."""
+    leaders = _named(profiled["spans"], "xla.coll.leader", op=op)
+    waits = _named(profiled["spans"], "xla.coll.release_wait", op=op)
+    counts = []
+    for _, start, end, _, line in _named(profiled["spans"], f"mpi.{op}"):
+        def inside(span):
+            return start <= span[1] and span[2] <= end and span[4] == line
+        if any(map(inside, leaders)) == leads:
+            counts.append(sum(map(inside, waits)))
+    return counts
+
+
 @pytest.mark.parametrize("op", ["allreduce", "bcast"])
-@pytest.mark.parametrize("wait", ["arrive_wait", "release_wait"])
-def test_profiler_holds_both_waits_of_every_rank(profiled, op, wait):
-    assert len(_named(profiled["spans"], f"xla.coll.{wait}", op=op)) \
-        == RANKS * ROUNDS
+def test_profiler_holds_one_wait_a_call_of_every_sleeping_rank(profiled, op):
+    assert _waits_inside(profiled, op, leads=False) \
+        == [1] * ((RANKS - 1) * ROUNDS)
+    assert _named(profiled["spans"], "xla.coll.arrive_wait") == []
+
+
+@pytest.mark.parametrize("op", ["allreduce", "bcast"])
+def test_profiler_holds_no_wait_of_the_leader(profiled, op):
+    assert _waits_inside(profiled, op, leads=True) == [0] * ROUNDS
+    assert len(_named(profiled["spans"], "xla.coll.release_wait", op=op)) \
+        == (RANKS - 1) * ROUNDS
 
 
 def test_profiler_alone_records_nothing_into_the_buffer(profiled):
@@ -177,6 +198,7 @@ def test_enabled_buffer_holds_the_same_spans_nested_by_time():
     try:
         _run_ranks()
         events = trace.events()
+        sleeps = trace.counters().get("xla.coll.sleeps", 0)
     finally:
         if not was:
             trace.disable()
@@ -185,7 +207,14 @@ def test_enabled_buffer_holds_the_same_spans_nested_by_time():
     for e in events:
         by_name.setdefault(e["name"], []).append(e)
     assert len(by_name["mpi.allreduce"]) == RANKS * ROUNDS
-    assert len(by_name["xla.coll.arrive_wait"]) >= 2 * RANKS * ROUNDS
+    # One wait a sleeping rank a call, none of the leader's, and the
+    # counter counts the same sleeps.
+    assert "xla.coll.arrive_wait" not in by_name
+    for op in ("allreduce", "bcast"):
+        assert len([e for e in by_name["xla.coll.release_wait"]
+                    if e["op"] == op]) == (RANKS - 1) * ROUNDS
+    assert sleeps == len(by_name["xla.coll.release_wait"]) \
+        == 2 * (RANKS - 1) * ROUNDS
     leaders = [e for e in by_name["xla.coll.leader"]
                if e["op"] == "allreduce"]
     assert len(leaders) == ROUNDS
