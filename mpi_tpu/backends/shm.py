@@ -59,8 +59,18 @@ from typing import List, Optional, Tuple, Union
 from ..api import MpiError
 from .. import native as _native
 
-__all__ = ["ShmConn", "ring_name", "session_key", "create_ring",
-           "attach_ring", "unlink_ring", "DEFAULT_RING_BYTES"]
+__all__ = ["ShmConn", "OrderlyClose", "ring_name", "session_key",
+           "create_ring", "attach_ring", "unlink_ring", "DEFAULT_RING_BYTES"]
+
+
+class OrderlyClose(ConnectionError):
+    """EOF met on a frame boundary: the peer shut this connection with
+    no frame in flight — what an orderly ``finalize()`` looks like from
+    the other end. Everything the peer wrote before it is still
+    readable ahead of it, so the reader that meets it answers for its
+    own direction only (backends/tcp.py ``_mark_conn_closed``). An EOF
+    inside a frame stays a plain :class:`ConnectionError`."""
+
 
 DEFAULT_RING_BYTES = 1 << 20
 
@@ -491,6 +501,8 @@ class ShmConn:
             except socket.timeout:
                 lib.shm_abandon(rx._h, 0)  # poison only if mid-header
                 raise
+            if rc == _native.PEER_CLOSED:
+                raise OrderlyClose("connection closed by peer")
             self._check_rc(rc, "recv header")
             n = length.value
             payload = bytearray(n)
@@ -513,7 +525,10 @@ class ShmConn:
         deadline = None if self._timeout is None \
             else time.monotonic() + self._timeout
         hdr = bytearray(_FRAME_HDR.size)
-        rx.read_into(hdr, 0, _FRAME_HDR.size, deadline)
+        try:
+            rx.read_into(hdr, 0, _FRAME_HDR.size, deadline)
+        except ConnectionError as exc:
+            raise OrderlyClose(str(exc)) from None
         kind_v, tag_v, length_v = _FRAME_HDR.unpack(bytes(hdr))
         payload = bytearray(length_v)
         if length_v:

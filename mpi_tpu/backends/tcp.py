@@ -80,7 +80,7 @@ from ..utils.serialize import encode as codec_encode
 from ..utils.serialize import encode_parts as codec_encode_parts
 from .rendezvous import (DeadlineError, ReceiveCancelled, Rendezvous,
                          TagManager)
-from .shm import ShmConn
+from .shm import OrderlyClose, ShmConn
 
 __all__ = ["TcpNetwork", "InitError", "ReceiveCancelled", "DeadlineError",
            "ChecksumError", "PeerDeadError", "RemoteAbortError"]
@@ -231,11 +231,7 @@ def _chaos_wire_send(sock, lock: threading.Lock, kind: int, tag: int,
         body[at] ^= 1 << (fault.corrupt_bit % 8)
     with lock:
         if fault.reset:
-            try:
-                sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            sock.close()
+            _shut(sock)
             return
         if fault.truncate_at is not None:
             # A frame cut short desynchronizes the stream permanently,
@@ -246,11 +242,7 @@ def _chaos_wire_send(sock, lock: threading.Lock, kind: int, tag: int,
                 sock.sendall(bytes(body[:cut]))
             except OSError:
                 pass
-            try:
-                sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            sock.close()
+            _shut(sock)
             return
         sock.sendall(bytes(body))
 
@@ -362,7 +354,9 @@ def _recv_exact(sock: socket.socket, n: int,
     resume reading from the middle of the frame and decode garbage. It
     is converted to a fatal :class:`ConnectionError` for this peer; only
     a timeout on a clean frame boundary surfaces as ``socket.timeout``
-    (the handshake accept/reply deadlines rely on that)."""
+    (the handshake accept/reply deadlines rely on that). EOF likewise:
+    on a frame boundary it is an :class:`OrderlyClose`, inside a frame
+    a plain :class:`ConnectionError`."""
     from .. import native as _native
 
     buf = bytearray(n)
@@ -379,7 +373,8 @@ def _recv_exact(sock: socket.socket, n: int,
             if rc != -_errno.EINTR:
                 break
         if rc == _native.PEER_CLOSED:
-            raise ConnectionError("connection closed by peer")
+            raise (ConnectionError if midframe or progress.value
+                   else OrderlyClose)("connection closed by peer")
         if rc != 0:
             import os as _os
 
@@ -398,7 +393,8 @@ def _recv_exact(sock: socket.socket, n: int,
                     f"unusable") from None
             raise
         if r == 0:
-            raise ConnectionError("connection closed by peer")
+            raise (ConnectionError if midframe or got
+                   else OrderlyClose)("connection closed by peer")
         got += r
     return buf
 
@@ -447,6 +443,21 @@ def _recv_frame(sock, crc: bool = False,
                 _crc32_frame(bytes(header), payload):
             raise ChecksumError(src, tag)
     return kind, tag, payload
+
+
+def _shut(sock) -> None:
+    """Shut down and close one connection; never raises. Shutdown
+    first so a reader thread blocked in recv on it wakes."""
+    if sock is None:
+        return
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass
+    try:
+        sock.close()
+    except OSError:
+        pass
 
 
 class _Peer:
@@ -570,16 +581,8 @@ class TcpNetwork:
                 except OSError:
                     pass
         for peer in self._peers.values():
-            for sock in (peer.dial_sock, peer.listen_sock):
-                if sock is not None:
-                    try:
-                        sock.shutdown(socket.SHUT_RDWR)
-                    except OSError:
-                        pass
-                    try:
-                        sock.close()
-                    except OSError:
-                        pass
+            _shut(peer.dial_sock)
+            _shut(peer.listen_sock)
         for peer in self._peers.values():
             for t in peer.reader_threads:
                 t.join(timeout=2.0)
@@ -1267,6 +1270,8 @@ class TcpNetwork:
                 peer.sendtags.route(tag, True)
         except RemoteAbortError as exc:
             self._mark_job_aborted(exc)
+        except OrderlyClose as exc:
+            self._mark_conn_closed(peer, peer.sendtags, peer.dial_sock, exc)
         except (ConnectionError, OSError, MpiError) as exc:
             self._mark_peer_dead(peer, exc)
 
@@ -1294,6 +1299,9 @@ class TcpNetwork:
             # naming another operation's tag.
             peer.receivetags.route(exc.tag, exc)
             self._mark_peer_dead(peer, PeerDeadError(peer.rank, exc))
+        except OrderlyClose as exc:
+            self._mark_conn_closed(peer, peer.receivetags, peer.listen_sock,
+                                   exc)
         except (ConnectionError, OSError, MpiError) as exc:
             self._mark_peer_dead(peer, exc)
 
@@ -1307,45 +1315,54 @@ class TcpNetwork:
             self._mark_peer_dead(p, exc)
 
     def _mark_peer_dead(self, peer: _Peer, exc: BaseException) -> None:
-        """On connection loss (either direction's reader died) the whole
-        peer is dead: fail all pending *and future* ops targeting it
+        """On connection loss (either direction's reader died of anything
+        but an orderly close, :meth:`_mark_conn_closed`) the whole peer
+        is dead: fail all pending *and future* ops targeting it
         instead of hanging (replaces the reference's reader panics,
         network.go:555,611). Ops already blocked get the exception via
         their slot; ops issued after the loss fail at claim(). Raw
         socket errors are wrapped in :class:`PeerDeadError` so callers
         always see a typed, classifiable MpiError."""
-        if self._closed.is_set():
-            exc = MpiError("mpi_tpu: network finalized")
-        elif not isinstance(exc, MpiError):
-            exc = PeerDeadError(peer.rank, exc)
-        # Poison with the FIRST cause of death: the sibling reader dying
-        # of this call's own cross-close must not rebrand the failure.
-        with peer.dead_lock:
-            if peer.dead is None:
-                peer.dead = exc
-            exc = peer.dead
+        exc = self._first_cause(peer, exc)
         peer.sendtags.poison(exc)
         peer.receivetags.poison(exc)
         # Drop both connections: the PEER's readers then observe EOF and
         # mark us dead too, so its blocked ops (e.g. the ack wait of the
         # send whose frame failed our CRC check) fail fast instead of
         # hanging until a deadline that may not be configured. During
-        # finalize the sockets are being closed anyway; re-closing is a
-        # no-op. The sibling reader of this conn pair wakes with a
-        # ConnectionError and re-enters here idempotently.
+        # finalize the sockets are being closed anyway. The sibling
+        # reader of this conn pair wakes with a ConnectionError and
+        # re-enters here idempotently.
         if not self._closed.is_set():
-            for sock in (peer.dial_sock, peer.listen_sock):
-                if sock is None:
-                    continue
-                try:
-                    if not isinstance(sock, ShmConn):
-                        sock.shutdown(socket.SHUT_RDWR)
-                except OSError:
-                    pass
-                try:
-                    sock.close()
-                except OSError:
-                    pass
+            _shut(peer.dial_sock)
+            _shut(peer.listen_sock)
+
+    def _mark_conn_closed(self, peer: _Peer, tags: TagManager, sock,
+                          exc: OrderlyClose) -> None:
+        """The reader of ``sock`` met EOF on a frame boundary: fail the
+        pending and future ops of ITS direction only. A peer that
+        finalizes (or dies) closes both connections, so the sibling
+        reader gets here on its own moments later — after it has routed
+        whatever the peer wrote ahead of the EOF. That order is the
+        point: the ack of a message the peer received just before it
+        finalized sits in the dial conn, and a listen reader that wins
+        the race to EOF must neither poison ``sendtags`` nor close the
+        dial socket over it (docs/FAULT_TOLERANCE.md)."""
+        tags.poison(self._first_cause(peer, exc))
+        if not self._closed.is_set():
+            _shut(sock)
+
+    def _first_cause(self, peer: _Peer, exc: BaseException) -> BaseException:
+        """Poison with the FIRST cause of death: the sibling reader
+        dying of this one's close must not rebrand the failure."""
+        if self._closed.is_set():
+            exc = MpiError("mpi_tpu: network finalized")
+        elif not isinstance(exc, MpiError):
+            exc = PeerDeadError(peer.rank, exc)
+        with peer.dead_lock:
+            if peer.dead is None:
+                peer.dead = exc
+            return peer.dead
 
     def _check_rank(self, r: int) -> None:
         if self._size is None:

@@ -26,7 +26,7 @@ Three pillars on top of the process-local tracer
     per-collective arrival skew, an ``observe top`` text summary on
     SIGUSR1 or at Finalize (``MPI_TPU_OBSERVE_SUMMARY=1``), and a
     machine-readable ``--mpi-metrics-out`` JSON artifact
-    (``MPI_TPU_METRICS_OUT``) that bench.py folds into BENCH rounds.
+    (``MPI_TPU_METRICS_OUT``).
 
 The facade (:mod:`mpi_tpu.api`) calls :func:`on_init` after a
 successful ``init()`` and :func:`on_finalize` at the top of

@@ -15,8 +15,8 @@ Three data sources, one renderer:
 
 ``summary_text()`` renders the ``mpi_tpu observe top``-style report —
 printed on SIGUSR1 (installed at init) or at finalize; ``write()``
-emits the machine-readable ``--mpi-metrics-out`` JSON artifact that
-``bench.py`` folds into BENCH rounds (schema in docs/OBSERVABILITY.md).
+emits the machine-readable ``--mpi-metrics-out`` JSON artifact
+(schema in docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -150,7 +150,7 @@ def snapshot(rank: Optional[int] = None,
 
 def validate(doc: Dict[str, Any]) -> None:
     """Raise ValueError unless ``doc`` is a well-formed metrics artifact
-    (the schema contract bench.py and the observe CLI rely on)."""
+    (the schema contract the observe CLI relies on)."""
     if not isinstance(doc, dict):
         raise ValueError("metrics artifact is not an object")
     if doc.get("schema_version") != SCHEMA_VERSION:

@@ -51,9 +51,9 @@ __all__ = ["quantized_allreduce", "quantize_blocks", "dequantize_blocks",
 # Measured dispatch gate (mirrors ``collectives_generic.ring_eligible``'s
 # measured-crossover discipline): the compression only pays where the
 # WIRE is the bottleneck, and below the crossover the extra
-# quantize/dequantize compute is a straight regression — BENCH_r03
-# recorded the forced path 8.6x slower than plain allreduce at 1 MiB on
-# the virtual CPU mesh.
+# quantize/dequantize compute is a straight regression — the forced
+# path measured 8.6x slower than plain allreduce at 1 MiB on the virtual
+# CPU mesh.
 #
 # fabric -> minimum payload bytes where int8+scales beats float32
 # (None = never):

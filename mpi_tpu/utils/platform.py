@@ -3,8 +3,9 @@
 :func:`force_platform` pins the platform (and the virtual CPU device
 count) through :func:`jax.config.update`, which must happen before the
 first device query; :func:`compile_cache_dir` decides where the
-persistent compilation cache lives. Centralized here so ``bench.py``,
-``chip_smoke.py``, the examples and ``__graft_entry__`` agree.
+persistent compilation cache lives. Centralized here so
+``chip_smoke.py``, ``benchmark/run.py``, the examples and
+``__graft_entry__`` agree.
 """
 
 from __future__ import annotations

@@ -45,20 +45,22 @@ def _free_ports(n: int) -> list:
 
 @contextmanager
 def tcp_cluster(n: int, password: str = "", timeout: float = 20.0,
-                **net_kwargs):
+                proto: str = "tcp", addrs=None, **net_kwargs):
     """Spin up n in-process TcpNetwork ranks on localhost and init them
     concurrently; yields the list ordered by rank. The in-process analogue
-    of the reference's N-OS-process localhost harness. Extra keyword
-    args (``crc=True``, ``optimeout=2.0``, ``chaos="7:1:delay"``, ...)
-    pass through to every rank's TcpNetwork constructor."""
+    of the reference's N-OS-process localhost harness. ``proto`` other
+    than ``"tcp"`` needs its own ``addrs`` (socket paths for ``unix``,
+    opaque ids for ``shm``). Extra keyword args (``crc=True``,
+    ``optimeout=2.0``, ``chaos="7:1:delay"``, ...) pass through to every
+    rank's TcpNetwork constructor."""
     from mpi_tpu.backends.tcp import TcpNetwork
 
-    ports = _free_ports(n)
-    # Fixed-width port strings sort lexically == numerically, giving a
-    # deterministic rank order we can predict in tests.
-    addrs = sorted(f"127.0.0.1:{p:05d}" for p in ports)
+    if addrs is None:
+        # Fixed-width port strings sort lexically == numerically, giving
+        # a deterministic rank order we can predict in tests.
+        addrs = sorted(f"127.0.0.1:{p:05d}" for p in _free_ports(n))
     nets = [TcpNetwork(addr=a, addrs=list(addrs), timeout=timeout,
-                       password=password, proto="tcp", **net_kwargs)
+                       password=password, proto=proto, **net_kwargs)
             for a in addrs]
     errs = [None] * n
 

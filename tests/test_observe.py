@@ -769,67 +769,6 @@ class TestDecodeDeadline:
 
 
 # ---------------------------------------------------------------------------
-# Bench regression gate (ISSUE 15 tentpole)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchGate:
-    GATE = str(REPO / "tools" / "bench_gate.py")
-
-    def _run(self, *args):
-        return subprocess.run([sys.executable, self.GATE, *args],
-                              capture_output=True, text=True, timeout=60)
-
-    def _write(self, tmp_path, name, rec):
-        p = tmp_path / name
-        p.write_text(json.dumps(rec))
-        return str(p)
-
-    def test_exit_codes(self, tmp_path):
-        base = self._write(tmp_path, "base.json", {
-            "platform": "cpu", "smoke": False,
-            "allreduce_8MiB_p50_us": 10000.0, "bounce_p50_us": 5000.0})
-        ok = self._write(tmp_path, "ok.json", {
-            "platform": "cpu", "smoke": False,
-            "allreduce_8MiB_p50_us": 10400.0, "bounce_p50_us": 5100.0})
-        bad = self._write(tmp_path, "bad.json", {
-            "platform": "cpu", "smoke": False,
-            "allreduce_8MiB_p50_us": 25000.0, "bounce_p50_us": 5100.0})
-        assert self._run(base, ok).returncode == 0
-        res = self._run(base, bad)
-        assert res.returncode == 1
-        assert "REGRESSION allreduce_8MiB_p50_us" in res.stdout
-        assert self._run(base, bad, "--warn-only").returncode == 0
-        # Allowlist: a regression outside --keys reports but passes.
-        assert self._run(base, bad, "--keys",
-                         "bounce_p50_us").returncode == 0
-        # Threshold override loosens the verdict.
-        assert self._run(base, bad, "--pct", "200").returncode == 0
-        assert self._run(base, str(tmp_path / "nope.json")).returncode == 2
-
-    def test_incomparable_platforms_exit_2(self, tmp_path):
-        base = self._write(tmp_path, "b.json",
-                           {"platform": "cpu", "smoke": False,
-                            "x_p50_us": 10000.0})
-        cur = self._write(tmp_path, "c.json",
-                          {"platform": "tpu", "smoke": False,
-                           "x_p50_us": 10000.0})
-        res = self._run(base, cur)
-        assert res.returncode == 2
-        assert "incomparable" in res.stderr
-
-    def test_metrics_artifacts_flattened(self, tmp_path):
-        mk = lambda p50: {"schema_version": 1, "rank": 0,
-                          "ops": {"send": {"count": 10, "p50_us": p50,
-                                           "p99_us": p50 * 2}}}
-        base = self._write(tmp_path, "mb.json", mk(8000.0))
-        cur = self._write(tmp_path, "mc.json", mk(20000.0))
-        res = self._run(base, cur)
-        assert res.returncode == 1
-        assert "op_send_p50_us" in res.stdout
-
-
-# ---------------------------------------------------------------------------
 # Crash-durable spooling under real mpirun (integration)
 # ---------------------------------------------------------------------------
 

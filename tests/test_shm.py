@@ -14,7 +14,6 @@ import subprocess
 import sys
 import threading
 import uuid
-from contextlib import contextmanager
 from pathlib import Path
 
 import numpy as np
@@ -26,7 +25,7 @@ from mpi_tpu.backends.shm import (ShmConn, attach_ring, create_ring,
                                   ring_name, session_key, unlink_ring)
 from mpi_tpu.backends.tcp import InitError, TcpNetwork
 
-from conftest import run_on_ranks
+from conftest import run_on_ranks, tcp_cluster
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -38,37 +37,9 @@ def _addrs(n: int):
     return [f"{base}-{i}" for i in range(n)]
 
 
-@contextmanager
 def shm_cluster(n: int, password: str = "", timeout: float = 20.0):
-    addrs = _addrs(n)
-    nets = [TcpNetwork(proto="shm", addr=a, addrs=list(addrs),
-                       timeout=timeout, password=password) for a in addrs]
-    errs = [None] * n
-
-    def _init(i):
-        try:
-            nets[i].init()
-        except BaseException as exc:  # noqa: BLE001
-            errs[i] = exc
-
-    threads = [threading.Thread(target=_init, args=(i,), daemon=True)
-               for i in range(n)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=timeout + 10)
-    for e in errs:
-        if e is not None:
-            raise e
-    nets_by_rank = sorted(nets, key=lambda m: m.rank())
-    try:
-        yield nets_by_rank
-    finally:
-        for net in nets_by_rank:
-            try:
-                net.finalize()
-            except BaseException:  # noqa: BLE001
-                pass
+    return tcp_cluster(n, password=password, timeout=timeout, proto="shm",
+                       addrs=_addrs(n))
 
 
 @pytest.fixture(params=["native", "python"])

@@ -6,12 +6,13 @@ gompirun.go:46-51, compressed into one process)."""
 
 import threading
 import time
+import uuid
 
 import numpy as np
 import pytest
 
 from mpi_tpu.api import MpiError, TagError
-from mpi_tpu.backends.tcp import InitError, TcpNetwork
+from mpi_tpu.backends.tcp import InitError, PeerDeadError, TcpNetwork
 
 from conftest import run_on_ranks, tcp_cluster
 
@@ -408,31 +409,10 @@ class TestProtocols:
     reference: NetProto accepts net-package protocols, network.go:26)."""
 
     def test_unix_socket_cluster(self, tmp_path):
-        import threading as _threading
-
         from mpi_tpu import collectives_generic as G
-        from mpi_tpu.backends.tcp import TcpNetwork
 
         addrs = sorted(str(tmp_path / f"rank{i}.sock") for i in range(3))
-        nets = [TcpNetwork(proto="unix", addr=a, addrs=list(addrs),
-                           timeout=20.0) for a in addrs]
-        errs = [None] * 3
-
-        def _init(i):
-            try:
-                nets[i].init()
-            except BaseException as exc:  # noqa: BLE001
-                errs[i] = exc
-
-        threads = [_threading.Thread(target=_init, args=(i,), daemon=True)
-                   for i in range(3)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(30)
-        assert all(e is None for e in errs), errs
-        nets_by_rank = sorted(nets, key=lambda m: m.rank())
-        try:
+        with tcp_cluster(3, proto="unix", addrs=addrs) as nets_by_rank:
             def prog(net, r):
                 import numpy as _np
 
@@ -444,12 +424,71 @@ class TestProtocols:
 
             totals = run_on_ranks(nets_by_rank, prog)
             assert all(float(t) == 6.0 for t in totals)
-        finally:
-            for m in nets_by_rank:
-                m.finalize()
         # Socket files are cleaned up on finalize.
         assert not any((tmp_path / f"rank{i}.sock").exists()
                        for i in range(3))
+
+    @staticmethod
+    def _pair(proto, tmp_path):
+        """A 2-rank cluster over ``proto``."""
+        addrs = {"tcp": None,
+                 "unix": [str(tmp_path / f"rank{i}.sock") for i in range(2)],
+                 "shm": [f"{uuid.uuid4().hex[:8]}-{i}" for i in range(2)]}
+        return tcp_cluster(2, proto=proto, addrs=addrs[proto])
+
+    @pytest.mark.parametrize("proto", ["tcp", "unix", "shm"])
+    def test_send_acked_before_receiver_finalized_returns(
+            self, proto, tmp_path):
+        # Rank 1 receives (which writes the ack) and finalizes at once.
+        # Rank 0 then has the ack and an EOF on its dial conn and a bare
+        # EOF on its listen conn, read by two threads; its dial reader
+        # is held back so the listen reader meets its EOF first. That
+        # EOF is an orderly close of ONE connection: it must not fail
+        # the send whose ack was written ahead of the other one's.
+        with self._pair(proto, tmp_path) as nets:
+            sendtags = nets[0]._peers[1].sendtags
+            route = sendtags.route
+
+            def late_route(tag, item):
+                time.sleep(0.5)
+                route(tag, item)
+
+            sendtags.route = late_route
+
+            def prog(net, r):
+                if r == 0:
+                    net.send(b"last words", 1, 9)
+                    return None
+                got = net.receive(0, 9)
+                net.finalize()
+                return got
+
+            assert run_on_ranks(nets, prog)[1] == b"last words"
+
+    @pytest.mark.parametrize("proto", ["tcp", "unix", "shm"])
+    def test_send_to_receiver_that_closed_unreceived_fails_fast(
+            self, proto, tmp_path):
+        # The other side of that line: no ack was ever written, so the
+        # close is the peer's death as far as this send can tell — and
+        # it says so within seconds with no --mpi-optimeout set.
+        with self._pair(proto, tmp_path) as nets:
+            assert nets[0].optimeout is None
+            err = []
+
+            def blocked():
+                try:
+                    nets[0].send(b"unheard", 1, 9)
+                except MpiError as exc:
+                    err.append(exc)
+
+            t = threading.Thread(target=blocked, daemon=True)
+            t.start()
+            time.sleep(0.3)
+            nets[1].finalize()
+            t.join(timeout=5.0)
+            assert not t.is_alive()
+            assert len(err) == 1 and isinstance(err[0], PeerDeadError)
+            assert err[0].peer == 1
 
     def test_unsupported_protocol_raises(self):
         from mpi_tpu.backends.tcp import InitError, TcpNetwork

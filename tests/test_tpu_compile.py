@@ -27,7 +27,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-# Flagship shapes (chip_smoke.py; bench.py measure_train_step defaults).
+# Flagship shapes (chip_smoke.py).
 BATCH, SEQ, HEADS, HEAD_DIM = 8, 1024, 8, 128
 VOCAB, D_MODEL, LAYERS, D_FF = 8192, 1024, 8, 4096
 

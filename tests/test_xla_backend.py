@@ -444,167 +444,6 @@ class TestOversubscription:
             np.testing.assert_array_equal(np.asarray(o), expect)
 
 
-def test_bench_harness_emits_json_line():
-    import json
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    root = Path(__file__).resolve().parent.parent
-    proc = subprocess.run(
-        [sys.executable, str(root / "bench.py"), "--platform", "cpu",
-         "--smoke"],
-        capture_output=True, text=True, timeout=420, cwd=root)
-    assert proc.returncode == 0, proc.stderr
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    rec = json.loads(line)
-    # The stdout line is the COMPACT headline-first contract (r3's
-    # 65-key line overflowed the driver's capture window and parsed as
-    # null): driver keys + provenance + representative numbers, under
-    # the byte budget, pointing at the full artifact.
-    import bench as _bench
-
-    assert len(line) <= _bench._LINE_BUDGET
-    assert {"metric", "value", "unit", "vs_baseline", "smoke",
-            "full_results"} <= set(rec)
-    assert rec["metric"] == "train_step_mfu"
-    # The CPU has no published peak, so there is no honest denominator:
-    # the headline MFU is null — never 0.0 or any other number — and
-    # tokens/s carries the line (r4 verdict weak #6).
-    assert rec["platform"] == "cpu"
-    assert rec["value"] is None and rec["vs_baseline"] is None
-    assert rec["train_tokens_per_s"] > 0
-    assert rec["smoke"] is True        # unambiguous marker, VERDICT r3
-    for key in ("train_step_ms", "bounce_tcp_us", "bounce_xla_us",
-                "peak_tflops"):
-        assert key in rec, key
-    # Every measurement — including the ones trimmed from the compact
-    # line — lands in the committed full artifact.
-    full = json.loads((root / rec["full_results"]).read_text())
-    assert set(rec) - {"full_results", "truncated"} <= set(full)
-    for key in ("allreduce_1MiB_gbps", "allreduce_devices"):
-        assert key in full, key
-    # One visible device → the in-process collective is degenerate: it
-    # must be null (never a latency artifact dressed as bandwidth) with
-    # the virtual-mesh leg carrying the real multi-device number. More
-    # devices (pytest's conftest exports an 8-device XLA_FLAGS that the
-    # bench subprocess inherits) → the direct number must be real.
-    if full["allreduce_devices"] == 1:
-        assert full["allreduce_1MiB_gbps"] is None
-        assert full["allreduce_1MiB_gbps_cpu8mesh"] > 0
-    else:
-        assert full["allreduce_1MiB_gbps"] > 0
-
-
-class TestBenchRegressionCheck:
-    """The bench self-regression verdict (r4 verdict item 3: shm went
-    1.48x -> 1.0x between rounds and nothing flagged it)."""
-
-    def _line(self, **kw):
-        base = {"platform": "cpu", "smoke": True,
-                "bounce_shm_us": 2000.0, "decode_tokens_per_s": 100.0,
-                "allreduce_1MiB_busbw_gbps": 7.0, "peak_tflops": 197.0,
-                "allreduce_devices": 8, "qallreduce_forced": True}
-        base.update(kw)
-        return base
-
-    def test_unchanged_tree_flags_nothing(self):
-        import bench
-        full = self._line()
-        bench._regression_check(full, dict(self._line()))
-        assert full["regressions"] == []
-        assert full["regressions_count"] == 0
-        assert not any(k.endswith("_regressed") for k in full)
-
-    def test_injected_slowdown_flags_both_directions(self):
-        import bench
-        # Latency-like key regresses UP, throughput-like key DOWN.
-        full = self._line(bounce_shm_us=3000.0, decode_tokens_per_s=60.0)
-        bench._regression_check(full, self._line())
-        flagged = {r["key"] for r in full["regressions"]}
-        assert flagged == {"bounce_shm_us", "decode_tokens_per_s"}
-        assert full["bounce_shm_us_regressed"] is True
-        assert full["decode_tokens_per_s_regressed"] is True
-        assert full["regressions_count"] == 2
-
-    def test_within_noise_band_not_flagged(self):
-        import bench
-        full = self._line(bounce_shm_us=2400.0)   # +20% < 30% default
-        bench._regression_check(full, self._line())
-        assert full["regressions"] == []
-
-    def test_improvements_never_flagged(self):
-        import bench
-        full = self._line(bounce_shm_us=500.0,
-                          decode_tokens_per_s=400.0)
-        bench._regression_check(full, self._line())
-        assert full["regressions"] == []
-
-    def test_cross_platform_lines_incomparable(self):
-        import bench
-        full = self._line(platform="tpu", smoke=False,
-                          decode_tokens_per_s=1.0)
-        bench._regression_check(full, self._line())
-        assert "regressions" not in full
-        assert full["regressions_vs"].startswith("incomparable")
-
-    def test_constants_and_diagnostics_skipped(self):
-        import bench
-        # peak table values and non-directional keys never flag even
-        # when they differ wildly.
-        full = self._line(peak_tflops=10.0, allreduce_devices=2)
-        bench._regression_check(full, self._line())
-        assert full["regressions"] == []
-
-    def test_threshold_env_override(self, monkeypatch):
-        import bench
-        monkeypatch.setenv("MPI_TPU_BENCH_REGRESS_PCT", "10")
-        full = self._line(bounce_shm_us=2400.0)   # +20% > 10%
-        bench._regression_check(full, self._line())
-        assert [r["key"] for r in full["regressions"]] == \
-            ["bounce_shm_us"]
-
-    def test_malformed_threshold_env_falls_back(self, monkeypatch):
-        import bench
-        monkeypatch.setenv("MPI_TPU_BENCH_REGRESS_PCT", "30%")
-        full = self._line(bounce_shm_us=3000.0)
-        bench._regression_check(full, self._line())  # must not raise
-        assert [r["key"] for r in full["regressions"]] == \
-            ["bounce_shm_us"]
-
-    def test_provenance_suffixed_keys_classified(self):
-        import bench
-        # A suffixed latency key regressing 7.5x must flag (the bare
-        # endswith('_us') test misses '_p50_us_cpu8mesh'); a suffixed
-        # sub-2ms micro-timing's throughput partner must NOT flag (its
-        # latency sibling is under the materiality floor).
-        prior = self._line(**{
-            "allreduce_8MiB_p50_us_cpu8mesh": 1340.8,
-            "allreduce_32KiB_gbps_cpu8mesh": 0.78,
-            "allreduce_32KiB_p50_us_cpu8mesh": 41.9})
-        full = self._line(**{
-            "allreduce_8MiB_p50_us_cpu8mesh": 10000.0,
-            "allreduce_32KiB_gbps_cpu8mesh": 0.4,
-            "allreduce_32KiB_p50_us_cpu8mesh": 80.0})
-        bench._regression_check(full, prior)
-        assert [r["key"] for r in full["regressions"]] == \
-            ["allreduce_8MiB_p50_us_cpu8mesh"]
-
-
-def test_bench_host_membw_probe_keys():
-    """The allreduce-curve diagnosis context (r4 verdict weak #2): the
-    probe must report both copy bandwidths and the topology facts that
-    make the cpu8mesh curve interpretable."""
-    import bench
-    r = bench._host_membw_probe()
-    assert r["host_membw_copy_cached_gbps"] > 0
-    assert r["host_membw_copy_dram_gbps"] > 0
-    assert r["host_cores"] >= 1
-    # l3 may legitimately be None in odd containers; when present it is
-    # a positive MiB figure.
-    assert r["host_l3_mib"] is None or r["host_l3_mib"] > 0
-
-
 def test_oversubscribed_validation_matches_mesh_path():
     """Payload mismatch raises the same clear error whether or not ranks
     oversubscribe — behavior must not depend on the rank/device ratio."""
@@ -861,29 +700,6 @@ class TestNonblocking:
         for r in range(N):
             np.testing.assert_array_equal(
                 out[r], np.full(3, (r - 1) % N, np.float32))
-
-
-def test_bench_flash_tune_path_runs_on_cpu(monkeypatch, tmp_path):
-    """The TPU-only bench path (flash attention + block autotune +
-    sweep-table keys) exercised end-to-end at smoke size via the
-    attention override — a wiring bug here would otherwise only
-    surface during the driver's real-chip run."""
-    import bench
-
-    monkeypatch.setenv("MPI_TPU_TUNE_CACHE", str(tmp_path / "tc.json"))
-    r = bench.measure_train_step(
-        d_model=32, n_layers=1, n_heads=2, d_ff=64, vocab=64,
-        batch=2, seq=32, short=1, long=3, attention="flash")
-    assert r["model"]["attention"] == "flash"
-    assert r["flash_block_q"] >= 1 and r["flash_block_k"] >= 1
-    # On the CPU test device there is no honest peak-TFLOPs denominator,
-    # so the MFU must be null (r4 verdict weak #6), never a
-    # v5e-denominator number.
-    assert r["mfu_pct"] is None
-    assert r["peak_source"].startswith("unknown-kind")
-    assert r["train_tokens_per_s"] > 0
-    # the sweep table came through (interpret-mode kernel on CPU)
-    assert any(k.startswith("flash_tune") for k in r)
 
 
 # --------------------------------------------------------------------------
