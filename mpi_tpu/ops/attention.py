@@ -34,9 +34,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..utils import trace
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "flash_attention_with_lse", "flash_chunk_bwd",
@@ -144,24 +147,28 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # --------------------------------------------------------------------------
 
 def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
-                seq_k: int, prefix=None):
-    """The (block_q, block_k) validity mask for grid cell (qi, ki).
+                prefix=None):
+    """The (block_q, block_k) validity mask for a *crossed* grid cell
+    (qi, ki) (:func:`_cell_kind`; the other kinds never build it).
 
     ``prefix=(q_per, k_per)`` is the window-level mask EVA's summaries
     need (:mod:`mpi_tpu.ops.eva_attention`): rows come in groups of
     ``q_per``, columns in groups of ``k_per``, and a row sees the columns
     of strictly earlier groups, ``col // k_per < row // q_per``.
     ``block_q`` divides ``q_per`` (:func:`_prefix_end`), so the block's
-    rows share one group and the test is one compare with a scalar."""
-    row = qi * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+    rows share one group and the test is one compare with a scalar.
+    No ``col < seq_k`` term: the blocks divide the lengths
+    (:func:`_blocks`), so no column lies past the keys."""
     col = ki * block_k + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    valid = col < seq_k
+    valid = None
     if causal:
-        valid &= row >= col
+        row = qi * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        valid = row >= col
     if prefix is not None:
-        valid &= col < _prefix_end(qi, block_q, prefix)
+        before = col < _prefix_end(qi, block_q, prefix)
+        valid = before if valid is None else valid & before
     return valid
 
 
@@ -173,44 +180,120 @@ def _prefix_end(qi, block_q: int, prefix):
     return (qi * block_q) // q_per * k_per
 
 
-def _when_live(live, qi, ki, block_q: int, block_k: int, prefix, compute):
-    """Run ``compute`` unless the mask leaves grid cell (qi, ki) empty.
-    ``live`` is the kernel's own causal test (``None`` without a causal
-    mask: blocks above the diagonal are empty); a prefix mask adds the
-    blocks that start at or past the last column the rows see.
-    Skipping saves the cell's MXU matmuls; the mask math keeps the
-    skipped state consistent."""
+def _cell_kind(qi, ki, block_q: int, block_k: int, causal: bool,
+               prefix=None):
+    """``(live, whole)`` for grid cell (qi, ki) of the three kernels:
+    the cell is *dead* (``not live``) when the mask hides every entry,
+    *whole* when it hides none, *crossed* (``live & ~whole``) otherwise.
+    Decided from the cell's position alone, so ``qi``/``ki`` may be
+    Python ints, numpy arrays (the census) or traced scalars (the
+    kernels and their index maps). Causal: live iff the block's first
+    column is at or before its last row, whole iff its first row is at
+    or past its last column. Prefix: live iff the first column is before
+    :func:`_prefix_end`, whole iff the last one is. With neither mask
+    every cell is whole, and both come back as Python ``True``."""
+    live = whole = True
+    row0, col0 = qi * block_q, ki * block_k
+    if causal:
+        live = col0 <= row0 + (block_q - 1)
+        whole = row0 >= col0 + (block_k - 1)
     if prefix is not None:
-        before = ki * block_k < _prefix_end(qi, block_q, prefix)
-        live = before if live is None else live & before
-    if live is None:
-        compute()
-    else:
-        pl.when(live)(compute)
+        end = _prefix_end(qi, block_q, prefix)
+        live = (col0 < end) & live
+        whole = (col0 + (block_k - 1) < end) & whole
+    return live, whole
 
 
-def _block_probs(q_ref, k_ref, lse_ref, qi, ki, *, causal: bool,
-                 scale: float, block_q: int, block_k: int, seq_k: int,
-                 prefix=None):
+def _resident_ki(qi, ki, block_q: int, block_k: int, causal: bool, prefix):
+    """The key block the forward and dq index maps name for cell
+    (qi, ki): its own when the cell is live, else the last live one of
+    the row (block 0 in a row with none), which is the block already
+    resident when a dead cell comes up, so Pallas issues no copy."""
+    if causal:
+        ki = jnp.minimum(ki, (qi * block_q + (block_q - 1)) // block_k)
+    if prefix is not None:
+        end = _prefix_end(qi, block_q, prefix)
+        ki = jnp.minimum(ki, jnp.maximum(end - 1, 0) // block_k)
+    return ki
+
+
+def _resident_qi(qi, ki, nq: int, block_q: int, block_k: int, causal: bool,
+                 prefix):
+    """The same for the dk/dv kernel, which walks a column of cells: the
+    query block its q, dO, lse and delta maps name is the cell's own
+    when live, else the column's first live one (the last block in a
+    column with none)."""
+    if causal:
+        qi = jnp.maximum(qi, jnp.minimum(ki * block_k // block_q, nq - 1))
+    if prefix is not None:
+        q_per, k_per = prefix
+        group = ki * block_k // k_per + 1  # first row group that sees ki
+        qi = jnp.maximum(
+            qi, jnp.minimum(group * (q_per // block_q), nq - 1))
+    return qi
+
+
+def _census(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
+            prefix):
+    """``(dead, crossed, whole)`` cells of one head's ``nq x nk`` grid,
+    from the function the kernels dispatch on."""
+    live, whole = (np.broadcast_to(x, (nq, nk)) for x in _cell_kind(
+        np.arange(nq)[:, None], np.arange(nk)[None, :], block_q, block_k,
+        causal, prefix))
+    return (int((~live).sum()), int((live & ~whole).sum()),
+            int(whole.sum()))
+
+
+def _count_cells(heads: int, nq: int, nk: int, block_q: int, block_k: int,
+                 causal: bool, prefix) -> None:
+    """Add one kernel call's grid-wide census to ``flash.cells.dead`` /
+    ``.crossed`` / ``.whole`` (docs/OBSERVABILITY.md), at trace time.
+    Silent with tracing off."""
+    if not trace.enabled():
+        return
+    for kind, n in zip(("dead", "crossed", "whole"),
+                       _census(nq, nk, block_q, block_k, causal, prefix)):
+        trace.count(f"flash.cells.{kind}", heads * n)
+
+
+def _for_cell_kind(qi, ki, block_q: int, block_k: int, causal: bool, prefix,
+                   compute):
+    """Emit ``compute(mask)`` once for each kind of cell that does work
+    (:func:`_cell_kind`): for whole cells with ``mask=None`` (no iota,
+    no compare, no ``where``), for crossed cells with the block's mask.
+    Dead cells run nothing: that saves their MXU matmuls, their index
+    maps save the fetch. Without a mask there is one kind and no branch."""
+    if not causal and prefix is None:
+        compute(None)
+        return
+    live, whole = _cell_kind(qi, ki, block_q, block_k, causal, prefix)
+    pl.when(whole)(lambda: compute(None))
+    pl.when(live & ~whole)(lambda: compute(
+        _block_mask(qi, ki, block_q, block_k, causal, prefix)))
+
+
+def _block_probs(q_ref, k_ref, lse_ref, mask, scale: float):
     """Backward-pass helper: rebuild this block's softmax probabilities
     from (q, k, lse) — the FlashAttention-2 trick that replaces O(s²)
     stored residuals. Returns (q, k) in their stored dtype (bf16 dots
     run the MXU at full rate; f32 casts would quarter it) and p in
-    float32 (the exp must match the forward's f32 softmax state)."""
+    float32 (the exp must match the forward's f32 softmax state).
+    ``mask`` is the crossed cell's, ``None`` in a whole cell."""
     q = q_ref[0]
     k = k_ref[0]
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k, prefix)
-    p = jnp.where(valid, jnp.exp(logits - lse_ref[0, 0][:, None]), 0.0)
+    p = jnp.exp(logits - lse_ref[0, 0][:, None])
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
     return q, k, p
 
 
 def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
                           m_scr, l_scr, acc_scr, *, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          seq_k: int, prefix=None):
+                          prefix=None):
     """Forward kernel that also emits the log-sum-exp rows — the only
     residual the backward kernels need (FlashAttention-2 scheme: softmax
     is reconstructed from (q, k, lse), never stored)."""
@@ -224,7 +307,7 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def compute():
+    def compute(mask):
         # Inputs stay in their STORED dtype (bf16 on the flagship) so
         # the MXU runs at full bf16 rate; preferred_element_type keeps
         # the accumulation f32 — softmax state is always f32. Casting
@@ -236,8 +319,8 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        valid = _block_mask(qi, ki, block_q, block_k, causal, seq_k, prefix)
-        logits = jnp.where(valid, logits, _NEG_INF)
+        if mask is not None:
+            logits = jnp.where(mask, logits, _NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
         p = jnp.exp(logits - m_new[:, None])
@@ -248,9 +331,7 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # Blocks the mask leaves empty contribute nothing.
-    _when_live(ki * block_k <= qi * block_q + block_q - 1 if causal else None,
-               qi, ki, block_q, block_k, prefix, compute)
+    _for_cell_kind(qi, ki, block_q, block_k, causal, prefix, compute)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -261,8 +342,7 @@ def _flash_kernel_fwd_res(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal: bool, scale: float,
-                         block_q: int, block_k: int, seq_k: int,
-                         prefix=None):
+                         block_q: int, block_k: int, prefix=None):
     """dq = Σ_k  ds·K  with ds = P ∘ (dP − δ), P rebuilt from (q, k, lse).
     Grid (bh, nq, nk): each (bh, qi) accumulates over the key blocks."""
     qi = pl.program_id(1)
@@ -273,12 +353,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def compute():
+    def compute(mask):
         v = v_ref[0]
         g = g_ref[0]
-        _, k, p = _block_probs(q_ref, k_ref, lse_ref, qi, ki,
-                               causal=causal, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_k=seq_k, prefix=prefix)
+        _, k, p = _block_probs(q_ref, k_ref, lse_ref, mask, scale)
         dp = jax.lax.dot_general(
             g, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -287,8 +365,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(ki * block_k <= qi * block_q + block_q - 1 if causal else None,
-               qi, ki, block_q, block_k, prefix, compute)
+    _for_cell_kind(qi, ki, block_q, block_k, causal, prefix, compute)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -298,7 +375,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          seq_k: int, nq: int, prefix=None):
+                          nq: int, prefix=None):
     """dv = Σ_q Pᵀ·dO and dk = Σ_q dsᵀ·Q. Grid (b·kv_heads, nk, G·nq):
     each (bh, ki) accumulates over the query blocks of EVERY query head
     in the kv head's group (G = n_heads / kv_heads; 1 for MHA) — the
@@ -314,12 +391,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def compute():
+    def compute(mask):
         v = v_ref[0]
         g = g_ref[0]
-        q, _, p = _block_probs(q_ref, k_ref, lse_ref, qi, ki,
-                               causal=causal, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_k=seq_k, prefix=prefix)
+        q, _, p = _block_probs(q_ref, k_ref, lse_ref, mask, scale)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -331,10 +406,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # Query blocks that see nothing of this key block skip all four MXU
-    # matmuls.
-    _when_live(qi * block_q + block_q - 1 >= ki * block_k if causal else None,
-               qi, ki, block_q, block_k, prefix, compute)
+    # Query blocks that see nothing of this key block run no matmul.
+    _for_cell_kind(qi, ki, block_q, block_k, causal, prefix, compute)
 
     @pl.when(t == nt - 1)
     def _():
@@ -372,17 +445,29 @@ def _env_flash_blocks():
             # and fall back to the shipped default.
             warnings.warn(
                 f"mpi_tpu: ignoring malformed MPI_TPU_FLASH_BLOCKS="
-                f"{env!r} (expected 'BQ,BK', e.g. '256,512')",
+                f"{env!r} (expected 'BQ,BK', e.g. '1024,1024')",
                 stacklevel=2)
-    return [256, 512]
+    return [1024, 1024]
 
 
 # Default (block_q, block_k) used when flash_attention is called with
 # block sizes of None (every internal caller — transformer.py, ring
-# attention chunks). The shipped 256x512 comes from a v5e sweep
-# (128x128 keeps the MXU only ~30% as busy at s=1024); override per
-# device/shape with :func:`set_flash_block_defaults` (the
-# ops.autotune sweep does this) or MPI_TPU_FLASH_BLOCKS="bq,bk".
+# attention chunks); :func:`_pick_block` shrinks them to divide a short
+# sequence. Chosen on a v5e for the op alone, forward + backward, causal,
+# bfloat16, at (2, 4096, 24 -> 2 kv heads, 128), ms a call (PERF.md, PR
+# 32; the former default 256 x 512 took 12.53):
+#
+#     block_q \ block_k    128     256     512    1024
+#         256            29.76   20.90   12.53   10.33
+#         512            28.67   16.11    9.90    8.36
+#        1024            23.07   15.94   10.02    8.05
+#
+# The matmuls run near the MXU's floor; what the blocks move is the rest,
+# which follows the number of (query row, key block) pairs and of grid
+# cells, not the scores' area: wide blocks win although they leave fewer
+# cells dead or whole. Override with
+# :func:`set_flash_block_defaults` (the ops.autotune sweep does) or
+# MPI_TPU_FLASH_BLOCKS="bq,bk".
 _flash_block_default = _env_flash_blocks()
 
 
@@ -433,8 +518,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``interpret=None`` auto-selects interpreter mode off-TPU so tests run
     on CPU against the same kernel code. Block sizes of ``None`` take
-    the process-wide defaults (:func:`flash_block_defaults` — 256x512 from a v5e sweep unless the
-    :mod:`mpi_tpu.ops.autotune` sweep picked better for this shape);
+    the process-wide defaults (:func:`flash_block_defaults` — 1024x1024
+    from a v5e sweep unless the :mod:`mpi_tpu.ops.autotune` sweep picked
+    better for this shape);
     :func:`_pick_block` shrinks them to fit short sequences.
     """
     itp = _should_interpret() if interpret is None else interpret
@@ -468,16 +554,30 @@ def _gqa_layout(q, k, v):
     return qf, kf, vf, kv_index, group
 
 
-def _query_block(s: int, block_q: int, prefix) -> int:
-    """The query block: a divisor of the sequence and, under a prefix
-    mask, of its row groups too, so no block straddles two groups."""
+def _blocks(s: int, t: int, block_q, block_k, prefix):
+    """The call's ``(block_q, block_k)``: the asked or default sizes
+    shrunk to divisors of the two lengths and, under a prefix mask, of
+    its row groups too, so no query block straddles two groups. That
+    they divide is what lets the kernels drop the ``col < seq_k`` test."""
+    block_q, block_k = _resolve_blocks(block_q, block_k, s, t)
     if prefix is None:
-        return _pick_block(s, block_q)
-    if s % prefix[0]:
+        bq = _pick_block(s, block_q)
+    elif s % prefix[0]:
         raise ValueError(
             f"mpi_tpu: a prefix mask's row groups of {prefix[0]} must "
             f"divide the {s} query rows")
-    return _pick_block(prefix[0], block_q)
+    else:
+        bq = _pick_block(prefix[0], block_q)
+    bk = _pick_block(t, block_k)
+    assert s % bq == 0 and t % bk == 0, (s, bq, t, bk)
+    return bq, bk
+
+
+def _kv_spec(bq: int, bk: int, d: int, kv_index, causal: bool, prefix):
+    """k/v block of grid cell (bh, qi, ki) of the forward and dq kernels:
+    query head ``bh``'s kv head, the key block :func:`_resident_ki`."""
+    return pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (
+        kv_index(bh), _resident_ki(qi, ki, bq, bk, causal, prefix), 0))
 
 
 def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret,
@@ -492,23 +592,21 @@ def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret,
     nothing is materialised group-times larger."""
     b, s, h, d = q.shape
     t = k.shape[1]
-    block_q, block_k = _resolve_blocks(block_q, block_k, s, t)
-    bq = _query_block(s, block_q, prefix)
-    bk = _pick_block(t, block_k)
+    bq, bk = _blocks(s, t, block_q, block_k, prefix)
     qf, kf, vf, kv_index, _ = _gqa_layout(q, k, v)
     grid = (b * h, s // bq, t // bk)
+    _count_cells(*grid, bq, bk, causal, prefix)
     kernel = functools.partial(
         _flash_kernel_fwd_res, causal=causal, scale=_scale(q), block_q=bq,
-        block_k=bk, seq_k=t, prefix=prefix)
+        block_k=bk, prefix=prefix)
+    kspec = _kv_spec(bq, bk, d, kv_index, causal, prefix)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d),
-                         lambda bh, qi, ki: (kv_index(bh), ki, 0)),
-            pl.BlockSpec((1, bk, d),
-                         lambda bh, qi, ki: (kv_index(bh), ki, 0)),
+            kspec,
+            kspec,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -544,9 +642,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     b, s, h, d = q.shape
     t = k.shape[1]
     hk = k.shape[2]
-    block_q, block_k = _resolve_blocks(block_q, block_k, s, t)
-    bq = _query_block(s, block_q, prefix)
-    bk = _pick_block(t, block_k)
+    bq, bk = _blocks(s, t, block_q, block_k, prefix)
     qf, kf, vf, kv_index, group = _gqa_layout(q, k, v)
     gf = g.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     of = out.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -556,15 +652,16 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
                     -1)[:, None, :]
 
     common = dict(causal=causal, scale=_scale(q), block_q=bq, block_k=bk,
-                  seq_k=t, prefix=prefix)
+                  prefix=prefix)
+    nq, nk = s // bq, t // bk
     qspec = pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))
-    kspec = pl.BlockSpec((1, bk, d),
-                         lambda bh, qi, ki: (kv_index(bh), ki, 0))
+    kspec = _kv_spec(bq, bk, d, kv_index, causal, prefix)
     rowspec = pl.BlockSpec((1, 1, bq), lambda bh, qi, ki: (bh, 0, qi))
 
+    _count_cells(b * h, nq, nk, bq, bk, causal, prefix)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(b * h, s // bq, t // bk),
+        grid=(b * h, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
@@ -576,20 +673,23 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     # dk/dv: grid (b*hk, nk, group*nq) — ki owns the accumulation, the
     # third axis walks the group's query heads g-major so the scratch
     # gathers all of them; index maps send q/g/lse/delta at group
-    # member g's flat query head.
-    nq = s // bq
-
+    # member g's flat query head, at the query block a dead cell finds
+    # resident (the same cells as dq's, walked by column: counted again).
     def q_head(bh, gq):
         return (bh // hk) * h + (bh % hk) * group + gq // nq
 
+    def q_block(ki, gq):
+        return _resident_qi(gq % nq, ki, nq, bq, bk, causal, prefix)
+
     qspec2 = pl.BlockSpec(
-        (1, bq, d), lambda bh, ki, gq: (q_head(bh, gq), gq % nq, 0))
+        (1, bq, d), lambda bh, ki, gq: (q_head(bh, gq), q_block(ki, gq), 0))
     kspec2 = pl.BlockSpec((1, bk, d), lambda bh, ki, gq: (bh, ki, 0))
     rowspec2 = pl.BlockSpec(
-        (1, 1, bq), lambda bh, ki, gq: (q_head(bh, gq), 0, gq % nq))
+        (1, 1, bq), lambda bh, ki, gq: (q_head(bh, gq), 0, q_block(ki, gq)))
+    _count_cells(b * h, nq, nk, bq, bk, causal, prefix)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, nq=nq, **common),
-        grid=(b * hk, t // bk, group * nq),
+        grid=(b * hk, nk, group * nq),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[

@@ -1,9 +1,9 @@
 """Flash-attention block-size autotuner.
 
 The Pallas flash kernel's throughput on a given chip is dominated by
-its ``(block_q, block_k)`` grid shape — the shipped 256x512 default
-came from a hand sweep on v5e at s=1024 (2.6x over 128x128), but the
-best shape shifts with sequence length, head count, head dim, and chip
+its ``(block_q, block_k)`` grid shape — the shipped 1024x1024 default
+came from a sweep on v5e at s=4096 (``ops/attention.py`` has the
+table), but the best shape shifts with sequence length, head count, head dim, and chip
 generation. :func:`tune_flash_blocks` measures the real kernel
 (forward or forward+backward) over a candidate grid ON THE CURRENT
 BACKEND, registers the winner for the exact tuned shape

@@ -127,11 +127,16 @@ def _prefix(window: int, chunk: int):
 
 
 # (block_q, block_k) of the two passes, shrunk by the kernels to divide a
-# short window. Chosen on a v5e for the op alone, forward + backward, at
-# (1, 16384, 32, 128) with windows of 2048 and chunks of 16 (PERF.md, PR
-# 29): the flash default 256 x 512 took 44.7 ms; 1024 x 1024 for the
-# window's keys 36.5 (512 x 1024 37.2, 256 x 256 57.6); 1024 x 512 for the
-# 1,024 summaries 42.0 with the window's at the default (256 x 128 54.7).
+# short window. Chosen on a v5e for each pass alone, forward + backward,
+# bfloat16, 32 heads of 128 (PERF.md, PR 32; PR 29 had chosen the same two
+# from the whole op). ms a call, block_q down, block_k 128 / 256 / 512 /
+# 1024 across:
+#
+#   the window's keys, causal,         the 1,024 summaries of 16,384 rows,
+#   8 windows of 2,048 in the batch    prefix mask (2048, 128)
+#    256   49.82  35.49  23.48  19.91    256   20.65  16.45  11.85  10.98
+#    512   47.44  28.16  18.65  16.66    512   18.56  12.63   9.80   9.38
+#   1024   40.43  28.84  19.40  16.22   1024   14.17  11.46   9.16   9.31
 _LOCAL_BLOCKS = dict(block_q=1024, block_k=1024)
 _REMOTE_BLOCKS = dict(block_q=1024, block_k=512)
 

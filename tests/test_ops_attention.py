@@ -271,11 +271,11 @@ class TestAutotune:
             with warnings.catch_warnings(record=True) as w:
                 warnings.simplefilter("always")
                 got = A._env_flash_blocks()
-            assert got == [256, 512]
+            assert got == [1024, 1024]
             assert any("malformed" in str(x.message) for x in w)
         finally:
             del osmod.environ["MPI_TPU_FLASH_BLOCKS"]
-        assert A._env_flash_blocks() == [256, 512]
+        assert A._env_flash_blocks() == [1024, 1024]
 
     def test_disk_cache_roundtrip(self, tmp_path, monkeypatch):
         """MPI_TPU_TUNE_CACHE persists winners across processes: a
@@ -338,3 +338,238 @@ def test_tune_deadline_truncates_with_best_so_far(monkeypatch, tmp_path):
         assert not cache_file.exists()  # truncated -> not persisted
     finally:
         autotune._cache.clear()
+
+
+# --------------------------------------------------------------------------
+# Dead, crossed and whole grid cells (PR 32)
+# --------------------------------------------------------------------------
+
+from mpi_tpu.ops import attention as attn_mod  # noqa: E402
+from mpi_tpu.ops import flash_attention_with_lse, flash_chunk_bwd  # noqa: E402
+from mpi_tpu.utils import trace  # noqa: E402
+
+# 64 x 64 scores; a prefix of row groups of 16 over column groups of 12,
+# which straddle key blocks of 8 and of 16.
+_S = _T = 64
+_PREFIX = (16, 12)
+_MASKS = {"causal": (True, None), "prefix": (False, _PREFIX),
+          "both": (True, _PREFIX), "neither": (False, None)}
+_BLOCKS = [(8, 16), (16, 16), (16, 8)]   # block_q <, =, > block_k
+
+
+def _numpy_mask(causal, prefix, s=_S, t=_T):
+    row, col = np.arange(s)[:, None], np.arange(t)[None, :]
+    mask = np.ones((s, t), bool)
+    if causal:
+        mask &= row >= col
+    if prefix is not None:
+        mask &= col // prefix[1] < row // prefix[0]
+    return mask
+
+
+def _cells(mask, bq, bk):
+    for qi in range(mask.shape[0] // bq):
+        for ki in range(mask.shape[1] // bk):
+            yield qi, ki, mask[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+
+
+@pytest.mark.parametrize("blocks", _BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("kind", list(_MASKS))
+def test_cell_kind_matches_numpy_mask(kind, blocks):
+    """A cell is dead iff the mask is all false there, whole iff all
+    true: with Python ints (here), as with the kernels' traced scalars."""
+    causal, prefix = _MASKS[kind]
+    bq, bk = blocks
+    seen = set()
+    for qi, ki, tile in _cells(_numpy_mask(causal, prefix), bq, bk):
+        live, whole = attn_mod._cell_kind(qi, ki, bq, bk, causal, prefix)
+        assert bool(live) == bool(tile.any()), (qi, ki)
+        assert bool(whole) == bool(tile.all()), (qi, ki)
+        seen.add("whole" if whole else "crossed" if live else "dead")
+    want = {"neither": {"whole"}}.get(kind, {"dead", "crossed", "whole"})
+    assert seen == want
+
+
+def _tiles_census(mask, bq, bk):
+    tiles = [t for _, _, t in _cells(mask, bq, bk)]
+    return (sum(not t.any() for t in tiles),
+            sum(t.any() and not t.all() for t in tiles),
+            sum(t.all() for t in tiles))
+
+
+@pytest.mark.parametrize("blocks", _BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("kind", list(_MASKS))
+def test_census_of_a_head_matches_numpy_mask(kind, blocks):
+    """The same function over numpy index arrays, which is how the
+    census counts a head's grid."""
+    causal, prefix = _MASKS[kind]
+    bq, bk = blocks
+    assert attn_mod._census(_S // bq, _T // bk, bq, bk, causal, prefix) == \
+        _tiles_census(_numpy_mask(causal, prefix), bq, bk)
+
+
+@pytest.mark.parametrize("blocks", _BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("kind", list(_MASKS))
+def test_dead_cells_name_a_resident_block(kind, blocks):
+    """The index maps' clamps: a live cell keeps its own block; a dead
+    cell names the nearest live cell of the row (forward, dq) or column
+    (dk/dv) it is walked in, so nothing is fetched for it; a row or
+    column with no live cell names one block throughout."""
+    causal, prefix = _MASKS[kind]
+    bq, bk = blocks
+    nq, nk = _S // bq, _T // bk
+    live = np.zeros((nq, nk), bool)
+    for qi, ki, tile in _cells(_numpy_mask(causal, prefix), bq, bk):
+        live[qi, ki] = tile.any()
+    for qi in range(nq):
+        last = max(np.flatnonzero(live[qi]), default=0)
+        assert live[qi, :last + 1].all() or not live[qi].any()
+        for ki in range(nk):
+            got = int(attn_mod._resident_ki(qi, ki, bq, bk, causal, prefix))
+            assert got == min(ki, last), (qi, ki, got)
+    for ki in range(nk):
+        first = min(np.flatnonzero(live[:, ki]), default=nq - 1)
+        assert live[first:, ki].all() or not live[:, ki].any()
+        for qi in range(nq):
+            got = int(attn_mod._resident_qi(qi, ki, nq, bq, bk, causal,
+                                            prefix))
+            assert got == max(qi, first), (qi, ki, got)
+
+
+@pytest.fixture
+def traced():
+    was = trace.enabled()
+    trace.clear()
+    trace.enable()
+    try:
+        yield trace
+    finally:
+        trace.clear()
+        if not was:
+            trace.disable()
+
+
+def _cells_counted(counters):
+    return tuple(int(counters.get(f"flash.cells.{k}", 0))
+                 for k in ("dead", "crossed", "whole"))
+
+
+@pytest.mark.parametrize("blocks", _BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("heads", [(2, 2), (6, 2)], ids=["mha", "gqa6-2"])
+def test_three_kinds_match_dense_forward_and_grads(heads, blocks, traced):
+    """Causal flash at blocks where dead, crossed and whole cells all
+    occur: forward, dq, dk and dv against the dense oracle and its
+    ``jax.grad`` (kv heads repeated for GQA)."""
+    h, hk = heads
+    bq, bk = blocks
+    b, s, d = 1, _S, 8
+    key = jax.random.PRNGKey(32)
+    q = jax.random.normal(key, (b, s, h, d))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hk, d))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, hk, d))
+    g = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+    rep = lambda x: jnp.repeat(x, h // hk, axis=2)  # noqa: E731
+
+    out = flash_attention(q, k, v, True, bq, bk)
+    dead, crossed, whole = _cells_counted(traced.counters())
+    assert min(dead, crossed, whole) > 0
+    assert dead + crossed + whole == b * h * (s // bq) * (s // bk)
+    np.testing.assert_allclose(out, dense_attention(q, rep(k), rep(v)),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda q, k, v: jnp.vdot(
+        flash_attention(q, k, v, True, bq, bk), g), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.vdot(
+        dense_attention(q, rep(k), rep(v)), g), argnums=(0, 1, 2))(q, k, v)
+    for name, gg, w in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(gg, w, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(8, 16), (16, 8)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_prefix_noncausal_matches_eva_jnp_masking(blocks, traced):
+    """The prefix mask alone (EVA's summaries' pass), with column groups
+    that straddle key blocks: forward and the chunk backward against
+    materialised scores under ``_eva_jnp``'s remote mask,
+    ``col // k_per < row // q_per``. Rows of the first group see nothing
+    and carry no cotangent."""
+    bq, bk = blocks
+    q_per, k_per = _PREFIX
+    b, h, d = 1, 2, 8
+    key = jax.random.PRNGKey(29)
+    q = jax.random.normal(key, (b, _S, h, d))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, _T, h, d))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, _T, h, d))
+    g = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+    g = g.at[:, :q_per].set(0.0)
+    remote = jnp.arange(_T)[None, :] // k_per < jnp.arange(_S)[:, None] // q_per
+
+    def dense(q, k, v):
+        logits = jnp.einsum("bshk,bthk->bhst", q, k) * d ** -0.5
+        probs = jax.nn.softmax(
+            jnp.where(remote[None, None], logits, attn_mod.NEG_INF), axis=-1)
+        return jnp.einsum("bhst,bthk->bshk", probs, v)
+
+    out, lse = flash_attention_with_lse(q, k, v, False, bq, bk,
+                                        prefix=_PREFIX)
+    dead, crossed, whole = _cells_counted(traced.counters())
+    assert min(dead, crossed, whole) > 0
+    np.testing.assert_allclose(out[:, q_per:], dense(q, k, v)[:, q_per:],
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(out[:, :q_per]).any()
+    got = flash_chunk_bwd(q, k, v, out, lse, g, False, bq, bk,
+                          prefix=_PREFIX)
+    want = jax.grad(lambda q, k, v: jnp.vdot(dense(q, k, v), g),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, gg, w in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(gg, w, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+def _cell1_shapes():
+    q = jax.ShapeDtypeStruct((2, 4096, 24, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 4096, 2, 128), jnp.bfloat16)
+    return q, kv, kv
+
+
+@pytest.mark.parametrize("blocks,want", [
+    ((256, 512), (2688, 768, 2688)), ((None, None), (288, 192, 288))],
+    ids=["256x512", "default"])
+def test_census_of_cell_one_forward(blocks, want, traced):
+    """b 2 x h 24 x s 4096, times 48 heads. At 256 x 512, of a head's 128
+    cells 56 are dead, 16 crossed and 56 whole (live iff qi >= 2 ki,
+    whole iff qi >= 2 ki + 2); at the default 1024 x 1024, of 16 cells 6,
+    4 and 6. Counted when the call is built: nothing runs."""
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, True, *blocks),
+                   *_cell1_shapes())
+    assert _cells_counted(traced.counters()) == want
+
+
+@pytest.mark.parametrize("kind", list(_MASKS))
+def test_census_counts_each_kernel_call(kind, traced):
+    """64 x 64 at 16 x 16, one head: the forward's call adds the grid's
+    census once, the backward's two kernels once each (causal: 6 dead,
+    4 crossed, 6 whole; the prefix with or without it 10, 3, 3)."""
+    want = _tiles_census(_numpy_mask(*_MASKS[kind]), 16, 16)
+    assert want == {"causal": (6, 4, 6), "neither": (0, 0, 16)}.get(
+        kind, (10, 3, 3))
+    causal, prefix = _MASKS[kind]
+    x = jax.ShapeDtypeStruct((1, _S, 1, 8), jnp.float32)
+    jax.eval_shape(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal, 16, 16, prefix=prefix), x, x, x)
+    assert _cells_counted(traced.counters()) == want
+    lse = jax.ShapeDtypeStruct((1, 1, _S), jnp.float32)
+    jax.eval_shape(lambda q, k, v, o, l, g: flash_chunk_bwd(
+        q, k, v, o, l, g, causal, 16, 16, prefix=prefix), x, x, x, x, lse, x)
+    assert _cells_counted(traced.counters()) == tuple(3 * n for n in want)
+
+
+def test_census_stays_silent_with_tracing_off():
+    was = trace.enabled()
+    trace.disable()
+    trace.clear()
+    try:
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, True),
+                       *_cell1_shapes())
+        assert not [n for n in trace.counters() if n.startswith("flash.")]
+    finally:
+        if was:
+            trace.enable()

@@ -103,15 +103,27 @@ def test_flash_forward_compiles(one_chip):
              names=["%flash_fwd"])
 
 
-def test_flash_forward_backward_compiles(one_chip):
+@pytest.mark.parametrize("shape", [
+    (BATCH, SEQ, HEADS, HEADS), (2, 4096, 24, 2)],
+    ids=["flagship", "starcoder2-cell"])
+def test_flash_forward_backward_compiles(shape, one_chip):
+    """Causal, default blocks, at the flagship's shape and at the one the
+    benchmark's ``starcoder2-3b-L6.pretrain-4k-b2`` cell calls the
+    kernels with (batch 2 x 4,096, 24 query heads on 2 kv heads): the
+    grid in which dead, crossed and whole cells each take their own
+    branch and the index maps clamp."""
     from mpi_tpu.ops import flash_attention
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, True, None, None, False)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = _qkv(one_chip)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+    b, s, h, hk = shape
+    q = jax.ShapeDtypeStruct((b, s, h, HEAD_DIM), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, hk, HEAD_DIM), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
              names=FLASH_KERNELS)
 
 
