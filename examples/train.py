@@ -36,7 +36,9 @@ def main() -> None:
                              "ring_flash", "zigzag", "zigzag_flash",
                              "ulysses", "ulysses_flash"])
     ap.add_argument("--remat", action="store_true",
-                    help="rematerialise each block in the backward "
+                    help="rematerialise each block in the backward, "
+                         "holding only the attention kernels' output and "
+                         "log-sum-exp and the FFN's first matmul output "
                          "(train longer sequences in the same HBM)")
     ap.add_argument("--grad-accum", type=int, default=1,
                     help="microbatches per optimizer step")

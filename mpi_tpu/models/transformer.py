@@ -25,6 +25,7 @@ reference's ``bounce`` example exercises Send/Receive
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -34,7 +35,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..utils import trace
 
 __all__ = [
     "TransformerConfig",
@@ -94,9 +98,12 @@ class TransformerConfig:
     moe_aux_coef: float = 0.01
     moe_top_k: int = 1
     # Rematerialise each block in the backward pass (jax.checkpoint):
-    # activations per block are recomputed instead of stored, trading
-    # ~1/3 more FLOPs for O(n_layers) less residual memory — the switch
-    # that lets long sequences train on one chip's HBM.
+    # a block's backward recomputes in its forward what was not worth
+    # holding, trading FLOPs for O(n_layers) less residual memory — the
+    # switch that lets long sequences train on one chip's HBM. Held are
+    # the few values that are cheap to hold and dear to redo
+    # (``_REMAT_KEEPS``: the attention kernels' output and log-sum-exp,
+    # so they run once a layer, and the FFN's gate pre-activation).
     remat: bool = False
     # Grouped-query attention: number of k/v heads (None = n_heads,
     # plain MHA; 1 = MQA). Queries keep n_heads; k/v project to
@@ -383,6 +390,11 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         pos = jnp.arange(s, dtype=jnp.int32)
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
+    # Named before any consumer (EVA's pooling reads k, v too), for
+    # checkpointed_block; outside a checkpoint a name is the identity.
+    q = checkpoint_name(q, "attn_q")
+    k = checkpoint_name(k, "attn_k")
+    v = checkpoint_name(v, "attn_v")
     impl = cfg.attention_impl
     if impl != "flash":
         # The flash kernel reads grouped kv heads natively through its
@@ -504,10 +516,12 @@ def _ffn(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh]):
         return moe_ffn(x, blk["moe"], cfg.n_experts,
                        capacity_factor=cfg.capacity_factor, mesh=mesh,
                        top_k=cfg.moe_top_k)
-    h = jnp.einsum("bsd,df->bsf", x, blk["w1"].astype(x.dtype))
+    h = checkpoint_name(
+        jnp.einsum("bsd,df->bsf", x, blk["w1"].astype(x.dtype)), "ffn_gate")
     if cfg.ffn == "swiglu":
-        h = jax.nn.silu(h) * jnp.einsum("bsd,df->bsf", x,
-                                        blk["w3"].astype(x.dtype))
+        h = jax.nn.silu(h) * checkpoint_name(
+            jnp.einsum("bsd,df->bsf", x, blk["w3"].astype(x.dtype)),
+            "ffn_up")
     else:
         h = jax.nn.gelu(h)
     y = jnp.einsum("bsf,fd->bsd", h, blk["w2"].astype(x.dtype))
@@ -535,6 +549,70 @@ def block_body(x, blk, cfg: TransformerConfig,
         y, blk_aux = _ffn(h, blk, cfg, mesh)
         x = x + y.astype(x.dtype)
     return _act_constraint(x, mesh), blk_aux
+
+
+# What a block under ``remat`` holds from its forward for its backward:
+# of the values named in ``_attention``, ``_ffn`` and the attention ops'
+# forward rules, those that cost far more to redo than to hold. Everything
+# else is recomputed. Chosen on a v5e in the benchmark's EvaByte cell (4
+# layers of d 4096 / ff 11008, one sequence of 16,384 bytes, bfloat16;
+# PERF.md, PR 35): bytes held a token a layer; ms a step and tokens/s, one
+# run each, every run correct; GiB the v5e's compiler counts for the step
+# / for the benchmark's correctness program with the optimizer state
+# beside it, of the chip's 15.75. The rule: the fastest; of two within 1%
+# the one that holds less; none that leaves under 0.5 GiB.
+#
+#   (nothing: the bare checkpoint)    0  1,072.4  15,277  13.741 / 13.633
+#   out lse                       8,320  1,031.5  15,883  13.753 / 13.773
+#   out lse gate                 30,336    995.2  16,462  14.761 / 14.781  <-
+#   out lse gate ks vs           31,360    994.7  16,470  14.894 / 14.819
+#   out lse gate q               38,528    985.1  16,630  15.324 / 15.025
+#   out lse gate ks vs k v       47,744    984.8  16,635  15.193 / 15.427
+#   out lse gate k v             46,720  not run          15.161 / 15.521
+#   out lse ks vs q k v          33,920  not run          14.827 / 15.377
+#   out lse gate up              52,352  does not fit     15.474 / 15.866
+#
+# ``out`` + ``lse`` (``attn_out``, ``attn_lse``) take the forward kernels'
+# second run a layer away for 12 MiB more at the compiler's peak; the gate
+# pre-activation ``x @ w1`` (``ffn_gate``) one of the three recomputed FFN
+# matmuls for 344 MiB a layer.
+_REMAT_KEEPS = ("attn_out", "attn_lse", "ffn_gate")
+
+
+def checkpointed_block(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
+    """``block(x, blk) -> (x, aux_loss)``: :func:`block_body` for ``cfg``
+    on ``mesh``, and the one place it is wrapped for ``cfg.remat``. The
+    wrapped block's backward recomputes its forward except the named
+    values of ``_REMAT_KEEPS``, which it holds. With tracing on
+    (docs/OBSERVABILITY.md) each wrapped call adds 1 to ``remat.blocks``
+    and the bytes its backward would hold to ``remat.kept_bytes``: at
+    trace time, from the shapes."""
+    block = functools.partial(block_body, cfg=cfg, mesh=mesh)
+    if not cfg.remat:
+        return block
+    kept = jax.checkpoint(
+        block,
+        policy=jax.checkpoint_policies.save_only_these_names(*_REMAT_KEEPS))
+
+    def counted(x, blk):
+        if trace.enabled():
+            trace.count("remat.blocks")
+            trace.count("remat.kept_bytes", _kept_bytes(kept, x, blk))
+        return kept(x, blk)
+
+    return counted
+
+
+def _kept_bytes(fn, *args) -> int:
+    """Bytes ``fn``'s backward holds from its forward besides ``args``
+    themselves, from the shapes: what is held, less one of the same shape
+    and dtype for every leaf of ``args``."""
+    held = jax.eval_shape(lambda *a: jax.vjp(fn, *a)[1], *args)
+    left = collections.Counter(
+        (x.shape, x.dtype) for x in jax.tree.leaves(held))
+    left.subtract((x.shape, x.dtype) for x in jax.tree.leaves(args))
+    return sum(n * math.prod(shape) * dtype.itemsize
+               for (shape, dtype), n in left.items() if n > 0)
 
 
 def token_xent(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -586,9 +664,7 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
     x = _act_constraint(x, mesh)
     aux = jnp.zeros((), jnp.float32)
 
-    block = functools.partial(block_body, cfg=cfg, mesh=mesh)
-    if cfg.remat:
-        block = jax.checkpoint(block)
+    block = checkpointed_block(cfg, mesh)
     for blk in params["blocks"]:
         x, blk_aux = block(x, blk)
         aux = aux + blk_aux

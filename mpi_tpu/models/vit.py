@@ -16,7 +16,6 @@ beyond-parity breadth like the MoE/LoRA/quant variants.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -24,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .transformer import (TransformerConfig, _act_constraint, _dense_init,
-                          _layernorm, block_body, init_params,
+                          _layernorm, checkpointed_block, init_params,
                           make_optimizer, param_specs, sanitize_spec,
                           token_xent)
 
@@ -107,14 +106,11 @@ def _patchify(images: jax.Array, cfg: ViTConfig) -> jax.Array:
 def forward_vit(params: Dict[str, Any], images: jax.Array,
                 cfg: ViTConfig, mesh: Optional[Mesh] = None) -> jax.Array:
     """Class logits ``(b, n_classes)`` for ``(b, H, W, C)`` images."""
-    inner = cfg.inner
     dt = cfg.dtype
     x = _patchify(images.astype(dt), cfg) @ params["patch"].astype(dt)
     x = x + params["pos"].astype(dt)[None]
     x = _act_constraint(x, mesh)
-    body = functools.partial(block_body, cfg=inner, mesh=mesh)
-    if cfg.remat:
-        body = jax.checkpoint(body)
+    body = checkpointed_block(cfg.inner, mesh)
     for blk in params["blocks"]:
         x, _ = body(x, blk)
     x = _layernorm(x, params["final_ln"]["scale"].astype(dt),

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -769,6 +770,11 @@ def flash_chunk_bwd(q, k, v, out, lse, g, causal: bool = False,
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
     itp = _should_interpret() if interpret is None else interpret
     out, lse = _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, itp)
+    # Named for a block under ``remat`` (models/transformer.py,
+    # ``_REMAT_KEEPS``): held, they spare the backward this kernel's second
+    # run. Outside a checkpoint a name is the identity.
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -49,6 +49,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .attention import (NEG_INF, _scale, flash_attention_with_lse,
                         flash_chunk_bwd, merge_attention_chunks)
@@ -161,6 +162,11 @@ def _eva_flash_fwd(q, k, v, ks, vs, window, chunk, interpret):
                 prefix=_prefix(window, chunk), **_REMOTE_BLOCKS)
         with jax.named_scope("eva.merge"):
             out, lse = merge_attention_chunks(out, lse, out_r, lse_r)
+    # Named for a block under ``remat`` (models/transformer.py,
+    # ``_REMAT_KEEPS``): held, they spare the backward both forward passes
+    # and the merge. Outside a checkpoint a name is the identity.
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, ks, vs, out, lse)
 
 
@@ -199,6 +205,8 @@ def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
     with jax.named_scope("eva"):
         with jax.named_scope("eva.summarize"):
             ks, vs = eva_summaries(k, v, phi, mu, chunk)
+            ks = checkpoint_name(ks, "eva_ks")
+            vs = checkpoint_name(vs, "eva_vs")
         if impl == "jnp":
             return _eva_jnp(q, k, v, ks, vs, window, chunk)
         if impl != "flash":

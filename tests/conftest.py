@@ -116,6 +116,23 @@ def _free_port_block(n: int, lo: int = 20000, hi: int = 60000) -> int:
 
 
 @pytest.fixture
+def traced():
+    """``mpi_tpu.utils.trace`` with its buffer and counters empty and
+    recording on; left as it was found."""
+    from mpi_tpu.utils import trace
+
+    was = trace.enabled()
+    trace.clear()
+    trace.enable()
+    try:
+        yield trace
+    finally:
+        trace.clear()
+        if not was:
+            trace.disable()
+
+
+@pytest.fixture
 def cluster4():
     with tcp_cluster(4) as nets:
         yield nets

@@ -5,6 +5,8 @@ SPMD showcase: forward determinism, tp/dp/sp-sharded training parity with
 the unsharded single-device step, and the driver-contract entry points.
 """
 
+import collections
+import functools
 import sys
 from dataclasses import replace as dataclasses_replace
 from pathlib import Path
@@ -182,12 +184,26 @@ def _tokens(batch=4, seq=17, seed=0):
         jnp.int32)
 
 
-def test_remat_matches_plain_step():
-    """remat=True recomputes activations in the backward but must leave
-    the math untouched: identical loss and identical updated params."""
+_REMAT_IMPLS = ("eva", "flash", "dense")
+
+
+def _remat_cfg(impl, **kw):
+    """``_tiny`` with rope and a gated FFN, so every value a block under
+    remat can be told to hold exists; for eva two windows of two chunks."""
+    return _tiny(attention_impl=impl, rope=True, ffn="swiglu", eva_window=8,
+                 eva_chunk=4, **kw)
+
+
+@pytest.mark.parametrize("make", [_tiny] + [
+    functools.partial(_remat_cfg, impl) for impl in _REMAT_IMPLS],
+    ids=("classic",) + _REMAT_IMPLS)
+def test_remat_matches_plain_step(make):
+    """remat=True recomputes activations in the backward, all but the
+    named values it holds, which are the forward's own: it must leave
+    the math untouched, identical loss and identical updated params."""
     results = []
     for remat in (False, True):
-        init_state, step = make_train_step(_tiny(remat=remat))
+        init_state, step = make_train_step(make(remat=remat))
         state = init_state(jax.random.PRNGKey(0))
         state, loss = step(state, _tokens())
         results.append((float(loss), state["params"]))
@@ -195,6 +211,92 @@ def test_remat_matches_plain_step():
     np.testing.assert_allclose(l0, l1, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(p0), jax.tree.leaves(p1)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _pallas_calls(jaxpr, found=None):
+    """``{kernel name: calls}`` over a jaxpr and every jaxpr inside it."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (
+                    value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _pallas_calls(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("impl, forward_kernels", [
+    ("eva", ("flash_fwd", "eva_remote_fwd")), ("flash", ("flash_fwd",))])
+def test_remat_runs_forward_kernels_once_a_layer(impl, forward_kernels):
+    """The attention op's output and log-sum-exp are held, so the
+    gradient of a block under remat has no second forward kernel."""
+    from mpi_tpu.models.transformer import loss_fn
+
+    cfg = _remat_cfg(impl, remat=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    calls = _pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda p, t: loss_fn(p, t, cfg)))(params, _tokens()).jaxpr)
+    for name in forward_kernels + ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert calls[name] == cfg.n_layers, (name, dict(calls))
+
+
+def _named_bytes(cfg, batch, seq):
+    """Bytes of each value a block names, by hand, float32 throughout."""
+    d, h, f = cfg.d_model, cfg.n_heads, cfg.d_ff
+    token_wide = 4 * batch * seq
+    by_hand = {"attn_q": token_wide * d, "attn_k": token_wide * d,
+               "attn_v": token_wide * d, "ffn_gate": token_wide * f,
+               "ffn_up": token_wide * f}
+    if cfg.attention_impl != "dense":
+        by_hand.update(attn_out=token_wide * d, attn_lse=token_wide * h)
+    if cfg.attention_impl == "eva":
+        summaries = 4 * batch * (seq // cfg.eva_chunk) * d
+        by_hand.update(eva_ks=summaries, eva_vs=summaries)
+    return by_hand
+
+
+@pytest.mark.parametrize("keeps", ["as committed", "every name"])
+@pytest.mark.parametrize("impl", _REMAT_IMPLS)
+def test_remat_counters_read_their_hand_count(impl, keeps, monkeypatch,
+                                              traced):
+    """``remat.blocks`` and ``remat.kept_bytes`` with tracing on; silent
+    with it off. With every name kept, each named site is checked."""
+    from mpi_tpu.models import transformer
+
+    cfg = _remat_cfg(impl, remat=True)
+    tok = _tokens()
+    by_hand = _named_bytes(cfg, tok.shape[0], tok.shape[1] - 1)
+    if keeps == "every name":
+        monkeypatch.setattr(transformer, "_REMAT_KEEPS", tuple(by_hand))
+    want = cfg.n_layers * sum(
+        by_hand.get(name, 0) for name in transformer._REMAT_KEEPS)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+
+    def counted_while_tracing_the_gradient():
+        jax.make_jaxpr(jax.grad(     # a new function: nothing cached
+            lambda p, t: transformer.loss_fn(p, t, cfg)))(params, tok)
+        return {k: v for k, v in traced.counters().items()
+                if k.startswith("remat.")}
+
+    traced.disable()
+    assert counted_while_tracing_the_gradient() == {}
+    traced.enable()
+    assert counted_while_tracing_the_gradient() == {
+        "remat.blocks": cfg.n_layers, "remat.kept_bytes": want}
+
+
+def test_plain_block_is_returned_as_it_is():
+    """Without remat the helper hands back the block itself: nothing
+    wrapped, nothing counted, the program of a model without remat as
+    it was."""
+    from mpi_tpu.models.transformer import block_body, checkpointed_block
+
+    block = checkpointed_block(_tiny(), None)
+    assert block.func is block_body and block.keywords == {
+        "cfg": _tiny(), "mesh": None}
 
 
 def test_grad_accum_matches_full_batch():

@@ -436,19 +436,6 @@ def test_dead_cells_name_a_resident_block(kind, blocks):
             assert got == max(qi, first), (qi, ki, got)
 
 
-@pytest.fixture
-def traced():
-    was = trace.enabled()
-    trace.clear()
-    trace.enable()
-    try:
-        yield trace
-    finally:
-        trace.clear()
-        if not was:
-            trace.disable()
-
-
 def _cells_counted(counters):
     return tuple(int(counters.get(f"flash.cells.{k}", 0))
                  for k in ("dead", "crossed", "whole"))

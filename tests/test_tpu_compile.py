@@ -18,7 +18,9 @@ the test's own process, and all such tests live in this ONE file so a
 single worker owns the library.
 """
 
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +82,10 @@ def _compile(fn, *args, names=()):
     """Compile for the described chip; ``names`` are what a profiler
     trace of the program is read by (kernel names as the instructions'
     names, layer scopes inside ``op_name``) and must be in its text."""
-    compiled = jax.jit(fn).lower(*args).compile()
+    # A train step is jitted already, with its state donated: wrapped in
+    # another jit it would lose the donation and count its state twice.
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = jitted.lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, \
         "no Mosaic kernel in the compiled program"
@@ -127,20 +132,15 @@ def test_flash_forward_backward_compiles(shape, one_chip):
              names=FLASH_KERNELS)
 
 
-def _flagship_step_args(mesh):
-    """(step, state, tokens) for the flagship train step on ``mesh``,
-    as shapes carrying the shardings the program itself would commit:
+def _step_args(cfg, mesh, batch, seq):
+    """(step, state, tokens) for ``cfg``'s train step on ``mesh``, as
+    shapes carrying the shardings the program itself would commit:
     parameters to ``sane_param_specs``; the optimizer state to nothing,
     since ``init_state`` builds it with a bare ``jit(opt.init)`` and
     leaves it uncommitted; the batch to ShardedLoader's ``P('dp', None)``."""
-    from mpi_tpu.models import (TransformerConfig, make_train_step,
-                                sanitize_spec)
+    from mpi_tpu.models import make_train_step, sanitize_spec
     from mpi_tpu.models.transformer import sane_param_specs
 
-    cfg = TransformerConfig(
-        vocab=VOCAB, d_model=D_MODEL, n_heads=HEADS, n_layers=LAYERS,
-        d_ff=D_FF, max_seq=SEQ + 1, dtype=jnp.bfloat16,
-        attention_impl="flash")
     init_state, step = make_train_step(cfg, mesh=mesh)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
     params = jax.tree.map(
@@ -150,9 +150,19 @@ def _flagship_step_args(mesh):
     opt = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state["opt"])
     tokens = jax.ShapeDtypeStruct(
-        (BATCH, SEQ + 1), jnp.int32,
+        (batch, seq + 1), jnp.int32,
         sharding=NamedSharding(mesh, sanitize_spec(P("dp", None), mesh)))
     return step, {"params": params, "opt": opt}, tokens
+
+
+def _flagship_step_args(mesh):
+    from mpi_tpu.models import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab=VOCAB, d_model=D_MODEL, n_heads=HEADS, n_layers=LAYERS,
+        d_ff=D_FF, max_seq=SEQ + 1, dtype=jnp.bfloat16,
+        attention_impl="flash")
+    return _step_args(cfg, mesh, BATCH, SEQ)
 
 
 def test_flagship_train_step_compiles_one_chip(topo, compiled_kernels):
@@ -199,6 +209,50 @@ def test_eva_attention_compiles_at_evabyte_shapes(one_chip):
              names=FLASH_KERNELS + EVA_KERNELS + (
                  "eva.summarize", "/eva.local/", "/eva.remote/",
                  "/eva.merge/"))
+
+
+# What the compiler may count for the EvaByte cell's step: 14.761 GiB with
+# what ``_REMAT_KEEPS`` holds today (13.741 with nothing held).
+EVABYTE_STEP_GIB = 15.0
+CHIP_GIB = 15.75
+
+
+def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
+        topo, compiled_kernels):
+    """The whole train step of the benchmark's ``evabyte-L4.pretrain-16k-b1``
+    cell (its ``model`` as the configuration file has it, ``remat`` on, one
+    sequence of 16,384 bytes and its target): it compiles, which a program
+    too large for the chip does not; what a block keeps under ``remat``
+    (``models/transformer.py`` ``_REMAT_KEEPS``) leaves each forward kernel
+    one call a layer; and the compiler's count of its memory stays under
+    ``EVABYTE_STEP_GIB``, so that a later change's extra temporary cannot
+    push the cell out of the chip's memory unseen."""
+    from mpi_tpu.models import TransformerConfig, make_mesh_nd
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "evabyte-L4.json")) as f:
+        model = json.load(f)["model"]
+    seq = 16384
+    cfg = TransformerConfig(**dict(model, dtype=jnp.dtype(model["dtype"]),
+                                   max_seq=seq + 1))
+    assert cfg.remat
+    mesh = make_mesh_nd(1, devices=topo.devices[:1])
+    compiled = _compile(*_step_args(cfg, mesh, 1, seq),
+                        names=FLASH_KERNELS + EVA_KERNELS + LAYER_SCOPES)
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "eva_remote_fwd"):
+        calls = len(re.findall(rf"%{kernel}(\.\d+)? = ", text))
+        assert calls == cfg.n_layers, \
+            f"{calls} %{kernel} calls for {cfg.n_layers} layers"
+    mem = compiled.memory_analysis()
+    gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+           + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    assert gib < EVABYTE_STEP_GIB, (
+        f"the compiler counts {gib:.3f} GiB for the step, over the "
+        f"{EVABYTE_STEP_GIB} GiB this case allows, which leaves "
+        f"{CHIP_GIB - EVABYTE_STEP_GIB:.2f} GiB of the chip's {CHIP_GIB} for "
+        f"the benchmark's correctness program (up to 0.24 GiB larger) and "
+        f"what else the process holds")
 
 
 @pytest.fixture(scope="module")

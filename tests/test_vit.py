@@ -127,6 +127,20 @@ def test_remat_with_mesh_matches_no_remat():
     assert float(l2) < float(l1)
 
 
+def test_remat_goes_through_the_one_helper(traced):
+    """``forward_vit`` wraps its blocks where the decoder does
+    (``transformer.checkpointed_block``): the helper's counter sees every
+    block of an encoder under remat and none of one without."""
+    import dataclasses
+
+    params = init_vit_params(jax.random.PRNGKey(4), CFG)
+    imgs, _ = _images(2)
+    forward_vit(params, imgs, CFG)
+    assert "remat.blocks" not in traced.counters()
+    forward_vit(params, imgs, dataclasses.replace(CFG, remat=True))
+    assert traced.counters()["remat.blocks"] == CFG.n_layers
+
+
 def test_encoder_sequence_parallel_ulysses_and_ring():
     """causal=False flows through to the contiguous ring and ulysses
     sequence-parallel impls (only zigzag is causal-only): encoder
