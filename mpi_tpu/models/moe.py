@@ -9,12 +9,21 @@ inserts the all-to-all exchanges that carry tokens to their experts over
 ICI — the standard tpu-native MoE dataflow (no reference analogue:
 btracey/mpi has no ML code, SURVEY.md §2).
 
-Everything here is einsum/one-hot arithmetic — MXU-friendly, fully
-differentiable, no data-dependent shapes.
+Everything in :func:`moe_ffn` is einsum/one-hot arithmetic — MXU-friendly,
+fully differentiable, no data-dependent shapes.
+
+:func:`routed_share_ffn` is the other routed layer: one device's share of
+an expert-parallel layer whose experts outnumber the devices. It scores
+every expert of the layer, is told which contiguous share it holds, drops
+no (token, expert) pair whatever the load, and adds a shared expert. The
+pairs that land on held experts are sorted by expert and laid out in tiles
+of rows that belong to one expert each; the expert products are two
+matmuls a tile, in one loop over the occupied tiles.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -23,20 +32,24 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["init_moe_params", "moe_specs", "moe_ffn"]
+from ..utils import trace
+
+__all__ = ["init_moe_params", "moe_specs", "moe_ffn",
+           "init_routed_share_params", "routed_share_specs",
+           "routed_share_ffn", "route_top_k", "floor_tiles"]
+
+
+def _dense(key, shape, fan_in, dtype):
+    return (jax.random.normal(key, shape) / math.sqrt(fan_in)).astype(dtype)
 
 
 def init_moe_params(key: jax.Array, d_model: int, d_ff: int,
                     n_experts: int, dtype: Any) -> Dict[str, Any]:
     k1, k2, k3 = jax.random.split(key, 3)
-
-    def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape) / math.sqrt(fan_in)).astype(dtype)
-
     return {
-        "router": dense(k1, (d_model, n_experts), d_model),
-        "w1e": dense(k2, (n_experts, d_model, d_ff), d_model),
-        "w2e": dense(k3, (n_experts, d_ff, d_model), d_ff),
+        "router": _dense(k1, (d_model, n_experts), d_model, dtype),
+        "w1e": _dense(k2, (n_experts, d_model, d_ff), d_model, dtype),
+        "w2e": _dense(k3, (n_experts, d_ff, d_model), d_ff, dtype),
     }
 
 
@@ -132,3 +145,227 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     mean_prob = jnp.mean(probs, axis=(0, 1))
     aux = e * jnp.sum(frac * mean_prob)
     return y, aux.astype(jnp.float32)
+
+
+# --------------------------------------------------------------------------
+# One device's share of a sigmoid-routed layer, without a dropped pair
+# --------------------------------------------------------------------------
+
+def init_routed_share_params(key: jax.Array, d_model: int, d_ff: int,
+                             d_shared: int, n_experts: int, held: int,
+                             dtype: Any) -> Dict[str, Any]:
+    """The router over all ``n_experts``, ``held`` two-matrix experts of
+    width ``d_ff`` and the shared expert of width ``d_shared``."""
+    keys = jax.random.split(key, 5)
+    return {
+        "router": _dense(keys[0], (d_model, n_experts), d_model, dtype),
+        "w_up": _dense(keys[1], (held, d_model, d_ff), d_model, dtype),
+        "w_down": _dense(keys[2], (held, d_ff, d_model), d_ff, dtype),
+        "shared_up": _dense(keys[3], (d_model, d_shared), d_model, dtype),
+        "shared_down": _dense(keys[4], (d_shared, d_model), d_shared, dtype),
+    }
+
+
+def routed_share_specs() -> Dict[str, P]:
+    """Every leaf replicated: the share IS the device's part of the
+    expert-parallel layer, and nothing of it is split further."""
+    return {name: P() for name in (
+        "router", "w_up", "w_down", "shared_up", "shared_down")}
+
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def _router_scores(x2, router):
+    """``sigmoid(x W_r)`` over every expert of the layer, product and
+    scores in float32 (at the highest precision: a TPU's default would
+    round the operands to bfloat16)."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x2.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+
+
+# Rows of one tile of the dispatch loop: every tile belongs to one expert,
+# so an expert's rows are padded to whole tiles.
+_TILE = 512
+
+
+def route_top_k(x2: jax.Array, router: jax.Array, top_k: int,
+                scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """The routed layer's decisions for ``x2`` ``(tokens, d)``: the
+    ``top_k`` largest of ``sigmoid(x W_r)`` over every expert of the layer
+    as ``idx`` ``(tokens, top_k)``, and their weights ``s_k / (sum_k s_k +
+    1e-20) * scale`` in float32."""
+    chosen, idx = lax.top_k(_router_scores(x2, router), top_k)
+    return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+def _tile_layout(order, sizes, top_k: int):
+    """The sorted pairs ``order`` laid out with each held expert's
+    ``sizes[e]`` rows padded to whole tiles. Returns the occupied tiles
+    and ``rows_of(t) -> (e, pair, token, live)`` for tile ``t``: its
+    expert, and a row's pair, token and whether the row holds a pair (a
+    tile past the occupied ones holds none)."""
+    held = sizes.shape[0]
+    first = jnp.cumsum(sizes) - sizes              # in ``order``
+    tiles = -(-sizes // _TILE)                     # whole tiles an expert
+    tile_end = jnp.cumsum(tiles)
+
+    def rows_of(t):
+        e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
+                        held - 1)
+        within = (t - (tile_end[e] - tiles[e])) * _TILE + jnp.arange(_TILE)
+        live = (t < tile_end[-1]) & (within < sizes[e])
+        pair = order[jnp.clip(first[e] + within, 0, order.size - 1)]
+        return e, pair, pair // top_k, live
+
+    return tile_end[-1], rows_of
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _expert_tiles(x2, w_up, w_down, pair_weight, order, sizes, top_k,
+                  floor):
+    """``sum over a token's pairs of weight x W_down[e] relu(W_up[e] x)^2``
+    for the pairs on held experts: ``x2`` ``(tokens, d)``, ``pair_weight``
+    ``(tokens * top_k,)``, ``order`` the pairs sorted by held expert and
+    ``sizes`` the pairs of each; returns ``(tokens, d)`` in ``x2``'s dtype.
+
+    ONE loop over the tiles, with a trip count read from the load: a
+    tile's rows are gathered, go through two matmuls with their expert's
+    matrices, and are added to their tokens' rows, so the work follows
+    the pairs and nothing holds a buffer. The loop runs at least ``floor``
+    tiles (the tiles past the occupied ones hold no pair and add
+    nothing). The backward pass is the same loop again: it recomputes a
+    tile's hidden rows and adds its two weight gradients into float32
+    sums in place."""
+    tokens, d = x2.shape
+    occupied, rows_of = _tile_layout(order, sizes, top_k)
+
+    def body(t, acc):
+        e, pair, token, live = rows_of(t)
+        out = jnp.dot(_relu2(jnp.dot(x2[token], w_up[e])), w_down[e])
+        weight = jnp.where(live, pair_weight[pair], 0)
+        return acc.at[token].add(out.astype(jnp.float32) * weight[:, None])
+
+    return lax.fori_loop(0, jnp.maximum(occupied, floor), body,
+                         jnp.zeros((tokens, d), jnp.float32)
+                         ).astype(x2.dtype)
+
+
+def _expert_tiles_fwd(x2, w_up, w_down, pair_weight, order, sizes, top_k,
+                      floor):
+    return (_expert_tiles(x2, w_up, w_down, pair_weight, order, sizes,
+                          top_k, floor),
+            (x2, w_up, w_down, pair_weight, order, sizes))
+
+
+def _expert_tiles_bwd(top_k, floor, held_back, g):
+    x2, w_up, w_down, pair_weight, order, sizes = held_back
+    f32 = jnp.float32
+    occupied, rows_of = _tile_layout(order, sizes, top_k)
+
+    def body(t, sums):
+        d_x, d_up, d_down, d_weight = sums
+        e, pair, token, live = rows_of(t)
+        x, g_t = x2[token], g[token]
+        weight = jnp.where(live, pair_weight[pair], 0)[:, None]
+        r = jax.nn.relu(jnp.dot(x, w_up[e]))
+        r2 = r * r
+        back = jnp.dot(g_t, w_down[e].T)            # d out / d hidden
+        d_weight = d_weight.at[pair].add(jnp.where(
+            live, (r2.astype(f32) * back.astype(f32)).sum(-1), 0))
+        d_down = d_down.at[e].add(jnp.dot(
+            (r2 * weight.astype(r2.dtype)).T, g_t, preferred_element_type=f32))
+        d_pre = back * (2 * r) * weight.astype(r.dtype)
+        d_up = d_up.at[e].add(jnp.dot(x.T, d_pre, preferred_element_type=f32))
+        d_x = d_x.at[token].add(jnp.dot(d_pre, w_up[e].T).astype(f32))
+        return d_x, d_up, d_down, d_weight
+
+    d_x, d_up, d_down, d_weight = lax.fori_loop(
+        0, jnp.maximum(occupied, floor), body,
+        (jnp.zeros(x2.shape, f32), jnp.zeros(w_up.shape, f32),
+         jnp.zeros(w_down.shape, f32), jnp.zeros(pair_weight.shape, f32)))
+    return (d_x.astype(x2.dtype), d_up.astype(w_up.dtype),
+            d_down.astype(w_down.dtype), d_weight.astype(pair_weight.dtype),
+            None, None)
+
+
+_expert_tiles.defvjp(_expert_tiles_fwd, _expert_tiles_bwd)
+
+
+def floor_tiles(tokens: int, top_k: int, held: int, n_experts: int) -> int:
+    """Tiles the dispatch loop always runs: those of a buffer of twice the
+    pairs that uniform routing sends to the held experts. With weights as
+    drawn a router is far from uniform (over 640 readings on the chip a
+    share's load lay between 0.31 and 2.58 times the uniform one, median
+    0.98: PERF.md, PR 36); up to this many tiles a step's time does not
+    depend on the draw, past it the loop goes on over the occupied tiles
+    and no pair is dropped."""
+    return -(-2 * tokens * top_k * held // (n_experts * _TILE))
+
+
+def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
+                     top_k: int, offset: int = 0,
+                     scale: float = 1.0) -> jax.Array:
+    """``x`` ``(batch, seq, d)`` -> ``sum_k w_k expert_k(x) + shared(x)``
+    over the experts ``offset .. offset + held - 1`` that ``params`` holds
+    (``held = params["w_up"].shape[0]``); what the other experts of the
+    layer would add is left out.
+
+    Routing (:func:`route_top_k`): ``s = sigmoid(x W_r)`` over all
+    ``n_experts``, product and scores in float32; the ``top_k`` largest;
+    weights ``s_k / (sum_k s_k + 1e-20) * scale`` (the sum over all
+    ``top_k`` chosen, held or not). An expert is ``W_down relu(W_up
+    x)^2``, the shared expert the same at its own width for every token.
+
+    No pair that lands on a held expert is dropped, whatever the load. The
+    pairs are sorted by expert (those of absent experts last) and laid out
+    with each expert's rows padded to whole tiles of ``_TILE``, so that a
+    tile's rows share one expert, and one loop runs over the occupied
+    tiles (:func:`_expert_tiles`; at least :func:`floor_tiles` of them).
+    Scopes ``moe.route``, ``moe.routed`` (sort, dispatch, expert products,
+    combine) and ``moe.shared``; with tracing on each call adds 1 to
+    ``moe.layers``, ``held`` to ``moe.experts_held`` and the rows of the
+    tiles the loop always runs to ``moe.rows`` (at trace time)."""
+    b, s, d = x.shape
+    tokens, held = b * s, params["w_up"].shape[0]
+    if not 1 <= top_k <= n_experts:
+        raise ValueError(
+            f"mpi_tpu: moe top_k={top_k} must be in [1, n_experts="
+            f"{n_experts}]")
+    if offset < 0 or offset + held > n_experts:
+        raise ValueError(
+            f"mpi_tpu: experts {offset}..{offset + held - 1} are not among "
+            f"the layer's {n_experts}")
+    floor = floor_tiles(tokens, top_k, held, n_experts)
+    if trace.enabled():
+        trace.count("moe.layers")
+        trace.count("moe.experts_held", held)
+        trace.count("moe.rows", floor * _TILE)
+    x2 = x.reshape(tokens, d)
+
+    with jax.named_scope("moe.route"):
+        idx, weight = route_top_k(x2, params["router"], top_k, scale)
+        local = idx - offset
+        # A pair's key: its expert's place in the share, or ``held`` for
+        # an absent expert, so that a sort puts the share's pairs first.
+        key = jnp.where((local >= 0) & (local < held), local,
+                        held).reshape(-1)
+
+    with jax.named_scope("moe.routed"):
+        order = jnp.argsort(key, stable=True)
+        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
+            0, dtype=jnp.int32)
+        routed = _expert_tiles(
+            x2, params["w_up"].astype(x.dtype),
+            params["w_down"].astype(x.dtype), weight.reshape(-1), order,
+            sizes, top_k, floor)
+
+    with jax.named_scope("moe.shared"):
+        shared = jnp.einsum(
+            "tf,fd->td",
+            _relu2(jnp.einsum("td,df->tf", x2,
+                              params["shared_up"].astype(x.dtype))),
+            params["shared_down"].astype(x.dtype))
+    return (routed + shared).reshape(b, s, d)

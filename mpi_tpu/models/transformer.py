@@ -29,7 +29,7 @@ import collections
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,7 @@ __all__ = [
     "init_params",
     "forward",
     "forward_with_aux",
+    "routed_choices",
     "param_specs",
     "sanitize_spec",
     "apply_rope",
@@ -131,7 +132,7 @@ class TransformerConfig:
     # zero, no bias).
     norm: str = "layernorm"
     # Dense FFN: "gelu" (two matrices, w2(gelu(w1 x))) | "swiglu" (three,
-    # w2(silu(w1 x) * (w3 x))).
+    # w2(silu(w1 x) * (w3 x))) | "relu2" (two, w2(relu(w1 x)^2)).
     ffn: str = "gelu"
     # Output head: the embedding's transpose (tied), or an untied matrix
     # ``head`` of ``n_pred_heads * vocab`` rows whose logits are float32.
@@ -146,19 +147,58 @@ class TransformerConfig:
     # None = the compute dtype. "float32" keeps the sum x + f(norm(x)) in
     # float32 while matmuls and attention run in ``dtype``.
     residual_dtype: Any = None
+    # Size of an attention head where ``n_heads`` of them do not make up
+    # ``d_model`` (None = ``d_model // n_heads``): q projects to
+    # ``n_heads x attn_head_dim``, ``wo`` back from it.
+    attn_head_dim: Optional[int] = None
+    # With ``rope`` off: the learned absolute table (True), or no position
+    # term at all (False; a model whose mixers see order by themselves).
+    position_table: bool = True
+    # A block stack driven by a list of layer kinds, one letter a layer
+    # (``n_layers`` = its length; None = the classic block throughout).
+    # Block ``i`` is then ``x + f_i(norm(x))`` with ONE sub-layer, one norm
+    # (``ln1``) and only its own leaves: ``M`` a Mamba-2 mixer
+    # (models/mamba2.py; the ``ssm_*`` sizes below), ``*`` attention (the
+    # ``_attention`` of the classic block), ``E`` one device's share of a
+    # sigmoid-routed expert layer beside a shared expert (models/moe.py
+    # ``routed_share_ffn``): ``n_experts`` is the width of the router,
+    # ``moe_top_k`` the experts a token, ``d_ff`` an expert's width,
+    # ``moe_experts_held`` contiguous experts from ``moe_expert_offset``
+    # live here (None = all), ``moe_shared_d_ff`` is the shared expert's
+    # width and ``moe_routed_scale`` multiplies the normalised weights.
+    # ``M`` and ``*`` run under the scope ``attn``, ``E`` under ``ffn``.
+    layer_pattern: Optional[str] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    moe_experts_held: Optional[int] = None
+    moe_expert_offset: int = 0
+    moe_shared_d_ff: int = 0
+    moe_routed_scale: float = 1.0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm_unit_offset"):
             raise ValueError(
                 f"mpi_tpu: unknown norm {self.norm!r}: expected "
                 f"layernorm|rmsnorm_unit_offset")
-        if self.ffn not in ("gelu", "swiglu"):
+        if self.ffn not in ("gelu", "swiglu", "relu2"):
             raise ValueError(
-                f"mpi_tpu: unknown ffn {self.ffn!r}: expected gelu|swiglu")
-        if self.ffn != "gelu" and self.n_experts > 0:
+                f"mpi_tpu: unknown ffn {self.ffn!r}: expected "
+                f"gelu|swiglu|relu2")
+        if self.layer_pattern is not None:
+            self._check_layer_pattern()
+        elif self.ffn != "gelu" and self.n_experts > 0:
             raise ValueError(
-                f"mpi_tpu: ffn={self.ffn!r} is the dense FFN's; the experts "
-                f"of models/moe.py are two-matrix GELU")
+                f"mpi_tpu: ffn={self.ffn!r} is the dense FFN's; the "
+                f"capacity-routed experts of models/moe.py are two-matrix "
+                f"GELU")
+        if self.rope and not self.position_table:
+            raise ValueError(
+                "mpi_tpu: position_table=False means no position term at "
+                "all; rope=True is one")
         if self.n_pred_heads < 1 or (self.n_pred_heads > 1
                                      and self.tie_embeddings):
             raise ValueError(
@@ -166,6 +206,41 @@ class TransformerConfig:
                 f"tie_embeddings=False (each head has its own output rows)")
         if self.attention_impl == "eva" and not self.causal:
             raise ValueError("mpi_tpu: attention_impl='eva' is causal only")
+
+    def _check_layer_pattern(self):
+        pattern = self.layer_pattern
+        if not pattern or set(pattern) - set("ME*") \
+                or len(pattern) != self.n_layers:
+            raise ValueError(
+                f"mpi_tpu: layer_pattern={pattern!r} must be n_layers="
+                f"{self.n_layers} letters of M (Mamba-2), E (experts), "
+                f"* (attention)")
+        if "M" in pattern and (self.ssm_heads < 1
+                               or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                f"mpi_tpu: an M layer needs ssm_heads (got {self.ssm_heads}) "
+                f"in whole groups of ssm_groups={self.ssm_groups}")
+        if "E" in pattern:
+            if self.ffn != "relu2":
+                raise ValueError(
+                    f"mpi_tpu: the experts of an E layer are relu2 (two "
+                    f"matrices); ffn={self.ffn!r} experts are not "
+                    f"implemented")
+            held = self.experts_held
+            if (self.n_experts < 1 or held < 1 or self.moe_shared_d_ff < 1
+                    or self.moe_expert_offset < 0
+                    or self.moe_expert_offset + held > self.n_experts):
+                raise ValueError(
+                    f"mpi_tpu: an E layer needs n_experts (got "
+                    f"{self.n_experts}), a share moe_expert_offset="
+                    f"{self.moe_expert_offset} + moe_experts_held={held} "
+                    f"inside it and moe_shared_d_ff (got "
+                    f"{self.moe_shared_d_ff})")
+
+    @property
+    def experts_held(self) -> int:
+        return (self.n_experts if self.moe_experts_held is None
+                else self.moe_experts_held)
 
     @property
     def stream_dtype(self):
@@ -182,7 +257,8 @@ class TransformerConfig:
         refuse by name until it handles them."""
         classic = TransformerConfig()
         names = ["norm", "ffn", "tie_embeddings", "n_pred_heads",
-                 "residual_dtype"]
+                 "residual_dtype", "attn_head_dim", "position_table",
+                 "layer_pattern"]
         out = [f"{n}={getattr(self, n)!r}" for n in names
                if getattr(self, n) != getattr(classic, n)]
         if self.attention_impl == "eva":
@@ -191,6 +267,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim is not None:
+            return self.attn_head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -229,9 +307,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         params["head"] = _dense_init(
             jax.random.fold_in(keys[0], 1),
             (cfg.n_pred_heads * cfg.vocab, cfg.d_model), pd, cfg.d_model)
-    if not cfg.rope:  # rope needs no learned position table
+    if _has_pos_table(cfg):  # rope needs no learned position table
         params["pos"] = _dense_init(keys[1], (cfg.max_seq, cfg.d_model),
                                     pd, cfg.d_model)
+    if cfg.layer_pattern is not None:
+        params["blocks"] = [_init_pattern_block(keys[2 + i], kind, cfg)
+                            for i, kind in enumerate(cfg.layer_pattern)]
+        return params
     for i in range(cfg.n_layers):
         ks = jax.random.split(keys[2 + i], 6)
         h, d, f = cfg.n_heads, cfg.d_model, cfg.d_ff
@@ -264,6 +346,51 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                 jax.random.fold_in(keys[2 + i], 8), (h, hd)).astype(pd)
         params["blocks"].append(blk)
     return params
+
+
+def _has_pos_table(cfg: TransformerConfig) -> bool:
+    return cfg.position_table and not cfg.rope
+
+
+def _init_pattern_block(key, kind: str, cfg: TransformerConfig):
+    """One block of a ``layer_pattern`` stack: ``ln1`` and the leaves of
+    its one sub-layer."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    blk = {"ln1": _norm_init(cfg)}
+    if kind == "M":
+        from .mamba2 import init_mamba2_params
+
+        blk.update(init_mamba2_params(key, cfg))
+    elif kind == "E":
+        from .moe import init_routed_share_params
+
+        blk.update(init_routed_share_params(
+            key, d, cfg.d_ff, cfg.moe_shared_d_ff, cfg.n_experts,
+            cfg.experts_held, pd))
+    else:
+        ks = jax.random.split(key, 4)
+        h, hd, kv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+        blk.update(wq=_dense_init(ks[0], (d, h, hd), pd, d),
+                   wk=_dense_init(ks[1], (d, kv, hd), pd, d),
+                   wv=_dense_init(ks[2], (d, kv, hd), pd, d),
+                   wo=_dense_init(ks[3], (h, hd, d), pd, h * hd))
+    return blk
+
+
+def _pattern_block_specs(kind: str, norm) -> Dict[str, Any]:
+    """Replicated throughout: a ``layer_pattern`` stack refuses a mesh
+    that would split a layer (``_refuse_split_mesh``)."""
+    if kind == "M":
+        from .mamba2 import mamba2_specs
+
+        leaves = mamba2_specs()
+    elif kind == "E":
+        from .moe import routed_share_specs
+
+        leaves = routed_share_specs()
+    else:
+        leaves = {name: P() for name in ("wq", "wk", "wv", "wo")}
+    return dict(leaves, ln1=dict(norm))
 
 
 def _norm_init(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -305,11 +432,14 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     specs = {
         "embed": P("tp", None),
         "final_ln": dict(norm),
-        "blocks": [dict(blk) for _ in range(cfg.n_layers)],
+        "blocks": ([dict(blk) for _ in range(cfg.n_layers)]
+                   if cfg.layer_pattern is None else
+                   [_pattern_block_specs(kind, norm)
+                    for kind in cfg.layer_pattern]),
     }
     if not cfg.tie_embeddings:
         specs["head"] = P("tp", None)
-    if not cfg.rope:
+    if _has_pos_table(cfg):
         specs["pos"] = P()
     return specs
 
@@ -522,19 +652,64 @@ def _ffn(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh]):
         h = jax.nn.silu(h) * checkpoint_name(
             jnp.einsum("bsd,df->bsf", x, blk["w3"].astype(x.dtype)),
             "ffn_up")
+    elif cfg.ffn == "relu2":
+        h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h)
     y = jnp.einsum("bsf,fd->bsd", h, blk["w2"].astype(x.dtype))
     return y, jnp.zeros((), jnp.float32)
 
 
+def _refuse_split_mesh(cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """A ``layer_pattern`` stack runs each layer whole on every device:
+    its mixers, its share of the experts and their dispatch know no
+    ``tp``, ``ep`` or ``sp``. Refused by name, not run wrongly."""
+    if mesh is None:
+        return
+    split = [f"{a}={mesh.shape[a]}" for a in ("tp", "ep", "sp")
+             if mesh.shape.get(a, 1) > 1]
+    if split:
+        raise ValueError(
+            f"mpi_tpu: layer_pattern={cfg.layer_pattern!r} on a mesh with "
+            f"{', '.join(split)}: the Mamba-2 mixer, the routed share and "
+            f"its dispatch are not split over tp, ep or sp; use dp")
+
+
+def _pattern_block(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh],
+                   kind: str):
+    """Block of kind ``kind`` of a ``layer_pattern`` stack: ``x +
+    f(norm(x))`` with one sub-layer. The mixers (``M``, ``*``) stand under
+    the scope ``attn``, the experts (``E``) under ``ffn``: the mixer and
+    the feed-forward slot of the classic block, so that a trace's layer
+    scopes mean what they meant."""
+    with jax.named_scope("ffn" if kind == "E" else "attn"):
+        h = _norm(x, blk["ln1"], cfg)
+        if kind == "M":
+            from .mamba2 import mamba2_mixer
+
+            y = mamba2_mixer(h, blk, cfg)
+        elif kind == "E":
+            from .moe import routed_share_ffn
+
+            y = routed_share_ffn(
+                h, blk, cfg.n_experts, cfg.moe_top_k,
+                offset=cfg.moe_expert_offset, scale=cfg.moe_routed_scale)
+        else:
+            y = _attention(h, blk, cfg, mesh)
+        x = x + y.astype(x.dtype)
+    return _act_constraint(x, mesh), jnp.zeros((), jnp.float32)
+
+
 def block_body(x, blk, cfg: TransformerConfig,
-               mesh: Optional[Mesh] = None):
+               mesh: Optional[Mesh] = None, kind: Optional[str] = None):
     """ONE transformer block (pre-norm attention + FFN residuals) —
     the single definition shared by the sequential stack
     (:func:`forward_with_aux`) and the pipelined stages
     (:mod:`mpi_tpu.models.pipeline_lm`), so the two paths cannot
-    drift. Returns ``(x, aux_loss)``."""
+    drift. Returns ``(x, aux_loss)``. ``kind`` is the block's letter in
+    ``cfg.layer_pattern`` (None = the classic block)."""
+    if kind is not None:
+        return _pattern_block(x, blk, cfg, mesh, kind)
     # The named scopes here and in forward_with_aux / token_xent / the
     # train step are what a profiler trace's device ops are grouped by
     # (docs/OBSERVABILITY.md): metadata only, the program is unchanged.
@@ -579,15 +754,18 @@ def block_body(x, blk, cfg: TransformerConfig,
 _REMAT_KEEPS = ("attn_out", "attn_lse", "ffn_gate")
 
 
-def checkpointed_block(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
+def checkpointed_block(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+                       kind: Optional[str] = None):
     """``block(x, blk) -> (x, aux_loss)``: :func:`block_body` for ``cfg``
-    on ``mesh``, and the one place it is wrapped for ``cfg.remat``. The
+    on ``mesh`` (of ``kind``, in a ``layer_pattern`` stack), and the one
+    place it is wrapped for ``cfg.remat``. The
     wrapped block's backward recomputes its forward except the named
     values of ``_REMAT_KEEPS``, which it holds. With tracing on
     (docs/OBSERVABILITY.md) each wrapped call adds 1 to ``remat.blocks``
     and the bytes its backward would hold to ``remat.kept_bytes``: at
     trace time, from the shapes."""
-    block = functools.partial(block_body, cfg=cfg, mesh=mesh)
+    block = functools.partial(block_body, cfg=cfg, mesh=mesh,
+                              **({} if kind is None else {"kind": kind}))
     if not cfg.remat:
         return block
     kept = jax.checkpoint(
@@ -648,6 +826,16 @@ def pred_heads_xent(logits: jax.Array, tokens: jax.Array) -> jax.Array:
         return jnp.mean(per_head)
 
 
+def _embed(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """tokens (batch, seq) -> the residual stream entering block 0."""
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.stream_dtype)[tokens]
+        if _has_pos_table(cfg):
+            x = x + params["pos"].astype(cfg.stream_dtype)[
+                :tokens.shape[1]][None]
+    return _act_constraint(x, mesh)
+
+
 def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
                      cfg: TransformerConfig,
                      mesh: Optional[Mesh] = None
@@ -657,15 +845,18 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
     With ``cfg.n_pred_heads`` = P > 1 the logits are (batch, seq, P,
     vocab); those of an untied head are float32."""
     b, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.stream_dtype)[tokens]
-        if not cfg.rope:
-            x = x + params["pos"].astype(cfg.stream_dtype)[:s][None]
-    x = _act_constraint(x, mesh)
+    x = _embed(params, tokens, cfg, mesh)
     aux = jnp.zeros((), jnp.float32)
 
-    block = checkpointed_block(cfg, mesh)
-    for blk in params["blocks"]:
+    if cfg.layer_pattern is None:
+        block = checkpointed_block(cfg, mesh)
+        blocks = [block] * len(params["blocks"])
+    else:
+        _refuse_split_mesh(cfg, mesh)
+        by_kind = {kind: checkpointed_block(cfg, mesh, kind)
+                   for kind in sorted(set(cfg.layer_pattern))}
+        blocks = [by_kind[kind] for kind in cfg.layer_pattern]
+    for block, blk in zip(blocks, params["blocks"]):
         x, blk_aux = block(x, blk)
         aux = aux + blk_aux
     with jax.named_scope("logits_loss"):
@@ -686,6 +877,32 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
             cfg: TransformerConfig, mesh: Optional[Mesh] = None) -> jax.Array:
     """tokens (batch, seq) int32 → logits (batch, seq, vocab)."""
     return forward_with_aux(params, tokens, cfg, mesh)[0]
+
+
+def routed_choices(params: Dict[str, Any], tokens: jax.Array,
+                   cfg: TransformerConfig, mesh: Optional[Mesh] = None
+                   ) -> List[Tuple[jax.Array, jax.Array]]:
+    """What the routed layers of a ``layer_pattern`` stack decide for
+    ``tokens`` (batch, seq): for every ``E`` block in order, the router's
+    input ``(batch * seq, d_model)`` in the compute dtype and the experts
+    it chose ``(batch * seq, moe_top_k)`` among all ``cfg.n_experts``.
+    The forward pass of :func:`forward_with_aux`, block by block; for load
+    statistics, and for a reference that is to follow the program's
+    routing."""
+    from .moe import route_top_k
+
+    if cfg.layer_pattern is None:
+        raise ValueError("mpi_tpu: routed_choices needs a layer_pattern")
+    _refuse_split_mesh(cfg, mesh)
+    x = _embed(params, tokens, cfg, mesh)
+    choices = []
+    for blk, kind in zip(params["blocks"], cfg.layer_pattern):
+        if kind == "E":
+            h = _norm(x, blk["ln1"], cfg).reshape(-1, cfg.d_model)
+            choices.append((h, route_top_k(h, blk["router"],
+                                           cfg.moe_top_k)[0]))
+        x, _ = block_body(x, blk, cfg, mesh, kind)
+    return choices
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig,

@@ -255,6 +255,49 @@ def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
         f"what else the process holds")
 
 
+# What the compiler counts for the nemotron-3-nano cell's step: 12.010 GiB
+# at batch 2 (PR 36).
+NEMOTRON_STEP_GIB = 15.25
+
+
+def test_nemotron_cell_train_step_fits_with_room_to_spare(
+        topo, compiled_kernels):
+    """The whole train step of the benchmark's
+    ``nemotron-3-nano-L9-E8.pretrain-8k`` cell (its ``model`` as the
+    configuration file has it: the pattern ``MEMEM*EME`` at published
+    widths, ``remat`` on; its traffic's batch of sequences of 8,192 and
+    their targets): it compiles for one chip, with the flash kernels of
+    the attention layer and the scopes a trace splits it by, and the
+    compiler's count
+    of its memory leaves at least 0.5 GiB of the chip's 15.75."""
+    from mpi_tpu.models import TransformerConfig, make_mesh_nd
+
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark")
+    with open(os.path.join(bench, "configs",
+                           "nemotron-3-nano-L9-E8.json")) as f:
+        model = json.load(f)["model"]
+    with open(os.path.join(bench, "traffic", "pretrain-8k.json")) as f:
+        traffic = json.load(f)
+    batch, seq = traffic["batch"], traffic["seq"]
+    cfg = TransformerConfig(**dict(model, dtype=jnp.dtype(model["dtype"]),
+                                   max_seq=seq + 1))
+    assert cfg.remat and cfg.layer_pattern == "MEMEM*EME"
+    mesh = make_mesh_nd(1, devices=topo.devices[:1])
+    compiled = _compile(
+        *_step_args(cfg, mesh, batch, seq),
+        names=FLASH_KERNELS + LAYER_SCOPES + (
+            "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.norm",
+            "ssm.out_proj", "moe.route", "moe.routed", "moe.shared"))
+    mem = compiled.memory_analysis()
+    gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+           + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    assert NEMOTRON_STEP_GIB <= CHIP_GIB - 0.5
+    assert gib < NEMOTRON_STEP_GIB, (
+        f"the compiler counts {gib:.3f} GiB for the step at batch {batch}, "
+        f"over the {NEMOTRON_STEP_GIB} GiB that leave 0.5 of the chip's "
+        f"{CHIP_GIB}")
+
+
 @pytest.fixture(scope="module")
 def ring_mesh(topo):
     return Mesh(np.asarray(topo.devices), ("rank",))
