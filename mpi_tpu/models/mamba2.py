@@ -9,7 +9,8 @@ For the normed stream ``h`` ``(b, s, d)``, with ``H`` heads of ``P``
     xBC = silu(conv1d_causal_depthwise(xBC, k) + b)
     [x | B | C] = xBC                         (H, P), (G, N), (G, N)
     dt = softplus(dt + dt_bias);  A = -exp(A_log)
-    y  = scan(x, dt, A, B, C) + D x           mpi_tpu.ops.ssd, chunked
+    y  = scan(x, dt, A, B, C) + D x           mpi_tpu.ops.ssd, chunked:
+                                              two Pallas kernels on a TPU
     y  = GroupRMSNorm(y * silu(z)) * w        G groups of d_inner / G, gated
     out = y W_out                             d_inner -> d
 
@@ -18,19 +19,20 @@ are SSMs", arXiv:2405.21060). ``models/ssm.py``'s diagonal LRU is another
 model with its own configuration and train step, and is not this.
 
 Every leaf is replicated: the mixer runs whole on each device (a mesh with
-``tp`` or ``sp`` > 1 is refused where the block stack is built).
+``tp`` or ``sp`` > 1 is refused where the block stack is built), on its
+``dp`` share of the batch.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.ssd import ssd_scan
+from ..ops.ssd import ssd_scan_flat
 from ..utils import trace
 
 __all__ = ["init_mamba2_params", "mamba2_specs", "mamba2_mixer"]
@@ -87,14 +89,33 @@ def _causal_conv(u, w, bias):
                for j in range(k)) + bias.astype(_F32)
 
 
-def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg) -> jax.Array:
+def _scan_per_shard(mesh: Optional[Mesh], x, dt, A, B, C, D, chunk, groups):
+    """``ssd_scan_flat``, each device on its own rows of the batch. GSPMD
+    cannot partition a Mosaic kernel (it would gather the batch and run
+    the whole of it on every chip), so on a real mesh the scan runs per
+    shard over ``dp`` as the flash kernels do (``_kernel_per_shard``,
+    models/transformer.py); one chip and ``mesh=None`` call it directly."""
+    def scan(*inputs):
+        return ssd_scan_flat(*inputs, chunk, groups)
+
+    if mesh is None or mesh.size == 1:
+        return scan(x, dt, A, B, C, D)
+    rows, whole = P("dp" if "dp" in mesh.axis_names else None), P()
+    return jax.shard_map(
+        scan, mesh=mesh, in_specs=(rows, rows, whole, rows, rows, whole),
+        out_specs=rows, check_vma=False)(x, dt, A, B, C, D)
+
+
+def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg,
+                 mesh: Optional[Mesh] = None) -> jax.Array:
     """The equations above for ``h`` ``(b, s, d)`` in the compute dtype;
-    returns ``(b, s, d)``. Scopes ``ssm`` > ``ssm.in_proj`` / ``.conv`` /
-    ``.scan`` / ``.norm`` / ``.out_proj`` are what a trace splits the
-    mixer by; with tracing on each call adds 1 to ``ssm.layers`` and the
-    chunks of a sequence to ``ssm.chunks`` (at trace time)."""
+    returns ``(b, s, d)``. On a ``mesh`` of more than one device the scan
+    runs per shard of the batch. Scopes ``ssm`` > ``ssm.in_proj`` /
+    ``.conv`` / ``.scan`` / ``.norm`` / ``.out_proj`` are what a trace
+    splits the mixer by; with tracing on each call adds 1 to
+    ``ssm.layers`` and the chunks of a sequence to ``ssm.chunks`` (at
+    trace time)."""
     b, s, _ = h.shape
-    heads, hd = cfg.ssm_heads, cfg.ssm_head_dim
     groups, n = cfg.ssm_groups, cfg.ssm_state
     d_inner, conv_dim = _dims(cfg)
     if s % cfg.ssm_chunk:
@@ -115,17 +136,18 @@ def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg) -> jax.Array:
             xbc = jax.nn.silu(_causal_conv(
                 xbc, blk["conv_w"], blk["conv_b"])).astype(h.dtype)
         with jax.named_scope("ssm.scan"):
-            x = xbc[..., :d_inner].reshape(b, s, heads, hd)
-            B = xbc[..., d_inner:d_inner + groups * n].reshape(
-                b, s, groups, n)
-            C = xbc[..., d_inner + groups * n:].reshape(b, s, groups, n)
+            # Heads and groups stay side by side, as the scan's kernels
+            # read them: (b, s, h, p) would be another layout on the chip.
             step = jax.nn.softplus(dt.astype(_F32)
                                    + blk["dt_bias"].astype(_F32))
-            y = ssd_scan(x, step, -jnp.exp(blk["A_log"].astype(_F32)), B, C,
-                         blk["D"], cfg.ssm_chunk)
+            y = _scan_per_shard(
+                mesh, xbc[..., :d_inner], step,
+                -jnp.exp(blk["A_log"].astype(_F32)),
+                xbc[..., d_inner:d_inner + groups * n],
+                xbc[..., d_inner + groups * n:], blk["D"], cfg.ssm_chunk,
+                groups)
         with jax.named_scope("ssm.norm"):
-            gated = (y.reshape(b, s, d_inner).astype(_F32)
-                     * jax.nn.silu(z.astype(_F32)))
+            gated = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
             by_group = gated.reshape(b, s, groups, d_inner // groups)
             by_group = by_group * jax.lax.rsqrt(
                 jnp.mean(by_group * by_group, axis=-1, keepdims=True)

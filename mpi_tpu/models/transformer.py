@@ -687,7 +687,7 @@ def _pattern_block(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh],
         if kind == "M":
             from .mamba2 import mamba2_mixer
 
-            y = mamba2_mixer(h, blk, cfg)
+            y = mamba2_mixer(h, blk, cfg, mesh)
         elif kind == "E":
             from .moe import routed_share_ffn
 
@@ -751,6 +751,23 @@ def block_body(x, blk, cfg: TransformerConfig,
 # second run a layer away for 12 MiB more at the compiler's peak; the gate
 # pre-activation ``x @ w1`` (``ffn_gate``) one of the three recomputed FFN
 # matmuls for 344 MiB a layer.
+#
+# The Mamba-2 scan's forward rule (``ops/ssd.py``, PR 37) has two outputs a
+# block could hold: ``y`` and the float32 states entering the chunks, which
+# its backward kernel reads. Tried under the names ``ssm_y`` and
+# ``ssm_states`` in the benchmark's nemotron cell (four mixers at b 2 x
+# 8,192, 64 heads of 64, state 128; PERF.md, PR 37): bytes of the scan held
+# a token a mixer; ms a step and tokens/s, one run each; GiB the v5e's
+# compiler counts for the step:
+#
+#   out lse gate                      0    483.7  33,869  10.561          <-
+#   out lse gate y states        24,576    488.1  33,567  11.849
+#   out lse gate y (or states)   not run: the forward kernel writes both,
+#                                so it runs twice a mixer as with neither
+#
+# Held together they spare the forward kernel's second run (2.6 ms a
+# mixer) and the step is slower all the same, for 1.29 GiB more: the rule
+# keeps neither, and the scan's rule names nothing until a shape turns that.
 _REMAT_KEEPS = ("attn_out", "attn_lse", "ffn_gate")
 
 

@@ -519,6 +519,7 @@ class TestJobTermination:
             "addr = sys.argv[sys.argv.index('--mpi-addr') + 1]\n"
             "port = int(addr.rsplit(':', 1)[1])\n"
             "if port == base:\n"
+            "    time.sleep(1.5)   # let the survivor reach its SIG_IGN\n"
             "    sys.exit(3)\n"
             "signal.signal(signal.SIGTERM, signal.SIG_IGN)\n"
             "time.sleep(60)\n")

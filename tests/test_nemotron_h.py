@@ -233,6 +233,25 @@ def test_one_train_step_moves_every_leaf_by_the_references_gradient(
     assert max(_leaf_errors(used, want_grad).values()) < 2e-3
 
 
+def test_a_dp_mesh_runs_the_scan_per_shard_and_changes_no_value(drawn):
+    """The scan's kernels are Mosaic calls that GSPMD cannot partition: on
+    a dp mesh the mixer hands the scan each device's rows of the batch
+    under ``shard_map`` (the compile for four chips is held in
+    test_tpu_compile.py). Loss and every gradient, the replicated ``A_log``
+    and ``D`` with their sum over the shards, equal the unsharded ones."""
+    cfg, params, _ = drawn
+    tokens = jax.random.randint(jax.random.PRNGKey(38), (4, SEQ + 1), 0,
+                                cfg.vocab)
+    mesh = make_mesh_nd(2, axes=("dp", "tp"), devices=jax.devices()[:2])
+    sharded = jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg, mesh))
+    jaxpr = str(jax.make_jaxpr(sharded)(params, tokens))
+    assert jaxpr.count("shard_map") >= cfg.layer_pattern.count("M")
+    got, want = jax.jit(sharded)(params, tokens), jax.value_and_grad(
+        loss_fn)(params, tokens, cfg)
+    assert abs(float(got[0]) - float(want[0])) < 1e-5
+    assert max(_leaf_errors(got[1], want[1]).values()) < 2e-5
+
+
 def test_a_mesh_that_would_split_a_layer_is_refused_by_name(drawn):
     cfg, params, tokens = drawn
     mesh = make_mesh_nd(2, axes=("dp", "tp"), devices=jax.devices()[:2])
@@ -300,7 +319,7 @@ def test_counters_hold_their_counts(drawn, traced):
     def counted():
         jax.make_jaxpr(lambda p, t: loss_fn(p, t, cfg))(params, tokens)
         return {k: v for k, v in traced.counters().items()
-                if k.startswith(("ssm.", "moe."))}
+                if k.startswith(("ssm.", "ssd.", "moe."))}
 
     traced.disable()
     assert counted() == {}
@@ -309,7 +328,9 @@ def test_counters_hold_their_counts(drawn, traced):
     rows = 512 * floor_tiles(tokens_a_step, 3, 4, 16)
     assert rows == 512
     assert counted() == {
-        "ssm.layers": 4, "ssm.chunks": 4 * (SEQ // 8), "moe.layers": 4,
+        "ssm.layers": 4, "ssm.chunks": 4 * (SEQ // 8),
+        "ssd.scans.program": 4,         # a state of 16 fills no register
+        "moe.layers": 4,
         "moe.experts_held": 4 * 4, "moe.rows": 4 * rows}
 
 

@@ -18,6 +18,7 @@ the test's own process, and all such tests live in this ONE file so a
 single worker owns the library.
 """
 
+import contextlib
 import json
 import os
 import re
@@ -255,8 +256,88 @@ def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
         f"what else the process holds")
 
 
-# What the compiler counts for the nemotron-3-nano cell's step: 12.010 GiB
-# at batch 2 (PR 36).
+SSD_KERNELS = ("%ssd_fwd", "%ssd_bwd")
+
+
+@contextlib.contextmanager
+def _scans_counted():
+    """Tracing on; yields a dict that holds, on leaving, what the
+    ``ssd.scans.*`` counters rose by inside."""
+    from mpi_tpu.utils import trace
+
+    was, before, rose = trace.enabled(), dict(trace.counters()), {}
+    trace.enable()
+    try:
+        yield rose
+        rose.update({k: v - before.get(k, 0)
+                     for k, v in trace.counters().items()
+                     if k.startswith("ssd.scans.") and v != before.get(k, 0)})
+    finally:
+        if not was:
+            trace.disable()
+
+
+def test_ssd_kernels_compile_at_the_nemotron_cell_shapes(one_chip):
+    """The Mamba-2 scan, forward and backward, at the shapes the benchmark's
+    ``nemotron-3-nano-L9-E8.pretrain-8k`` cell calls it with (batch 2 x
+    8,192, 64 heads of 64 in 8 groups, a state of 128, chunks of 128,
+    bfloat16): ``ssd_scan`` hands a TPU lowering its two kernels without
+    being told to (the backend here is the CPU), every input has its
+    gradient, and the call is counted by what its shapes decide."""
+    from mpi_tpu.ops.ssd import ssd_scan
+
+    b, s, h, p, g, n, chunk = 2, 8192, 64, 64, 8, 128, 128
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*inputs):
+        return jnp.sum(ssd_scan(*inputs, chunk).astype(jnp.float32))
+
+    with _scans_counted() as took:
+        compiled = _compile(
+            jax.grad(loss, argnums=tuple(range(6))),
+            shaped((b, s, h, p), jnp.bfloat16), shaped((b, s, h), jnp.float32),
+            shaped((h,), jnp.float32), shaped((b, s, g, n), jnp.bfloat16),
+            shaped((b, s, g, n), jnp.bfloat16), shaped((h,), jnp.float32),
+            names=SSD_KERNELS)
+    assert took == {"ssd.scans.kernel": 1}
+    text = compiled.as_text()
+    assert " while(" not in text  # the program's recurrence over chunks
+
+
+def test_ssd_kernels_run_on_each_chips_rows_of_a_dp_batch(topo):
+    """dp 4 on the described 2x2: GSPMD cannot partition a Mosaic kernel,
+    and left to it every chip would gather ``x``, ``B``, ``C`` and ``y``'s
+    gradient and scan the whole batch. The mixer runs the scan per shard,
+    so the step's kernels take one chip's rows (batch 4 / dp 4 = 1) and
+    nothing is gathered for them."""
+    from mpi_tpu.models import TransformerConfig, make_mesh_nd
+
+    batch, seq, heads, hd, groups, n = 4, 256, 4, 64, 2, 128
+    cfg = TransformerConfig(
+        vocab=256, d_model=128, n_heads=2, n_layers=2, d_ff=256,
+        layer_pattern="MM", max_seq=seq + 1, dtype=jnp.bfloat16,
+        position_table=False, ssm_heads=heads, ssm_head_dim=hd,
+        ssm_groups=groups, ssm_state=n, ssm_conv=4, ssm_chunk=128, remat=True)
+    mesh = make_mesh_nd(4, axes=("dp",), devices=topo.devices)
+    assert dict(mesh.shape) == {"dp": 4}
+    text = _compile(*_step_args(cfg, mesh, batch, seq),
+                    names=SSD_KERNELS + ("ssm.scan",)).as_text()
+    assert "all-gather" not in text
+    assert "all-reduce" in text                 # the dp gradients
+    local = f"bf16[{batch // 4},{seq},{heads * hd}]"
+    for kernel in ("ssd_fwd", "ssd_bwd"):
+        calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*", text)
+        assert calls and all("ssm.scan" in call for call in calls), kernel
+        assert all(local in call and f"bf16[{batch}," not in call
+                   for call in calls), calls[0][:400]
+
+
+# What the compiler counts for the nemotron-3-nano cell's step: 10.561 GiB
+# at batch 2 with the scan as two kernels (PR 37; 12.010 with the scan as
+# an XLA program, PR 36; 11.849 with the kernels' output and states held
+# under ``remat``).
 NEMOTRON_STEP_GIB = 15.25
 
 
@@ -283,11 +364,25 @@ def test_nemotron_cell_train_step_fits_with_room_to_spare(
                                    max_seq=seq + 1))
     assert cfg.remat and cfg.layer_pattern == "MEMEM*EME"
     mesh = make_mesh_nd(1, devices=topo.devices[:1])
-    compiled = _compile(
-        *_step_args(cfg, mesh, batch, seq),
-        names=FLASH_KERNELS + LAYER_SCOPES + (
-            "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.norm",
-            "ssm.out_proj", "moe.route", "moe.routed", "moe.shared"))
+    with _scans_counted() as took:
+        compiled = _compile(
+            *_step_args(cfg, mesh, batch, seq),
+            names=FLASH_KERNELS + SSD_KERNELS + LAYER_SCOPES + (
+                "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.norm",
+                "ssm.out_proj", "moe.route", "moe.routed", "moe.shared"))
+    text = compiled.as_text()
+    mixers = cfg.layer_pattern.count("M")
+    # Counted as ``ssd_scan_flat`` is traced, and ``jax.checkpoint`` traces
+    # a kind's block once for all its layers of one shape (``ssm.layers``
+    # reads 1 here too; without ``remat`` both read the four mixers).
+    assert took == {"ssd.scans.kernel": 1}
+    # ``_REMAT_KEEPS`` holds nothing of the scan (the table above it), so
+    # the backward runs the forward kernel again for the states.
+    for kernel, a_mixer in (("ssd_fwd", 2), ("ssd_bwd", 1)):
+        calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*", text)
+        assert len(calls) == a_mixer * mixers, \
+            f"{len(calls)} %{kernel} calls for {mixers} mixers"
+        assert all("ssm.scan" in call for call in calls), kernel
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30

@@ -81,7 +81,8 @@ def _bf16_scores(x2, router):
 
 
 def _bf16_state_scan():
-    """``ssd_scan`` with the state carried in bfloat16 from chunk to chunk."""
+    """``ops/ssd.py`` with the state carried in bfloat16 from chunk to
+    chunk."""
     src = Path(ssd.__file__).read_text()
     carry = "        return whole_c[..., None, None] * state + own_c, state"
     start = "jnp.zeros((b, g, r, p, n), _F32)"
@@ -91,8 +92,9 @@ def _bf16_state_scan():
         "        return nxt.astype(jnp.bfloat16), state")).replace(
             start, start.replace("_F32", "jnp.bfloat16"))
     module = types.ModuleType("ssd_bf16_state")
+    module.__package__ = ssd.__package__    # the module's relative imports
     exec(compile(src, "ssd_bf16_state", "exec"), module.__dict__)
-    return module.ssd_scan
+    return module
 
 
 def _no_shared(x, params, *args, **kw):
@@ -114,8 +116,8 @@ def test_each_control_fails_the_number_meant_for_it(cell, monkeypatch,
         monkeypatch.setattr(moe, "_router_scores", _bf16_scores)
     elif control == "scan":
         narrow = _bf16_state_scan()
-        monkeypatch.setattr(ssd, "ssd_scan", narrow)
-        monkeypatch.setattr(mamba2, "ssd_scan", narrow)
+        monkeypatch.setattr(ssd, "ssd_scan", narrow.ssd_scan)
+        monkeypatch.setattr(mamba2, "ssd_scan_flat", narrow.ssd_scan_flat)
     else:
         monkeypatch.setattr(moe, "routed_share_ffn", _no_shared)
     ok, numbers, said = compare()
