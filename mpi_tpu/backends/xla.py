@@ -266,6 +266,7 @@ class _MeshCollectives:
         self._rank_of = rank_of
         self._coll = _CollectiveSession(self._n)
         self._jit_cache: Dict[Tuple, Any] = {}
+        trace.listen_compiles()     # a collective of a new shape compiles
         self._fillers: "OrderedDict[Tuple, Any]" = OrderedDict()
 
     def _myrank(self) -> int:

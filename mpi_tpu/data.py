@@ -177,6 +177,7 @@ class ShardedLoader:
         self.start_step = start_step
         self.prefetch = max(0, prefetch)
         self._sharding = None
+        trace.listen_compiles()     # the placement may compile
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 

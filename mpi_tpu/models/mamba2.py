@@ -113,8 +113,7 @@ def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg,
     runs per shard of the batch. Scopes ``ssm`` > ``ssm.in_proj`` /
     ``.conv`` / ``.scan`` / ``.norm`` / ``.out_proj`` are what a trace
     splits the mixer by; with tracing on each call adds 1 to
-    ``ssm.layers`` and the chunks of a sequence to ``ssm.chunks`` (at
-    trace time)."""
+    ``ssm.layers`` (at trace time)."""
     b, s, _ = h.shape
     groups, n = cfg.ssm_groups, cfg.ssm_state
     d_inner, conv_dim = _dims(cfg)
@@ -122,9 +121,7 @@ def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg,
         raise ValueError(
             f"mpi_tpu: the Mamba-2 scan runs in chunks of {cfg.ssm_chunk}: "
             f"seq {s} is not a multiple")
-    if trace.enabled():
-        trace.count("ssm.layers")
-        trace.count("ssm.chunks", s // cfg.ssm_chunk)
+    trace.count("ssm.layers")
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.in_proj"):
             zxbcdt = jnp.einsum("bsd,de->bse", h,

@@ -326,8 +326,7 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     tiles (:func:`_expert_tiles`; at least :func:`floor_tiles` of them).
     Scopes ``moe.route``, ``moe.routed`` (sort, dispatch, expert products,
     combine) and ``moe.shared``; with tracing on each call adds 1 to
-    ``moe.layers``, ``held`` to ``moe.experts_held`` and the rows of the
-    tiles the loop always runs to ``moe.rows`` (at trace time)."""
+    ``moe.layers`` (at trace time)."""
     b, s, d = x.shape
     tokens, held = b * s, params["w_up"].shape[0]
     if not 1 <= top_k <= n_experts:
@@ -339,10 +338,7 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
             f"mpi_tpu: experts {offset}..{offset + held - 1} are not among "
             f"the layer's {n_experts}")
     floor = floor_tiles(tokens, top_k, held, n_experts)
-    if trace.enabled():
-        trace.count("moe.layers")
-        trace.count("moe.experts_held", held)
-        trace.count("moe.rows", floor * _TILE)
+    trace.count("moe.layers")
     x2 = x.reshape(tokens, d)
 
     with jax.named_scope("moe.route"):

@@ -1035,6 +1035,7 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     combining both flags is an error."""
     import optax
 
+    trace.listen_compiles()
     if grad_accum < 1:
         raise ValueError(f"mpi_tpu: grad_accum must be >= 1, got "
                          f"{grad_accum}")

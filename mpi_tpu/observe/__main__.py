@@ -39,6 +39,8 @@ def _render_metrics(doc: Dict[str, Any], path: str) -> None:
         rec = doc["peers"][peer]
         print(f"  peer {peer}: tx {rec['tx_bytes_per_s'] / 1e6:.2f} MB/s"
               f"  rx {rec['rx_bytes_per_s'] / 1e6:.2f} MB/s")
+    for line in metrics.compile_lines(doc):
+        print(line)
     for row in doc.get("stragglers", []):
         print(f"  straggler: {row['collective']} skew "
               f"{row['max_skew_us']:.1f}µs slowest rank "

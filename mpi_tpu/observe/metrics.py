@@ -1,6 +1,6 @@
 """Live metrics + straggler detection.
 
-Three data sources, one renderer:
+Four data sources, one renderer:
 
   * **flight recorder** (:mod:`.flight`) — per-op duration samples →
     op p50/p99 and counts;
@@ -11,7 +11,10 @@ Three data sources, one renderer:
     drivers (xla/hybrid rank threads share one clock) additionally
     report exact per-collective arrival skew (``note_session_skew``),
     and the trace-collection merge (:mod:`.collect`) computes
-    cross-process skew from clock-aligned entries.
+    cross-process skew from clock-aligned entries;
+  * **the compile table** (``trace.compile_table()``) — which jitted
+    function was traced, lowered and compiled how often, for how long,
+    and how often the persistent cache served it.
 
 ``summary_text()`` renders the ``mpi_tpu observe top``-style report —
 printed on SIGUSR1 (installed at init) or at finalize; ``write()``
@@ -38,6 +41,8 @@ __all__ = ["note_collective_entry", "note_session_skew",
 
 SCHEMA_VERSION = 1
 
+_COMPILE_FIELDS = ("traces", "lowerings", "compiles", "cache_hits",
+                   "trace_s", "lower_s", "compile_s")
 _ENTRIES_CAP = 16384
 _SKEWS_CAP = 4096
 
@@ -143,6 +148,8 @@ def snapshot(rank: Optional[int] = None,
         "peers": peers,
         "counters": trace.counters(),
         "trace_dropped_events": trace.dropped(),
+        "compiles": trace.compile_table(),
+        "compiles_dropped": trace.compiles_dropped(),
         "stragglers": _worst_session_skews(),
         "collective_entries": len(collective_entries()),
     }
@@ -164,6 +171,29 @@ def validate(doc: Dict[str, Any]) -> None:
         for f in ("count", "p50_us", "p99_us"):
             if f not in st:
                 raise ValueError(f"metrics op {op!r} missing {f!r}")
+    # An artifact written before the section existed has none.
+    compiles = doc.get("compiles", {})
+    if not isinstance(compiles, dict):
+        raise ValueError("metrics artifact field 'compiles' malformed")
+    for fun, row in compiles.items():
+        missing = [f for f in _COMPILE_FIELDS
+                   if not isinstance(row, dict) or f not in row]
+        if missing:
+            raise ValueError(f"metrics compiles {fun!r} missing {missing}")
+
+
+def compile_lines(doc: Dict[str, Any], k: int = 8) -> List[str]:
+    """The ``compiles`` section as ``observe top`` prints it, the ``k``
+    functions that cost most first: which program the set-up went to,
+    and which one compiled again (``x2``)."""
+    def seconds(row):
+        return row["trace_s"] + row["lower_s"] + row["compile_s"]
+
+    rows = sorted(doc.get("compiles", {}).items(),
+                  key=lambda kv: -seconds(kv[1]))
+    return [f"  compiles: {fun} x{row['compiles']} {seconds(row):.1f} s "
+            f"({row['cache_hits']} from cache; traced x{row['traces']}, "
+            f"lowered x{row['lowerings']})" for fun, row in rows[:k]]
 
 
 def write(path: str, rank: Optional[int] = None,
@@ -211,6 +241,7 @@ def summary_text(rank: Optional[int] = None,
                 f"{rec['rx_bytes_per_s'] / 1e6:>10.2f} "
                 f"{rec['tx_bytes'] / 1e6:>10.2f} "
                 f"{rec['rx_bytes'] / 1e6:>10.2f}")
+    lines += compile_lines(doc)
     for row in doc["stragglers"]:
         lines.append(
             f"  straggler: {row['collective']:<12} max skew "

@@ -19,7 +19,13 @@ bounce example's manual ``time.Now()`` deltas (SURVEY.md §5; bounce.go:
     no flag; it exists once jax is imported (this module never imports
     jax — the socket drivers run without it);
   * **counters** — monotonically accumulated values (bytes sent/received
-    per peer, collective invocations), queryable for bench harnesses.
+    per peer, collective invocations), queryable for bench harnesses;
+  * **compiles** — once :func:`listen_compiles` has run (the entry
+    points that build jitted programs call it), every trace, lowering and
+    backend compile of a jitted function is a span ``jax.trace`` /
+    ``jax.lower`` / ``jax.compile`` with ``fun`` and ``nth``, and a row
+    of a table kept whether or not recording is on: :func:`compiles`,
+    :func:`compile_table`.
 
 With no profiler session and recording off a span allocates no event and
 costs well under a microsecond. :func:`add_span` records a span that has
@@ -54,6 +60,10 @@ __all__ = [
     "set_stream",
     "stream",
     "flush_stream",
+    "listen_compiles",
+    "compiles",
+    "compile_table",
+    "compiles_dropped",
 ]
 
 _MAX_EVENTS = 100_000
@@ -262,7 +272,225 @@ def wall_anchor_ns() -> int:
     return _tracer.wall_anchor_ns
 
 
+# -- compiles ---------------------------------------------------------------
+#
+# jax reports each stage of a jitted program through ``jax.monitoring``:
+# a scalar event as the stage begins and a duration event as it ends, both
+# on the compiling thread and both with the function's name; inside a
+# backend compile, whether the request went to the persistent cache and
+# whether the cache served it (jax/_src/dispatch.py, compiler.py).
+
+_MAX_COMPILES = 4096
+
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_COUNT_OF = {"trace": "traces", "lower": "lowerings", "compile": "compiles"}
+_CACHE_SAID = {     # asked is a miss until the cache says otherwise
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+
+
+def _bare(fun: str) -> str:
+    """``jit(step)`` -> ``step``: jax names a function bare as it traces
+    it and wrapped as it lowers and compiles it; the table has one row."""
+    if fun.endswith(")") and "(" in fun:
+        return fun[fun.index("(") + 1:-1]
+    return fun
+
+
+class _Stage:
+    """One open stage of one thread, from its begin event to its end. A
+    trace nested in another stage has no ``span``: it is counted only."""
+
+    __slots__ = ("stage", "row", "fun", "nth", "span", "t0", "cache",
+                 "saved_s")
+
+    def __init__(self, stage: str, row: Dict[str, Any], fun: str = "",
+                 nth: int = 0, span: Any = None):
+        self.stage, self.row, self.fun, self.nth = stage, row, fun, nth
+        self.span = span
+        self.cache = "off" if stage == "compile" else None
+        self.saved_s: Optional[float] = None
+        self.t0 = 0
+
+
+def _new_row() -> Dict[str, Any]:
+    return {"traces": 0, "nested_traces": 0, "lowerings": 0, "compiles": 0,
+            "cache_hits": 0, "trace_s": 0.0, "lower_s": 0.0,
+            "compile_s": 0.0, "saved_s": 0.0}
+
+
+class _Compiles:
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.listening = False
+        self.records: List[Dict[str, Any]] = []
+        self.dropped = 0
+        self.by_fun: Dict[str, Dict[str, Any]] = {}
+        self.open = threading.local()   # .stack: this thread's open stages
+
+    def stack(self) -> List[_Stage]:
+        try:
+            return self.open.stack
+        except AttributeError:
+            self.open.stack = []
+            return self.open.stack
+
+    def begin(self, stage: str, fun: str) -> None:
+        stack = self.stack()
+        if stage == "trace" and stack:
+            # A jitted function called while another is traced, or traced
+            # by a lowering rule (thousands a model): counted on the
+            # function whose stage is open, not listed.
+            row = stack[-1].row
+            with self.lock:
+                row["nested_traces"] += 1
+            stack.append(_Stage(stage, row))
+            return
+        with self.lock:
+            row = self.by_fun.get(_bare(fun))
+            if row is None:
+                row = self.by_fun[_bare(fun)] = _new_row()
+            row[_COUNT_OF[stage]] += 1
+            nth = row[_COUNT_OF[stage]]
+        st = _Stage(stage, row, fun, nth,
+                    span("jax." + stage, fun=fun, nth=nth))
+        stack.append(st)
+        st.span.__enter__()
+        st.t0 = time.perf_counter_ns()
+
+    def end(self, stage: str) -> None:
+        t1 = time.perf_counter_ns()
+        stack = self.stack()
+        if not any(st.stage == stage for st in stack):
+            return              # began before the listeners were there
+        while True:
+            st = stack.pop()
+            if st.stage == stage:
+                break
+            if st.span is not None:     # jax left it without an end event
+                st.span.__exit__(None, None, None)
+        if st.span is None:
+            return
+        rec = {"stage": stage, "fun": st.fun, "nth": st.nth,
+               "ts_us": st.t0 / 1e3, "dur_us": (t1 - st.t0) / 1e3,
+               "cache": st.cache,
+               "thread": threading.current_thread().name}
+        if st.saved_s is not None:
+            rec["saved_s"] = st.saved_s
+        if st.cache is not None and st.span is not _NO_SPAN:
+            # Known only now: the buffer's event gets them; the profiler's,
+            # whose stats are fixed as it begins, has ``fun`` and ``nth``.
+            st.span.attrs["cache"] = st.cache
+            if st.saved_s is not None:
+                st.span.attrs["saved_s"] = st.saved_s
+        st.span.__exit__(None, None, None)
+        with self.lock:
+            st.row[stage + "_s"] += (t1 - st.t0) / 1e9
+            if st.cache == "hit":
+                st.row["cache_hits"] += 1
+                st.row["saved_s"] += st.saved_s or 0.0
+            if len(self.records) >= _MAX_COMPILES:
+                self.dropped += 1
+            else:
+                self.records.append(rec)
+
+    def compiling(self) -> Optional[_Stage]:
+        """The backend compile open on this thread: jax's cache events
+        carry no name and belong to it."""
+        stack = self.stack()
+        return stack[-1] if stack and stack[-1].stage == "compile" else None
+
+
+_compiles = _Compiles()
+
+
+def _on_scalar(event: str, value: Any, fun_name: str = "", **kw: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is not None:
+        _compiles.begin(stage, fun_name)
+
+
+def _on_duration(event: str, duration: float, **kw: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is not None:
+        _compiles.end(stage)
+    elif event == _CACHE_SAVED:
+        st = _compiles.compiling()
+        if st is not None:
+            st.saved_s = duration
+
+
+def _on_event(event: str, **kw: Any) -> None:
+    said = _CACHE_SAID.get(event)
+    st = _compiles.compiling() if said is not None else None
+    if st is not None:
+        st.cache = said
+
+
+def listen_compiles() -> bool:
+    """Start listening to jax's compile events, once a process however
+    often it is called; ``False``, and nothing done, while jax is not in
+    the process. Called by whatever builds jitted programs
+    (``make_train_parts``, the xla driver's collectives, the loader), so
+    that the table holds every stage from the first program on."""
+    if _compiles.listening:
+        return True
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
+        return False
+    with _compiles.lock:
+        if not _compiles.listening:
+            monitoring.register_scalar_listener(_on_scalar)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            _compiles.listening = True
+    return True
+
+
+def compiles() -> List[Dict[str, Any]]:
+    """Every stage a jitted function went through since
+    :func:`listen_compiles`, in the order they ended, recording on or
+    off: ``{stage, fun, nth, ts_us, dur_us, cache, thread}`` with
+    ``stage`` ``trace`` | ``lower`` | ``compile``, ``fun`` as jax names
+    it (``step`` traced, ``jit(step)`` lowered and compiled), ``nth`` the
+    times this function has reached this stage in the process (``nth=2``
+    on a ``compile`` is the recompile), ``ts_us`` / ``dur_us`` on the
+    spans' ``perf_counter`` timeline (:func:`wall_anchor_ns` maps it to
+    jax's own wall-clock stamps), and on a ``compile`` ``cache``
+    ``hit`` | ``miss`` | ``off`` (``off``: the request did not go to the
+    persistent cache) with ``saved_s`` on a hit. A trace that begins
+    inside another stage of its thread is not listed (``nested_traces``
+    of :func:`compile_table`). Bounded: see :func:`compiles_dropped`."""
+    with _compiles.lock:
+        return [dict(r) for r in _compiles.records]
+
+
+def compile_table() -> Dict[str, Dict[str, Any]]:
+    """The same by function (``jit(step)`` and ``step`` are one row,
+    ``step``): ``traces``, ``nested_traces`` (jitted functions traced
+    inside its own traces and lowerings), ``lowerings``, ``compiles``
+    (each the last ``nth`` of its stage), ``cache_hits``, and the seconds
+    ``trace_s``, ``lower_s``, ``compile_s``, ``saved_s``. Never dropped
+    from."""
+    with _compiles.lock:
+        return {fun: dict(row) for fun, row in _compiles.by_fun.items()}
+
+
+def compiles_dropped() -> int:
+    """Stages left out of :func:`compiles` because its bound was hit."""
+    with _compiles.lock:
+        return _compiles.dropped
+
+
 def clear() -> None:
+    """Empty the span buffer and the counters. The compile table stays:
+    it is the process's history, as the programs it lists stay compiled."""
     with _tracer.lock:
         _tracer.events.clear()
         _tracer.counters.clear()

@@ -51,3 +51,34 @@ def test_rehearsal_prints_the_cells_record(cell, tmp_path):
     assert record["workload"] == cell
     assert record["failed"] == 0 and record["attempted"] > 0
     assert _end_to_end_names(cell) <= set(record["metrics"])
+
+
+@pytest.mark.integration
+@pytest.mark.parametrize("cell", ["osu-allreduce-f32-r4.sweep-4B-64MiB",
+                                  "starcoder2-3b-L6.pretrain-4k-b2"])
+def test_traced_rehearsal_reports_the_cells_set_up_metrics(cell, tmp_path):
+    """The four ``jit programs`` metrics read the program's always-on
+    compile table on the host, so a traced CPU rehearsal reports those of
+    the cell, the set-up table on the lines before the result."""
+    wanted = {m["name"] for m in BENCHMARK["per_layer"]
+              if m["layer"] == "jit programs" and cell in m["workloads"]}
+    assert {"setup_compile_s", "setup_trace_lower_s"} < wanted
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_ENABLE_X64")}
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    res = subprocess.run(
+        [sys.executable, *BENCHMARK["command"][1:], "--workload", cell,
+         "--seed", "2147483659", "--seconds", "2", "--trace", "1",
+         "--rehearse"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=240)
+    assert res.returncode == 0, res.stderr[-2000:]
+    lines = res.stdout.strip().splitlines()
+    metrics = json.loads(lines[-1])["metrics"]
+    assert wanted <= set(metrics)
+    assert any(line.startswith("setup_spans: ") for line in lines[:-1])
+    assert metrics["setup_compile_s"]["value"] > 0
+    assert metrics["setup_trace_lower_s"]["value"] > 0
+    if "step_compiles" in wanted:
+        assert metrics["step_compiles"]["value"] >= 1
+    # An empty cache of its own: every request went to it, none was served.
+    assert metrics["compile_cache_hit_share"]["value"] == 0.0

@@ -252,6 +252,36 @@ class TestMetricsArtifact:
         metrics.validate(again)
         assert again == doc
 
+    def test_snapshot_holds_and_validate_accepts_compiles(self):
+        import jax
+        import jax.numpy as jnp
+
+        assert trace.listen_compiles()
+
+        @jax.jit
+        def observed_program(x):
+            return x + 1
+
+        observed_program(jnp.ones(2))
+        observed_program(jnp.ones(3))
+        doc = json.loads(json.dumps(metrics.snapshot(rank=0, size=1)))
+        metrics.validate(doc)
+        row = doc["compiles"]["observed_program"]
+        assert (row["traces"], row["lowerings"], row["compiles"]) == (2, 2, 2)
+        assert row["compile_s"] > 0 and row["cache_hits"] == 0
+        assert doc["compiles_dropped"] == trace.compiles_dropped()
+        # `observe top` prints the costliest few of the process: one row.
+        line, = metrics.compile_lines({"compiles": {"observed_program": row}})
+        assert line.startswith("  compiles: observed_program x2 ")
+        assert "(0 from cache; traced x2, lowered x2)" in line
+        assert "  compiles: " in metrics.summary_text(rank=0)
+        # An artifact from before the section validates; a broken one not.
+        del doc["compiles"]
+        metrics.validate(doc)
+        for bad in ([], {"f": {"traces": 1}}, {"f": 3}):
+            with pytest.raises(ValueError):
+                metrics.validate(dict(doc, compiles=bad))
+
     def test_validate_rejects_malformed(self):
         with pytest.raises(ValueError):
             metrics.validate({"schema_version": 999})
