@@ -30,6 +30,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.ssd import ssd_scan_flat
@@ -124,14 +125,20 @@ def mamba2_mixer(h: jax.Array, blk: Dict[str, Any], cfg,
     trace.count("ssm.layers")
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.in_proj"):
-            zxbcdt = jnp.einsum("bsd,de->bse", h,
-                                blk["in_proj"].astype(h.dtype))
+            # Named for ``checkpointed_block`` (models/transformer.py), which
+            # holds what ``_REMAT_KEEPS`` lists: this product, not the conv's
+            # pre-activation below. Outside a checkpoint a name is the
+            # identity.
+            zxbcdt = checkpoint_name(
+                jnp.einsum("bsd,de->bse", h, blk["in_proj"].astype(h.dtype)),
+                "ssm_in")
             z = zxbcdt[..., :d_inner]
             xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
             dt = zxbcdt[..., d_inner + conv_dim:]
         with jax.named_scope("ssm.conv"):
-            xbc = jax.nn.silu(_causal_conv(
-                xbc, blk["conv_w"], blk["conv_b"])).astype(h.dtype)
+            xbc = jax.nn.silu(checkpoint_name(
+                _causal_conv(xbc, blk["conv_w"], blk["conv_b"]),
+                "ssm_conv")).astype(h.dtype)
         with jax.named_scope("ssm.scan"):
             # Heads and groups stay side by side, as the scan's kernels
             # read them: (b, s, h, p) would be another layout on the chip.

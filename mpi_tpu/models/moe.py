@@ -30,6 +30,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import trace
@@ -359,9 +360,11 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
             sizes, top_k, floor)
 
     with jax.named_scope("moe.shared"):
-        shared = jnp.einsum(
-            "tf,fd->td",
-            _relu2(jnp.einsum("td,df->tf", x2,
-                              params["shared_up"].astype(x.dtype))),
-            params["shared_down"].astype(x.dtype))
+        # Named for ``checkpointed_block``, which does not hold it: the
+        # table above ``_REMAT_KEEPS`` (models/transformer.py) says why.
+        up = checkpoint_name(
+            jnp.einsum("td,df->tf", x2, params["shared_up"].astype(x.dtype)),
+            "moe_shared_up")
+        shared = jnp.einsum("tf,fd->td", _relu2(up),
+                            params["shared_down"].astype(x.dtype))
     return (routed + shared).reshape(b, s, d)

@@ -104,7 +104,8 @@ class TransformerConfig:
     # switch that lets long sequences train on one chip's HBM. Held are
     # the few values that are cheap to hold and dear to redo
     # (``_REMAT_KEEPS``: the attention kernels' output and log-sum-exp,
-    # so they run once a layer, and the FFN's gate pre-activation).
+    # so they run once a layer, the FFN's gate pre-activation and a
+    # Mamba-2 block's in-projection).
     remat: bool = False
     # Grouped-query attention: number of k/v heads (None = n_heads,
     # plain MHA; 1 = MQA). Queries keep n_heads; k/v project to
@@ -768,7 +769,44 @@ def block_body(x, blk, cfg: TransformerConfig,
 # Held together they spare the forward kernel's second run (2.6 ms a
 # mixer) and the step is slower all the same, for 1.29 GiB more: the rule
 # keeps neither, and the scan's rule names nothing until a shape turns that.
-_REMAT_KEEPS = ("attn_out", "attn_lse", "ffn_gate")
+#
+# An ``M`` and an ``E`` block of a ``layer_pattern`` stack name three values
+# more (``models/mamba2.py``, ``models/moe.py``): ``ssm_in``, the
+# in-projection's product in the compute dtype; ``ssm_conv``, the conv's
+# float32 pre-activation; ``moe_shared_up``, the shared expert's
+# up-projection before ``relu2``. The same cell (four mixers, four expert
+# layers, shared width 3,712; PERF.md, PR 39): bytes held a token a block
+# (``in`` 20,608; ``conv`` 24,576; ``up`` 7,424); ms a step and tokens/s,
+# one run each, every run correct; GiB for the step / for the correctness
+# program with the 4.97 GiB optimizer state beside it; the largest
+# distance of a matrix of block 0's gradient from the float32 reference
+# in that run, of the 0.06 the cell allows:
+#
+#   out lse gate                  0  482.3  33,940  10.561 /  9.013  0.023
+#   out lse gate in          20,608  460.9  35,509  11.509 /  9.646  0.024  <-
+#   out lse gate in up       28,032  452.5  36,188  11.849 /  9.872  0.049
+#   out lse gate in conv     45,184  459.4  35,635  13.009 / 10.395  0.023
+#   out lse gate in conv up  52,608  450.9  36,311  13.349 / 10.622  0.045
+#
+# ``in`` takes the product's second run a mixer away (5.4 ms each).
+# ``conv`` buys 0.3% for 1.5 GiB: within 1%, and lost. ``up`` buys 1.9%
+# and the speed rule would keep it, but it moves the result: ``jax.
+# checkpoint`` rounds a held value to its dtype where it is produced
+# (``reduce_precision``, so that forward and backward read one value),
+# and without it the chip's compiler carries the product's float32
+# accumulator through ``relu2`` and rounds once, after the square. Held,
+# the gradient stands twice as far from the reference, at four fifths of
+# the cell's limit. So the rule has a line more: none that moves the
+# step's values. ``ssm_in`` moves nothing: its consumers are slices, so the
+# product was stored in its dtype already.
+#
+# One constant serves every kind of block, since a name exists only where
+# its value is produced: a classic block holds ``out lse gate``, an ``M``
+# block ``in``, an ``E`` block nothing, a ``*`` block ``out lse``. Choosing
+# the set from the compiler's ``memory_analysis`` waits for two cells that
+# want different sets of the same names; at a longer sequence the bytes a
+# token above say what a held value costs.
+_REMAT_KEEPS = ("attn_out", "attn_lse", "ffn_gate", "ssm_in")
 
 
 def checkpointed_block(cfg: TransformerConfig, mesh: Optional[Mesh] = None,

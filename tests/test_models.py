@@ -194,6 +194,21 @@ def _remat_cfg(impl, **kw):
                  eva_chunk=4, **kw)
 
 
+def _pattern_cfg(pattern="M*EM", **kw):
+    """A small ``layer_pattern`` stack: Mamba-2 mixers (``M``), attention
+    through the flash kernels on grouped k/v heads of a size of their own
+    (``*``) and a device's share of a routed expert layer beside a shared
+    expert (``E``), so every value an ``M`` or ``E`` block names exists."""
+    return TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, n_kv_heads=2, attn_head_dim=16,
+        d_ff=24, n_layers=len(pattern), layer_pattern=pattern, max_seq=32,
+        ffn="relu2", tie_embeddings=False, position_table=False,
+        attention_impl="flash", ssm_heads=4, ssm_head_dim=8, ssm_groups=2,
+        ssm_state=16, ssm_conv=4, ssm_chunk=8, n_experts=8, moe_top_k=2,
+        moe_experts_held=4, moe_expert_offset=2, moe_shared_d_ff=40,
+        moe_aux_coef=0.0, **kw)
+
+
 @pytest.mark.parametrize("make", [_tiny] + [
     functools.partial(_remat_cfg, impl) for impl in _REMAT_IMPLS],
     ids=("classic",) + _REMAT_IMPLS)
@@ -213,19 +228,30 @@ def test_remat_matches_plain_step(make):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def _pallas_calls(jaxpr, found=None):
-    """``{kernel name: calls}`` over a jaxpr and every jaxpr inside it."""
-    found = collections.Counter() if found is None else found
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of every jaxpr inside it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found[eqn.params["name"]] += 1
+        yield eqn
         for value in eqn.params.values():
             for inner in value if isinstance(value, (tuple, list)) else (
                     value,):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _pallas_calls(inner, found)
-    return found
+                    yield from _eqns(inner)
+
+
+def _pallas_calls(jaxpr):
+    """``{kernel name: calls}`` over a jaxpr and every jaxpr inside it."""
+    return collections.Counter(
+        eqn.params["name"] for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
+def _products(jaxpr):
+    """``{output shape: dot_generals}``, likewise."""
+    return collections.Counter(
+        eqn.outvars[0].aval.shape for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "dot_general")
 
 
 @pytest.mark.parametrize("impl, forward_kernels", [
@@ -244,9 +270,22 @@ def test_remat_runs_forward_kernels_once_a_layer(impl, forward_kernels):
 
 
 def _named_bytes(cfg, batch, seq):
-    """Bytes of each value a block names, by hand, float32 throughout."""
+    """Bytes of each value a block names, by hand, float32 throughout:
+    one ``{name: bytes}`` a block."""
     d, h, f = cfg.d_model, cfg.n_heads, cfg.d_ff
     token_wide = 4 * batch * seq
+    if cfg.layer_pattern is not None:
+        q, kv = h * cfg.attn_head_dim, cfg.n_kv_heads * cfg.attn_head_dim
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        by_kind = {
+            "M": {"ssm_in": token_wide * (inner + conv + cfg.ssm_heads),
+                  "ssm_conv": token_wide * conv},
+            "E": {"moe_shared_up": token_wide * cfg.moe_shared_d_ff},
+            "*": {"attn_q": token_wide * q, "attn_k": token_wide * kv,
+                  "attn_v": token_wide * kv, "attn_out": token_wide * q,
+                  "attn_lse": token_wide * h}}
+        return [by_kind[kind] for kind in cfg.layer_pattern]
     by_hand = {"attn_q": token_wide * d, "attn_k": token_wide * d,
                "attn_v": token_wide * d, "ffn_gate": token_wide * f,
                "ffn_up": token_wide * f}
@@ -255,24 +294,28 @@ def _named_bytes(cfg, batch, seq):
     if cfg.attention_impl == "eva":
         summaries = 4 * batch * (seq // cfg.eva_chunk) * d
         by_hand.update(eva_ks=summaries, eva_vs=summaries)
-    return by_hand
+    return [by_hand] * cfg.n_layers
 
 
 @pytest.mark.parametrize("keeps", ["as committed", "every name"])
-@pytest.mark.parametrize("impl", _REMAT_IMPLS)
+@pytest.mark.parametrize("impl", _REMAT_IMPLS + ("pattern",))
 def test_remat_counters_read_their_hand_count(impl, keeps, monkeypatch,
                                               traced):
     """``remat.blocks`` and ``remat.kept_bytes`` with tracing on; silent
-    with it off. With every name kept, each named site is checked."""
+    with it off. With every name kept, each named site is checked:
+    those of the classic block by attention op and, in a ``layer_pattern``
+    stack, those of an ``M``, an ``E`` and a ``*`` block."""
     from mpi_tpu.models import transformer
 
-    cfg = _remat_cfg(impl, remat=True)
+    cfg = (_pattern_cfg(remat=True) if impl == "pattern"
+           else _remat_cfg(impl, remat=True))
     tok = _tokens()
     by_hand = _named_bytes(cfg, tok.shape[0], tok.shape[1] - 1)
     if keeps == "every name":
-        monkeypatch.setattr(transformer, "_REMAT_KEEPS", tuple(by_hand))
-    want = cfg.n_layers * sum(
-        by_hand.get(name, 0) for name in transformer._REMAT_KEEPS)
+        monkeypatch.setattr(transformer, "_REMAT_KEEPS",
+                            tuple(set().union(*by_hand)))
+    want = sum(blk.get(name, 0) for blk in by_hand
+               for name in transformer._REMAT_KEEPS)
     params = init_params(jax.random.PRNGKey(0), cfg)
 
     def counted_while_tracing_the_gradient():
@@ -286,6 +329,27 @@ def test_remat_counters_read_their_hand_count(impl, keeps, monkeypatch,
     traced.enable()
     assert counted_while_tracing_the_gradient() == {
         "remat.blocks": cfg.n_layers, "remat.kept_bytes": want}
+
+
+@pytest.mark.parametrize("keeps, a_mixer", [("as committed", 1),
+                                            ("nothing", 2)])
+def test_remat_runs_the_in_projection_once_a_mixer(keeps, a_mixer,
+                                                   monkeypatch):
+    """An ``M`` block under remat holds its in-projection's product, so
+    the gradient's jaxpr has it once a mixer: the backward does not run
+    it again. With nothing held it does, which the count shows."""
+    from mpi_tpu.models import transformer
+
+    if keeps == "nothing":
+        monkeypatch.setattr(transformer, "_REMAT_KEEPS", ())
+    cfg = _pattern_cfg("MEME", remat=True)
+    tok = _tokens()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    products = _products(jax.make_jaxpr(jax.grad(
+        lambda p, t: transformer.loss_fn(p, t, cfg)))(params, tok).jaxpr)
+    wide = params["blocks"][0]["in_proj"].shape[1]
+    assert products[tok.shape[0], tok.shape[1] - 1, wide] == (
+        a_mixer * cfg.layer_pattern.count("M")), dict(products)
 
 
 def test_plain_block_is_returned_as_it_is():

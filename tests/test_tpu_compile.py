@@ -334,10 +334,11 @@ def test_ssd_kernels_run_on_each_chips_rows_of_a_dp_batch(topo):
                    for call in calls), calls[0][:400]
 
 
-# What the compiler counts for the nemotron-3-nano cell's step: 10.561 GiB
-# at batch 2 with the scan as two kernels (PR 37; 12.010 with the scan as
-# an XLA program, PR 36; 11.849 with the kernels' output and states held
-# under ``remat``).
+# What the compiler counts for the nemotron-3-nano cell's step: 11.509 GiB
+# at batch 2 with the in-projection's product held under ``remat`` (PR 39;
+# 10.561 with nothing of an ``M`` block held, PR 37; 12.010 with the scan
+# as an XLA program, PR 36; 13.349 with every value an ``M`` or ``E`` block
+# names held, the table above ``_REMAT_KEEPS``).
 NEMOTRON_STEP_GIB = 15.25
 
 
@@ -383,6 +384,15 @@ def test_nemotron_cell_train_step_fits_with_room_to_spare(
         assert len(calls) == a_mixer * mixers, \
             f"{len(calls)} %{kernel} calls for {mixers} mixers"
         assert all("ssm.scan" in call for call in calls), kernel
+    # What an ``M`` block holds (``_REMAT_KEEPS``: ``ssm_in``) the backward
+    # does not redo: the step has the in-projection's product
+    # ``bf16[2,8192,10304]`` once a mixer forward and none in a block's
+    # recomputation, where the parent's had one a mixer there.
+    products = [line for line in text.splitlines()
+                if "/ssm.in_proj/bsd,de->bse/dot_general" in line]
+    assert any("/jvp(" in op for op in products)
+    redone = [op for op in products if "rematted_computation" in op]
+    assert not redone, redone[0][:300]
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
