@@ -270,3 +270,6 @@ class ShardedLoader:
                 yield item
         finally:
             stop.set()
+            # A daemon thread still inside a jax call when the interpreter
+            # exits aborts the process ("exception not rethrown").
+            t.join()

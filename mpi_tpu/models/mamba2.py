@@ -80,14 +80,16 @@ def mamba2_specs() -> Dict[str, P]:
         "out_proj")}
 
 
-def _causal_conv(u, w, bias):
+def _causal_conv(u, w, bias=None):
     """Depthwise, causal: ``out_t = sum_j w[j] u_{t - (k-1) + j} + bias``
-    with ``u`` zero before the sequence. ``u`` ``(b, s, ch)``, ``w``
-    ``(k, ch)``."""
+    with ``u`` zero before the sequence, in float32. ``u`` ``(b, s, ch)``,
+    ``w`` ``(k, ch)``, ``bias`` ``(ch,)`` or None (none: the short conv of
+    ``models/short_conv.py``)."""
     k, s = w.shape[0], u.shape[1]
     padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + s].astype(_F32) * w[j].astype(_F32)
-               for j in range(k)) + bias.astype(_F32)
+    out = sum(padded[:, j:j + s].astype(_F32) * w[j].astype(_F32)
+              for j in range(k))
+    return out if bias is None else out + bias.astype(_F32)
 
 
 def _scan_per_shard(mesh: Optional[Mesh], x, dt, A, B, C, D, chunk, groups):

@@ -15,10 +15,11 @@ fully differentiable, no data-dependent shapes.
 :func:`routed_share_ffn` is the other routed layer: one device's share of
 an expert-parallel layer whose experts outnumber the devices. It scores
 every expert of the layer, is told which contiguous share it holds, drops
-no (token, expert) pair whatever the load, and adds a shared expert. The
-pairs that land on held experts are sorted by expert and laid out in tiles
-of rows that belong to one expert each; the expert products are two
-matmuls a tile, in one loop over the occupied tiles.
+no (token, expert) pair whatever the load, and adds a shared expert where
+it has one. The pairs that land on held experts are sorted by expert and
+laid out in tiles of rows that belong to one expert each; the expert
+products are two (relu²) or three (SwiGLU) matmuls a tile, in one loop
+over the occupied tiles.
 """
 
 from __future__ import annotations
@@ -154,24 +155,47 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
 
 def init_routed_share_params(key: jax.Array, d_model: int, d_ff: int,
                              d_shared: int, n_experts: int, held: int,
-                             dtype: Any) -> Dict[str, Any]:
-    """The router over all ``n_experts``, ``held`` two-matrix experts of
-    width ``d_ff`` and the shared expert of width ``d_shared``."""
+                             dtype: Any, gated: bool = False,
+                             select_bias: bool = False,
+                             bias_std: float = 0.0) -> Dict[str, Any]:
+    """The router over all ``n_experts``, ``held`` experts of width
+    ``d_ff`` (two matrices, or with ``gated`` a third, ``w_gate``: SwiGLU)
+    and the shared expert of width ``d_shared`` (none where it is 0). With
+    ``select_bias`` the router's selection bias ``router_bias``
+    ``(n_experts,)`` float32, zero or drawn N(0, ``bias_std``^2): it
+    decides which experts a token uses, never their weights, and no step
+    updates it (``make_train_parts``)."""
     keys = jax.random.split(key, 5)
-    return {
+    out = {
         "router": _dense(keys[0], (d_model, n_experts), d_model, dtype),
         "w_up": _dense(keys[1], (held, d_model, d_ff), d_model, dtype),
         "w_down": _dense(keys[2], (held, d_ff, d_model), d_ff, dtype),
-        "shared_up": _dense(keys[3], (d_model, d_shared), d_model, dtype),
-        "shared_down": _dense(keys[4], (d_shared, d_model), d_shared, dtype),
     }
+    if d_shared:
+        out["shared_up"] = _dense(keys[3], (d_model, d_shared), d_model,
+                                  dtype)
+        out["shared_down"] = _dense(keys[4], (d_shared, d_model), d_shared,
+                                    dtype)
+    if gated:   # folded in, so that the two-matrix draw is what it was
+        out["w_gate"] = _dense(jax.random.fold_in(key, 5),
+                               (held, d_model, d_ff), d_model, dtype)
+    if select_bias:
+        out["router_bias"] = bias_std * jax.random.normal(
+            jax.random.fold_in(key, 6), (n_experts,), jnp.float32) \
+            if bias_std else jnp.zeros((n_experts,), jnp.float32)
+    return out
 
 
-def routed_share_specs() -> Dict[str, P]:
-    """Every leaf replicated: the share IS the device's part of the
-    expert-parallel layer, and nothing of it is split further."""
-    return {name: P() for name in (
-        "router", "w_up", "w_down", "shared_up", "shared_down")}
+def routed_share_specs(gated: bool = False, shared: bool = True,
+                       select_bias: bool = False) -> Dict[str, P]:
+    """Every leaf of :func:`init_routed_share_params`'s tree replicated:
+    the share IS the device's part of the expert-parallel layer, and
+    nothing of it is split further."""
+    names = ["router", "w_up", "w_down"]
+    names += ["shared_up", "shared_down"] if shared else []
+    names += ["w_gate"] if gated else []
+    names += ["router_bias"] if select_bias else []
+    return {name: P() for name in names}
 
 
 def _relu2(h):
@@ -193,12 +217,19 @@ _TILE = 512
 
 
 def route_top_k(x2: jax.Array, router: jax.Array, top_k: int,
-                scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """The routed layer's decisions for ``x2`` ``(tokens, d)``: the
-    ``top_k`` largest of ``sigmoid(x W_r)`` over every expert of the layer
-    as ``idx`` ``(tokens, top_k)``, and their weights ``s_k / (sum_k s_k +
-    1e-20) * scale`` in float32."""
-    chosen, idx = lax.top_k(_router_scores(x2, router), top_k)
+                scale: float = 1.0, bias: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The routed layer's decisions for ``x2`` ``(tokens, d)``: over every
+    expert of the layer the scores ``s = sigmoid(x W_r)``, the ``top_k``
+    largest of ``s + bias`` (of ``s`` without a bias) as ``idx`` ``(tokens,
+    top_k)``, and their weights ``s_k / (sum_k s_k + 1e-20) * scale`` in
+    float32: the bias selects, the scores weigh."""
+    scores = _router_scores(x2, router)
+    if bias is None:
+        chosen, idx = lax.top_k(scores, top_k)
+    else:
+        idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)[1]
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
 
 
@@ -224,28 +255,40 @@ def _tile_layout(order, sizes, top_k: int):
     return tile_end[-1], rows_of
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _expert_tiles(x2, w_up, w_down, pair_weight, order, sizes, top_k,
-                  floor):
-    """``sum over a token's pairs of weight x W_down[e] relu(W_up[e] x)^2``
-    for the pairs on held experts: ``x2`` ``(tokens, d)``, ``pair_weight``
+def _expert_rows(x, experts, e):
+    """Rows ``x`` through held expert ``e``: ``W_down relu(W_up x)^2`` for
+    ``experts`` = ``(w_up, w_down)``, ``W_down (silu(W_gate x) * W_up x)``
+    for ``(w_up, w_down, w_gate)``."""
+    if len(experts) == 2:
+        w_up, w_down = experts
+        return jnp.dot(_relu2(jnp.dot(x, w_up[e])), w_down[e])
+    w_up, w_down, w_gate = experts
+    return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate[e])) * jnp.dot(x, w_up[e]),
+                   w_down[e])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _expert_tiles(x2, experts, pair_weight, order, sizes, top_k, floor):
+    """``sum over a token's pairs of weight x expert_e(x)``
+    (:func:`_expert_rows`) for the pairs on held experts: ``x2`` ``(tokens,
+    d)``, ``experts`` the held experts' matrices, ``pair_weight``
     ``(tokens * top_k,)``, ``order`` the pairs sorted by held expert and
     ``sizes`` the pairs of each; returns ``(tokens, d)`` in ``x2``'s dtype.
 
     ONE loop over the tiles, with a trip count read from the load: a
-    tile's rows are gathered, go through two matmuls with their expert's
-    matrices, and are added to their tokens' rows, so the work follows
+    tile's rows are gathered, go through their expert's two or three
+    matmuls, and are added to their tokens' rows, so the work follows
     the pairs and nothing holds a buffer. The loop runs at least ``floor``
     tiles (the tiles past the occupied ones hold no pair and add
     nothing). The backward pass is the same loop again: it recomputes a
-    tile's hidden rows and adds its two weight gradients into float32
-    sums in place."""
+    tile's hidden rows and adds its weight gradients into float32 sums in
+    place."""
     tokens, d = x2.shape
     occupied, rows_of = _tile_layout(order, sizes, top_k)
 
     def body(t, acc):
         e, pair, token, live = rows_of(t)
-        out = jnp.dot(_relu2(jnp.dot(x2[token], w_up[e])), w_down[e])
+        out = _expert_rows(x2[token], experts, e)
         weight = jnp.where(live, pair_weight[pair], 0)
         return acc.at[token].add(out.astype(jnp.float32) * weight[:, None])
 
@@ -254,21 +297,20 @@ def _expert_tiles(x2, w_up, w_down, pair_weight, order, sizes, top_k,
                          ).astype(x2.dtype)
 
 
-def _expert_tiles_fwd(x2, w_up, w_down, pair_weight, order, sizes, top_k,
-                      floor):
-    return (_expert_tiles(x2, w_up, w_down, pair_weight, order, sizes,
-                          top_k, floor),
-            (x2, w_up, w_down, pair_weight, order, sizes))
+def _expert_tiles_fwd(x2, experts, pair_weight, order, sizes, top_k, floor):
+    return (_expert_tiles(x2, experts, pair_weight, order, sizes, top_k,
+                          floor),
+            (x2, experts, pair_weight, order, sizes))
 
 
 def _expert_tiles_bwd(top_k, floor, held_back, g):
-    x2, w_up, w_down, pair_weight, order, sizes = held_back
+    x2, experts, pair_weight, order, sizes = held_back
     f32 = jnp.float32
     occupied, rows_of = _tile_layout(order, sizes, top_k)
 
-    def body(t, sums):
-        d_x, d_up, d_down, d_weight = sums
-        e, pair, token, live = rows_of(t)
+    def relu2_body(t, sums):
+        d_x, (d_up, d_down), d_weight = sums
+        (w_up, w_down), (e, pair, token, live) = experts, rows_of(t)
         x, g_t = x2[token], g[token]
         weight = jnp.where(live, pair_weight[pair], 0)[:, None]
         r = jax.nn.relu(jnp.dot(x, w_up[e]))
@@ -281,15 +323,44 @@ def _expert_tiles_bwd(top_k, floor, held_back, g):
         d_pre = back * (2 * r) * weight.astype(r.dtype)
         d_up = d_up.at[e].add(jnp.dot(x.T, d_pre, preferred_element_type=f32))
         d_x = d_x.at[token].add(jnp.dot(d_pre, w_up[e].T).astype(f32))
-        return d_x, d_up, d_down, d_weight
+        return d_x, (d_up, d_down), d_weight
 
-    d_x, d_up, d_down, d_weight = lax.fori_loop(
-        0, jnp.maximum(occupied, floor), body,
-        (jnp.zeros(x2.shape, f32), jnp.zeros(w_up.shape, f32),
-         jnp.zeros(w_down.shape, f32), jnp.zeros(pair_weight.shape, f32)))
-    return (d_x.astype(x2.dtype), d_up.astype(w_up.dtype),
-            d_down.astype(w_down.dtype), d_weight.astype(pair_weight.dtype),
-            None, None)
+    def swiglu_body(t, sums):
+        # The gate's derivative in float32: silu'(a) = s (1 + a (1 - s)).
+        d_x, (d_up, d_down, d_gate), d_weight = sums
+        (w_up, w_down, w_gate), (e, pair, token, live) = experts, rows_of(t)
+        x, g_t = x2[token], g[token]
+        weight = jnp.where(live, pair_weight[pair], 0)[:, None]
+        a, u = jnp.dot(x, w_gate[e]), jnp.dot(x, w_up[e])
+        hidden = jax.nn.silu(a) * u
+        back = jnp.dot(g_t, w_down[e].T)            # d out / d hidden
+        d_weight = d_weight.at[pair].add(jnp.where(
+            live, (hidden.astype(f32) * back.astype(f32)).sum(-1), 0))
+        d_down = d_down.at[e].add(jnp.dot(
+            (hidden * weight.astype(hidden.dtype)).T, g_t,
+            preferred_element_type=f32))
+        a32, d_hidden = a.astype(f32), back.astype(f32) * weight
+        sig = jax.nn.sigmoid(a32)
+        d_u = (d_hidden * a32 * sig).astype(x.dtype)
+        d_a = (d_hidden * u.astype(f32) * sig * (1 + a32 * (1 - sig))
+               ).astype(x.dtype)
+        d_up = d_up.at[e].add(jnp.dot(x.T, d_u, preferred_element_type=f32))
+        d_gate = d_gate.at[e].add(
+            jnp.dot(x.T, d_a, preferred_element_type=f32))
+        d_x = d_x.at[token].add(
+            jnp.dot(d_u, w_up[e].T, preferred_element_type=f32)
+            + jnp.dot(d_a, w_gate[e].T, preferred_element_type=f32))
+        return d_x, (d_up, d_down, d_gate), d_weight
+
+    d_x, d_experts, d_weight = lax.fori_loop(
+        0, jnp.maximum(occupied, floor),
+        relu2_body if len(experts) == 2 else swiglu_body,
+        (jnp.zeros(x2.shape, f32),
+         tuple(jnp.zeros(w.shape, f32) for w in experts),
+         jnp.zeros(pair_weight.shape, f32)))
+    return (d_x.astype(x2.dtype),
+            tuple(d.astype(w.dtype) for d, w in zip(d_experts, experts)),
+            d_weight.astype(pair_weight.dtype), None, None)
 
 
 _expert_tiles.defvjp(_expert_tiles_fwd, _expert_tiles_bwd)
@@ -312,13 +383,18 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     """``x`` ``(batch, seq, d)`` -> ``sum_k w_k expert_k(x) + shared(x)``
     over the experts ``offset .. offset + held - 1`` that ``params`` holds
     (``held = params["w_up"].shape[0]``); what the other experts of the
-    layer would add is left out.
+    layer would add is left out. What ``params`` holds decides the rest
+    (:func:`init_routed_share_params`).
 
     Routing (:func:`route_top_k`): ``s = sigmoid(x W_r)`` over all
-    ``n_experts``, product and scores in float32; the ``top_k`` largest;
-    weights ``s_k / (sum_k s_k + 1e-20) * scale`` (the sum over all
-    ``top_k`` chosen, held or not). An expert is ``W_down relu(W_up
-    x)^2``, the shared expert the same at its own width for every token.
+    ``n_experts``, product and scores in float32; the ``top_k`` largest
+    of ``s`` (of ``s + router_bias`` where ``params`` has that leaf: the
+    bias selects, and neither weighs nor gets a gradient); weights ``s_k
+    / (sum_k s_k + 1e-20) * scale`` (the sum over all ``top_k`` chosen,
+    held or not). An expert is ``W_down relu(W_up x)^2``, or ``W_down
+    (silu(W_gate x) * W_up x)`` where ``params`` has ``w_gate``; the
+    shared expert, where ``params`` has one, ``W_sd relu(W_su x)^2`` at
+    its own width for every token.
 
     No pair that lands on a held expert is dropped, whatever the load. The
     pairs are sorted by expert (those of absent experts last) and laid out
@@ -326,7 +402,7 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     tile's rows share one expert, and one loop runs over the occupied
     tiles (:func:`_expert_tiles`; at least :func:`floor_tiles` of them).
     Scopes ``moe.route``, ``moe.routed`` (sort, dispatch, expert products,
-    combine) and ``moe.shared``; with tracing on each call adds 1 to
+    combine) and ``moe.shared`` (none without a shared expert); with tracing on each call adds 1 to
     ``moe.layers`` (at trace time)."""
     b, s, d = x.shape
     tokens, held = b * s, params["w_up"].shape[0]
@@ -343,7 +419,8 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     x2 = x.reshape(tokens, d)
 
     with jax.named_scope("moe.route"):
-        idx, weight = route_top_k(x2, params["router"], top_k, scale)
+        idx, weight = route_top_k(x2, params["router"], top_k, scale,
+                                  params.get("router_bias"))
         local = idx - offset
         # A pair's key: its expert's place in the share, or ``held`` for
         # an absent expert, so that a sort puts the share's pairs first.
@@ -354,10 +431,12 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
         order = jnp.argsort(key, stable=True)
         sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
             0, dtype=jnp.int32)
-        routed = _expert_tiles(
-            x2, params["w_up"].astype(x.dtype),
-            params["w_down"].astype(x.dtype), weight.reshape(-1), order,
-            sizes, top_k, floor)
+        experts = tuple(params[name].astype(x.dtype) for name in (
+            "w_up", "w_down", "w_gate") if name in params)
+        routed = _expert_tiles(x2, experts, weight.reshape(-1), order,
+                               sizes, top_k, floor)
+    if "shared_up" not in params:
+        return routed.reshape(b, s, d)
 
     with jax.named_scope("moe.shared"):
         # Named for ``checkpointed_block``, which does not hold it: the

@@ -155,19 +155,29 @@ class TransformerConfig:
     # With ``rope`` off: the learned absolute table (True), or no position
     # term at all (False; a model whose mixers see order by themselves).
     position_table: bool = True
+    # A norm over each head's values of q and of k (RMS, weight 1 + scale,
+    # leaves ``q_norm`` / ``k_norm`` of ``head_dim``), before any position
+    # term.
+    qk_norm: bool = False
     # A block stack driven by a list of layer kinds, one letter a layer
     # (``n_layers`` = its length; None = the classic block throughout).
     # Block ``i`` is then ``x + f_i(norm(x))`` with ONE sub-layer, one norm
     # (``ln1``) and only its own leaves: ``M`` a Mamba-2 mixer
-    # (models/mamba2.py; the ``ssm_*`` sizes below), ``*`` attention (the
-    # ``_attention`` of the classic block), ``E`` one device's share of a
-    # sigmoid-routed expert layer beside a shared expert (models/moe.py
-    # ``routed_share_ffn``): ``n_experts`` is the width of the router,
-    # ``moe_top_k`` the experts a token, ``d_ff`` an expert's width,
-    # ``moe_experts_held`` contiguous experts from ``moe_expert_offset``
-    # live here (None = all), ``moe_shared_d_ff`` is the shared expert's
-    # width and ``moe_routed_scale`` multiplies the normalised weights.
-    # ``M`` and ``*`` run under the scope ``attn``, ``E`` under ``ffn``.
+    # (models/mamba2.py; the ``ssm_*`` sizes below), ``C`` a gated short
+    # conv (models/short_conv.py), ``*``
+    # attention (the ``_attention`` of the classic block), ``F`` the dense
+    # FFN of kind ``ffn`` at width ``dense_d_ff``, ``E`` one device's share
+    # of a sigmoid-routed expert layer (models/moe.py ``routed_share_ffn``):
+    # ``n_experts`` is the width of the router, ``moe_top_k`` the experts a
+    # token, ``d_ff`` an expert's width and ``ffn`` its kind (relu2 or
+    # swiglu), ``moe_experts_held`` contiguous experts from
+    # ``moe_expert_offset`` live here (None = all), ``moe_shared_d_ff`` is
+    # the shared expert's width (0 = none), ``moe_routed_scale`` multiplies
+    # the normalised weights and ``moe_router_bias`` gives the router a
+    # selection bias that no step updates, drawn N(0, std^2) at
+    # ``moe_router_bias_std`` (0: zero, as released; above 0 a stand-in for
+    # a bias that balancing has moved). ``M``, ``C`` and ``*`` run under
+    # the scope ``attn``, ``E`` and ``F`` under ``ffn``.
     layer_pattern: Optional[str] = None
     ssm_heads: int = 0
     ssm_head_dim: int = 64
@@ -179,6 +189,9 @@ class TransformerConfig:
     moe_expert_offset: int = 0
     moe_shared_d_ff: int = 0
     moe_routed_scale: float = 1.0
+    moe_router_bias: bool = False
+    moe_router_bias_std: float = 0.0
+    dense_d_ff: int = 0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm_unit_offset"):
@@ -210,33 +223,47 @@ class TransformerConfig:
 
     def _check_layer_pattern(self):
         pattern = self.layer_pattern
-        if not pattern or set(pattern) - set("ME*") \
+        if not pattern or set(pattern) - set("MC*EF") \
                 or len(pattern) != self.n_layers:
             raise ValueError(
                 f"mpi_tpu: layer_pattern={pattern!r} must be n_layers="
-                f"{self.n_layers} letters of M (Mamba-2), E (experts), "
-                f"* (attention)")
+                f"{self.n_layers} letters of M (Mamba-2), C (gated short "
+                f"conv), * (attention), E (routed experts), F (dense FFN)")
+        if "F" in pattern and self.dense_d_ff < 1:
+            raise ValueError(
+                f"mpi_tpu: an F layer needs its width dense_d_ff (got "
+                f"{self.dense_d_ff}); d_ff is an E layer's expert width")
         if "M" in pattern and (self.ssm_heads < 1
                                or self.ssm_heads % self.ssm_groups):
             raise ValueError(
                 f"mpi_tpu: an M layer needs ssm_heads (got {self.ssm_heads}) "
                 f"in whole groups of ssm_groups={self.ssm_groups}")
         if "E" in pattern:
-            if self.ffn != "relu2":
+            if self.ffn not in ("relu2", "swiglu"):
                 raise ValueError(
                     f"mpi_tpu: the experts of an E layer are relu2 (two "
-                    f"matrices); ffn={self.ffn!r} experts are not "
-                    f"implemented")
+                    f"matrices) or swiglu (three); ffn={self.ffn!r} experts "
+                    f"are not implemented")
+            if self.moe_router_bias_std and not self.moe_router_bias:
+                raise ValueError(
+                    f"mpi_tpu: moe_router_bias_std="
+                    f"{self.moe_router_bias_std} draws a selection bias "
+                    f"that moe_router_bias=False leaves out")
+            if self.ffn == "swiglu" and self.moe_shared_d_ff:
+                raise ValueError(
+                    f"mpi_tpu: the shared expert of an E layer is relu2; "
+                    f"beside swiglu experts moe_shared_d_ff must be 0 (got "
+                    f"{self.moe_shared_d_ff})")
             held = self.experts_held
-            if (self.n_experts < 1 or held < 1 or self.moe_shared_d_ff < 1
+            if (self.n_experts < 1 or held < 1 or self.moe_shared_d_ff < 0
                     or self.moe_expert_offset < 0
                     or self.moe_expert_offset + held > self.n_experts):
                 raise ValueError(
                     f"mpi_tpu: an E layer needs n_experts (got "
                     f"{self.n_experts}), a share moe_expert_offset="
                     f"{self.moe_expert_offset} + moe_experts_held={held} "
-                    f"inside it and moe_shared_d_ff (got "
-                    f"{self.moe_shared_d_ff})")
+                    f"inside it and moe_shared_d_ff >= 0 (got "
+                    f"{self.moe_shared_d_ff}; 0 = no shared expert)")
 
     @property
     def experts_held(self) -> int:
@@ -259,7 +286,8 @@ class TransformerConfig:
         classic = TransformerConfig()
         names = ["norm", "ffn", "tie_embeddings", "n_pred_heads",
                  "residual_dtype", "attn_head_dim", "position_table",
-                 "layer_pattern"]
+                 "layer_pattern", "qk_norm", "moe_router_bias",
+                 "dense_d_ff"]
         out = [f"{n}={getattr(self, n)!r}" for n in names
                if getattr(self, n) != getattr(classic, n)]
         if self.attention_impl == "eva":
@@ -337,6 +365,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
             if cfg.ffn == "swiglu":
                 blk["w3"] = _dense_init(jax.random.fold_in(keys[2 + i], 6),
                                         (d, f), pd, d)
+        if cfg.qk_norm:
+            blk.update(_qk_norm_init(cfg))
         if cfg.attention_impl == "eva":
             # Unit normal, not zero: with keys and queries of unit scale
             # the pooling weights are uneven and the summaries' offset
@@ -362,12 +392,24 @@ def _init_pattern_block(key, kind: str, cfg: TransformerConfig):
         from .mamba2 import init_mamba2_params
 
         blk.update(init_mamba2_params(key, cfg))
+    elif kind == "C":
+        from .short_conv import init_short_conv_params
+
+        blk.update(init_short_conv_params(key, cfg))
     elif kind == "E":
         from .moe import init_routed_share_params
 
         blk.update(init_routed_share_params(
             key, d, cfg.d_ff, cfg.moe_shared_d_ff, cfg.n_experts,
-            cfg.experts_held, pd))
+            cfg.experts_held, pd, gated=cfg.ffn == "swiglu",
+            select_bias=cfg.moe_router_bias,
+            bias_std=cfg.moe_router_bias_std))
+    elif kind == "F":
+        ks, f = jax.random.split(key, 3), cfg.dense_d_ff
+        blk.update(w1=_dense_init(ks[0], (d, f), pd, d),
+                   w2=_dense_init(ks[1], (f, d), pd, f))
+        if cfg.ffn == "swiglu":
+            blk["w3"] = _dense_init(ks[2], (d, f), pd, d)
     else:
         ks = jax.random.split(key, 4)
         h, hd, kv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
@@ -375,23 +417,43 @@ def _init_pattern_block(key, kind: str, cfg: TransformerConfig):
                    wk=_dense_init(ks[1], (d, kv, hd), pd, d),
                    wv=_dense_init(ks[2], (d, kv, hd), pd, d),
                    wo=_dense_init(ks[3], (h, hd, d), pd, h * hd))
+        if cfg.qk_norm:
+            blk.update(_qk_norm_init(cfg))
     return blk
 
 
-def _pattern_block_specs(kind: str, norm) -> Dict[str, Any]:
+def _pattern_block_specs(kind: str, norm, cfg: TransformerConfig
+                         ) -> Dict[str, Any]:
     """Replicated throughout: a ``layer_pattern`` stack refuses a mesh
     that would split a layer (``_refuse_split_mesh``)."""
     if kind == "M":
         from .mamba2 import mamba2_specs
 
         leaves = mamba2_specs()
+    elif kind == "C":
+        from .short_conv import short_conv_specs
+
+        leaves = short_conv_specs()
     elif kind == "E":
         from .moe import routed_share_specs
 
-        leaves = routed_share_specs()
+        leaves = routed_share_specs(
+            gated=cfg.ffn == "swiglu", shared=cfg.moe_shared_d_ff > 0,
+            select_bias=cfg.moe_router_bias)
+    elif kind == "F":
+        leaves = {name: P() for name in (
+            ("w1", "w2", "w3") if cfg.ffn == "swiglu" else ("w1", "w2"))}
     else:
         leaves = {name: P() for name in ("wq", "wk", "wv", "wo")}
+        if cfg.qk_norm:
+            leaves.update(q_norm={"scale": P()}, k_norm={"scale": P()})
     return dict(leaves, ln1=dict(norm))
+
+
+def _qk_norm_init(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The weights of the q and k norms, ``1 + scale`` with scale zero."""
+    return {name: {"scale": jnp.zeros((cfg.head_dim,), cfg.param_dtype)}
+            for name in ("q_norm", "k_norm")}
 
 
 def _norm_init(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -427,6 +489,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         blk["w2"] = P("tp", None)
         if cfg.ffn == "swiglu":
             blk["w3"] = P(None, "tp")
+    if cfg.qk_norm:
+        blk.update(q_norm={"scale": P()}, k_norm={"scale": P()})
     if cfg.attention_impl == "eva":
         blk["eva_phi"] = P("tp", None)
         blk["eva_mu"] = P("tp", None)
@@ -435,7 +499,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "final_ln": dict(norm),
         "blocks": ([dict(blk) for _ in range(cfg.n_layers)]
                    if cfg.layer_pattern is None else
-                   [_pattern_block_specs(kind, norm)
+                   [_pattern_block_specs(kind, norm, cfg)
                     for kind in cfg.layer_pattern]),
     }
     if not cfg.tie_embeddings:
@@ -515,6 +579,9 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     q = jnp.einsum("bsd,dhk->bshk", x, blk["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, blk["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, blk["wv"].astype(x.dtype))
+    if cfg.qk_norm:
+        q = _head_norm(q, blk["q_norm"])
+        k = _head_norm(k, blk["k_norm"])
     if cfg.rope:
         # Global positions, applied BEFORE any sequence sharding — the
         # ring/zigzag layouts then carry already-rotated values.
@@ -589,6 +656,15 @@ def _attention(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     return jnp.einsum("bshk,hkd->bsd", ctx, blk["wo"].astype(x.dtype))
 
 
+def _head_norm(x, p):
+    """RMSNorm over the last axis (a head's values), weight ``1 + scale``,
+    in float32; handed on in ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(ms + _NORM_EPS)
+            * (1 + p["scale"].astype(jnp.float32))).astype(x.dtype)
+
+
 def _kernel_per_shard(fn, mesh: Optional[Mesh], impl: str, sp_advice: str,
                       qkv, per_head=()):
     """Run an attention kernel ``fn(q, k, v, *per_head)`` that attends
@@ -647,6 +723,11 @@ def _ffn(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh]):
         return moe_ffn(x, blk["moe"], cfg.n_experts,
                        capacity_factor=cfg.capacity_factor, mesh=mesh,
                        top_k=cfg.moe_top_k)
+    return _dense_ffn(x, blk, cfg), jnp.zeros((), jnp.float32)
+
+
+def _dense_ffn(x, blk, cfg: TransformerConfig):
+    """The dense FFN of kind ``cfg.ffn`` (its width is its matrices')."""
     h = checkpoint_name(
         jnp.einsum("bsd,df->bsf", x, blk["w1"].astype(x.dtype)), "ffn_gate")
     if cfg.ffn == "swiglu":
@@ -657,8 +738,7 @@ def _ffn(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh]):
         h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h)
-    y = jnp.einsum("bsf,fd->bsd", h, blk["w2"].astype(x.dtype))
-    return y, jnp.zeros((), jnp.float32)
+    return jnp.einsum("bsf,fd->bsd", h, blk["w2"].astype(x.dtype))
 
 
 def _refuse_split_mesh(cfg: TransformerConfig, mesh: Optional[Mesh]):
@@ -672,29 +752,36 @@ def _refuse_split_mesh(cfg: TransformerConfig, mesh: Optional[Mesh]):
     if split:
         raise ValueError(
             f"mpi_tpu: layer_pattern={cfg.layer_pattern!r} on a mesh with "
-            f"{', '.join(split)}: the Mamba-2 mixer, the routed share and "
-            f"its dispatch are not split over tp, ep or sp; use dp")
+            f"{', '.join(split)}: the Mamba-2 mixer (M), the short conv "
+            f"(C), attention (*), the dense FFN (F), the routed share (E) "
+            f"and its dispatch are not split over tp, ep or sp; use dp")
 
 
 def _pattern_block(x, blk, cfg: TransformerConfig, mesh: Optional[Mesh],
                    kind: str):
     """Block of kind ``kind`` of a ``layer_pattern`` stack: ``x +
-    f(norm(x))`` with one sub-layer. The mixers (``M``, ``*``) stand under
-    the scope ``attn``, the experts (``E``) under ``ffn``: the mixer and
-    the feed-forward slot of the classic block, so that a trace's layer
-    scopes mean what they meant."""
-    with jax.named_scope("ffn" if kind == "E" else "attn"):
+    f(norm(x))`` with one sub-layer. The mixers (``M``, ``C``, ``*``)
+    stand under the scope ``attn``, the experts (``E``) and the dense FFN
+    (``F``) under ``ffn``: the mixer and the feed-forward slot of the
+    classic block, so that a trace's layer scopes mean what they meant."""
+    with jax.named_scope("ffn" if kind in "EF" else "attn"):
         h = _norm(x, blk["ln1"], cfg)
         if kind == "M":
             from .mamba2 import mamba2_mixer
 
             y = mamba2_mixer(h, blk, cfg, mesh)
+        elif kind == "C":
+            from .short_conv import short_conv_mixer
+
+            y = short_conv_mixer(h, blk)
         elif kind == "E":
             from .moe import routed_share_ffn
 
             y = routed_share_ffn(
                 h, blk, cfg.n_experts, cfg.moe_top_k,
                 offset=cfg.moe_expert_offset, scale=cfg.moe_routed_scale)
+        elif kind == "F":
+            y = _dense_ffn(h, blk, cfg)
         else:
             y = _attention(h, blk, cfg, mesh)
         x = x + y.astype(x.dtype)
@@ -802,7 +889,10 @@ def block_body(x, blk, cfg: TransformerConfig,
 #
 # One constant serves every kind of block, since a name exists only where
 # its value is produced: a classic block holds ``out lse gate``, an ``M``
-# block ``in``, an ``E`` block nothing, a ``*`` block ``out lse``. Choosing
+# block ``in``, an ``E`` block nothing, a ``*`` block ``out lse``, an ``F``
+# block (the classic block's dense FFN) ``gate``, a ``C`` block nothing
+# (its in-projection is named ``shortconv_in``, untried: PR 40 added the
+# kind and tuned nothing). Choosing
 # the set from the compiler's ``memory_analysis`` waits for two cells that
 # want different sets of the same names; at a longer sequence the bytes a
 # token above say what a held value costs.
@@ -940,7 +1030,8 @@ def routed_choices(params: Dict[str, Any], tokens: jax.Array,
     """What the routed layers of a ``layer_pattern`` stack decide for
     ``tokens`` (batch, seq): for every ``E`` block in order, the router's
     input ``(batch * seq, d_model)`` in the compute dtype and the experts
-    it chose ``(batch * seq, moe_top_k)`` among all ``cfg.n_experts``.
+    it chose ``(batch * seq, moe_top_k)`` among all ``cfg.n_experts`` (by
+    the scores plus the selection bias, where the block has one).
     The forward pass of :func:`forward_with_aux`, block by block; for load
     statistics, and for a reference that is to follow the program's
     routing."""
@@ -954,8 +1045,9 @@ def routed_choices(params: Dict[str, Any], tokens: jax.Array,
     for blk, kind in zip(params["blocks"], cfg.layer_pattern):
         if kind == "E":
             h = _norm(x, blk["ln1"], cfg).reshape(-1, cfg.d_model)
-            choices.append((h, route_top_k(h, blk["router"],
-                                           cfg.moe_top_k)[0]))
+            choices.append((h, route_top_k(
+                h, blk["router"], cfg.moe_top_k,
+                bias=blk.get("router_bias"))[0]))
         x, _ = block_body(x, blk, cfg, mesh, kind)
     return choices
 
@@ -1033,6 +1125,17 @@ def init_sharded_params(key: jax.Array, cfg: TransformerConfig,
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         params, sane_param_specs(cfg, params, mesh))
+
+
+def _keep_untrained(cfg: TransformerConfig, new, old):
+    """``new`` parameters with the leaves no step trains as in ``old``:
+    the routers' selection bias (its gradient is zero, but AdamW's decay
+    would still pull it towards zero)."""
+    if cfg.layer_pattern is None or not cfg.moe_router_bias:
+        return new
+    return dict(new, blocks=[
+        dict(n, router_bias=o["router_bias"]) if "router_bias" in o else n
+        for n, o in zip(new["blocks"], old["blocks"])])
 
 
 def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
@@ -1174,7 +1277,8 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             grads = constrain_params(grads, fspecs, mesh)
             with jax.named_scope("optimizer"):
                 updates, new_opt = opt.update(grads, state["opt"], params0)
-                new_params = optax.apply_updates(params0, updates)
+                new_params = _keep_untrained(
+                    cfg, optax.apply_updates(params0, updates), params0)
             new_params = constrain_params(new_params, fspecs, mesh)
             zspecs = zero1_specs(state["params"], fspecs, new_opt, mesh)
             new_opt = constrain_opt_state(new_opt, zspecs, mesh)
@@ -1183,7 +1287,9 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         with jax.named_scope("optimizer"):
             updates, new_opt = opt.update(grads, state["opt"],
                                           state["params"])
-            new_params = optax.apply_updates(state["params"], updates)
+            new_params = _keep_untrained(
+                cfg, optax.apply_updates(state["params"], updates),
+                state["params"])
         if zero1:
             from ..parallel.zero import constrain_opt_state, zero1_specs
 

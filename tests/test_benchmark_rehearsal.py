@@ -32,7 +32,8 @@ def _end_to_end_names(cell: str) -> set:
 @pytest.mark.integration
 @pytest.mark.parametrize("cell", ["osu-allreduce-f32-r4.sweep-4B-64MiB",
                                   "starcoder2-3b-L6.pretrain-4k-b2",
-                                  "nemotron-3-nano-L9-E8.pretrain-8k"])
+                                  "nemotron-3-nano-L9-E8.pretrain-8k",
+                                  "lfm2-24b-a2b-L9-E8.pretrain-8k"])
 def test_rehearsal_prints_the_cells_record(cell, tmp_path):
     assert cell in {w["name"] for w in BENCHMARK["workloads"]}
     # Without what conftest.py puts into the environment for the tests'

@@ -74,6 +74,25 @@ def test_loader_no_prefetch_matches_prefetch():
         np.testing.assert_array_equal(x, y)
 
 
+def test_closing_the_iterator_ends_its_prefetch_thread():
+    # A prefetch thread left inside a jax call at interpreter exit aborts
+    # the process ("FATAL: exception not rethrown").
+    import threading
+    import time
+
+    src = SyntheticLM(64, batch=2, seq=4)
+
+    def slow(step):
+        time.sleep(0.3)
+        return src(step)
+
+    it = iter(ShardedLoader(slow, prefetch=2))
+    next(it)
+    it.close()
+    assert not [t for t in threading.enumerate()
+                if t.name == "mpi-data-prefetch"]
+
+
 def test_loader_propagates_source_errors():
     def bad(step):
         raise RuntimeError("corpus exploded")

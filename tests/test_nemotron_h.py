@@ -268,10 +268,11 @@ def test_a_mesh_that_would_split_a_layer_is_refused_by_name(drawn):
     (dict(layer_pattern="MEM"), "n_layers=9 letters"),
     (dict(ssm_heads=0), "an M layer needs ssm_heads"),
     (dict(ssm_heads=9), "whole groups of ssm_groups=2"),
-    (dict(ffn="swiglu"), "experts of an E layer are relu2"),
+    (dict(ffn="gelu"), "experts of an E layer are relu2 .* or swiglu"),
+    (dict(ffn="swiglu"), "beside swiglu experts moe_shared_d_ff must be 0"),
     (dict(moe_expert_offset=14),
      "moe_expert_offset=14 \\+ moe_experts_held=4"),
-    (dict(moe_shared_d_ff=0), "moe_shared_d_ff"),
+    (dict(moe_shared_d_ff=-1), "moe_shared_d_ff >= 0 \\(got -1"),
     (dict(rope=True), "position_table=False means no position term"),
 ])
 def test_a_pattern_the_stack_cannot_run_is_refused(over, said):

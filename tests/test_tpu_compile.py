@@ -403,6 +403,74 @@ def test_nemotron_cell_train_step_fits_with_room_to_spare(
         f"{CHIP_GIB}")
 
 
+def test_flash_kernels_compile_at_head_dim_64(one_chip):
+    """Causal, default blocks, forward and backward, at the shape the
+    benchmark's ``lfm2-24b-a2b-L9-E8.pretrain-8k`` cell calls the kernels
+    with (batch 2 x 8,192, 32 query heads on 8 kv heads of 64): the
+    ``(1, block, 64)`` blocks take the arrays' whole last dimension."""
+    from mpi_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, None, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+             names=FLASH_KERNELS)
+
+
+# What the compiler counts for the lfm2-24b-a2b cell's step: 12.162 GiB at
+# batch 2 with ``remat`` on (12.412 with the head tied; PR 40), so the cut
+# is ``layer_types[1:10]`` and not ISSUE 40's fallback (over 15.0).
+LFM2_STEP_GIB = 15.25
+
+
+def test_lfm2_cell_train_step_fits_with_room_to_spare(topo, compiled_kernels):
+    """The whole train step of the benchmark's
+    ``lfm2-24b-a2b-L9-E8.pretrain-8k`` cell (its ``model`` as the
+    configuration file has it: the pattern ``CF*ECECECE*ECECECE`` at
+    published widths, heads of 64 through the flash kernels, ``remat`` on;
+    its traffic's batch of sequences of 8,192 and their targets): it
+    compiles for one chip, with the flash kernels and the scopes a trace
+    splits it by and no shared expert's, and the compiler's count of its
+    memory leaves at least 0.5 GiB of the chip's 15.75."""
+    from mpi_tpu.models import TransformerConfig, make_mesh_nd
+
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark")
+    with open(os.path.join(bench, "configs",
+                           "lfm2-24b-a2b-L9-E8.json")) as f:
+        model = json.load(f)["model"]
+    with open(os.path.join(bench, "traffic", "pretrain-8k.json")) as f:
+        traffic = json.load(f)
+    batch, seq = traffic["batch"], traffic["seq"]
+    cfg = TransformerConfig(**dict(model, dtype=jnp.dtype(model["dtype"]),
+                                   max_seq=seq + 1))
+    assert cfg.remat and cfg.head_dim == 64
+    mesh = make_mesh_nd(1, devices=topo.devices[:1])
+    compiled = _compile(
+        *_step_args(cfg, mesh, batch, seq),
+        names=FLASH_KERNELS + LAYER_SCOPES + (
+            "shortconv.in_proj", "shortconv.conv", "shortconv.out_proj",
+            "moe.route", "moe.routed"))
+    text = compiled.as_text()
+    assert "moe.shared" not in text
+    # ``attn_out`` / ``attn_lse`` are held: one forward kernel a layer.
+    calls = len(re.findall(r"%flash_fwd(\.\d+)? = ", text))
+    assert calls == cfg.layer_pattern.count("*"), calls
+    mem = compiled.memory_analysis()
+    gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+           + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    print(f"lfm2 cell step: the compiler counts {gib:.3f} GiB")
+    assert LFM2_STEP_GIB <= CHIP_GIB - 0.5
+    assert gib < LFM2_STEP_GIB, (
+        f"the compiler counts {gib:.3f} GiB for the step at batch {batch}, "
+        f"over the {LFM2_STEP_GIB} GiB that leave 0.5 of the chip's "
+        f"{CHIP_GIB}")
+
+
 @pytest.fixture(scope="module")
 def ring_mesh(topo):
     return Mesh(np.asarray(topo.devices), ("rank",))
