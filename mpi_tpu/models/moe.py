@@ -19,7 +19,10 @@ no (token, expert) pair whatever the load, and adds a shared expert where
 it has one. The pairs that land on held experts are sorted by expert and
 laid out in tiles of rows that belong to one expert each; the expert
 products are two (relu²) or three (SwiGLU) matmuls a tile, in one loop
-over the occupied tiles.
+over the occupied tiles. A tile writes its rows to its own contiguous
+rows of that layout, and after the loop a gather returns them, weighted,
+to their tokens (a short loop adds a token's later held pairs); the
+backward loop does the same with the rows of the input's gradient.
 """
 
 from __future__ import annotations
@@ -233,26 +236,57 @@ def route_top_k(x2: jax.Array, router: jax.Array, top_k: int,
     return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
 
 
-def _tile_layout(order, sizes, top_k: int):
-    """The sorted pairs ``order`` laid out with each held expert's
-    ``sizes[e]`` rows padded to whole tiles. Returns the occupied tiles
-    and ``rows_of(t) -> (e, pair, token, live)`` for tile ``t``: its
-    expert, and a row's pair, token and whether the row holds a pair (a
-    tile past the occupied ones holds none)."""
-    held = sizes.shape[0]
+def _layout_rows(tokens: int, top_k: int, held: int, floor: int) -> int:
+    """Rows of the tile layout: every tile the loop can run, so no pair is
+    dropped whatever the load (a token puts at most ``min(top_k, held)``
+    pairs on the share, and each expert pads its last tile)."""
+    return max(floor, -(-tokens * min(top_k, held) // _TILE) + held) * _TILE
+
+
+def _tile_layout(key, order, held: int, top_k: int, rows: int):
+    """The pairs laid out in tiles of one held expert each: ``key`` a
+    pair's place in the share (``held`` for an absent expert), ``order``
+    the pairs sorted stably by it. Returns the occupied tiles, ``rows_of(t)
+    -> (e, pair, token, live)`` for tile ``t`` (its expert, and a row's
+    pair, token and whether the row holds a pair: a tile past the occupied
+    ones holds none) and ``slot`` ``(pairs,)``: the row of the layout,
+    ``t * _TILE + i``, that holds a pair, or ``rows`` for a pair on an
+    absent expert."""
+    hits = key[:, None] == jnp.arange(held)[None, :]
+    sizes = hits.sum(0, dtype=jnp.int32)
     first = jnp.cumsum(sizes) - sizes              # in ``order``
     tiles = -(-sizes // _TILE)                     # whole tiles an expert
     tile_end = jnp.cumsum(tiles)
 
     def rows_of(t):
-        e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
-                        held - 1)
+        e = jnp.minimum(jnp.sum(tile_end <= t), held - 1)   # tiles ended
         within = (t - (tile_end[e] - tiles[e])) * _TILE + jnp.arange(_TILE)
         live = (t < tile_end[-1]) & (within < sizes[e])
         pair = order[jnp.clip(first[e] + within, 0, order.size - 1)]
         return e, pair, pair // top_k, live
 
-    return tile_end[-1], rows_of
+    # A pair's row: its expert's first row plus its rank among the
+    # expert's pairs, which the stable sort keeps in pair order (the count
+    # of the same key before it). Masked sums, not gathers by ``key``: a
+    # gather of 8 values at every pair is many ops once compiled.
+    start = (tile_end - tiles) * _TILE - 1
+    slot = jnp.sum(jnp.where(hits, start + jnp.cumsum(hits, 0,
+                                                      dtype=jnp.int32), 0),
+                   -1)
+    return tile_end[-1], rows_of, jnp.where(key < held, slot, rows)
+
+
+def _layout_buffer(rows: int, row_shape, dtype, after):
+    """The layout's ``rows + 1`` rows of ``row_shape``; the last, the one
+    an absent pair's slot names, is zero. The loop writes every row a slot
+    names before it is read, so the rest is left as it is allocated. The
+    zero row waits for ``after``, the layer's own input: a buffer that
+    depends on nothing could be allocated at the start of the step, every
+    layer's at once."""
+    zero, _ = lax.optimization_barrier(
+        (jnp.zeros((1, *row_shape), dtype), after))
+    return lax.dynamic_update_slice_in_dim(
+        lax.empty((rows + 1, *row_shape), dtype), zero, rows, 0)
 
 
 def _expert_rows(x, experts, e):
@@ -267,46 +301,90 @@ def _expert_rows(x, experts, e):
                    w_down[e])
 
 
+def _combine(buf, slot, weight, out_dtype):
+    """``y[tok] = sum_k weight[tok, k] buf[slot[tok, k]]`` in float32, cast
+    to ``out_dtype``: ``buf`` the layout's rows, its last row zero;
+    ``slot`` and ``weight`` ``(tokens, top_k)``, a slot naming the zero
+    row where the pair's expert is absent.
+
+    A row gather costs about the same for any row (about 50 ns a row on a
+    TPU v5e), the zero one too, and most slots name it. So one gather takes
+    each token's first pair on a held expert (the zero row where it has
+    none), and the token's later held pairs, few, are added in tiles of
+    ``_TILE`` by a loop whose trip count is read from their number. With
+    tracing on, each combine built adds 1 to ``moe.combine.gathers`` (at
+    trace time)."""
+    trace.count("moe.combine.gathers")
+    top_k = slot.shape[1]
+    held = slot != buf.shape[0] - 1
+    first = jnp.arange(top_k) == jnp.argmax(held, axis=1)[:, None]
+    y = (buf[jnp.sum(jnp.where(first, slot, 0), 1)]
+         * jnp.sum(jnp.where(first, weight, 0), 1, keepdims=True))
+    later = (held & ~first).reshape(-1)
+    # The n-th later pair is the one at which the running count passes n:
+    # counted by compares, since a search loop in the loop compiles slowly.
+    seen = jnp.cumsum(later, dtype=jnp.int32)
+    step = min(_TILE, later.size)
+
+    def body(i, y):
+        nth = i * step + jnp.arange(step)
+        pair = jnp.minimum(jnp.sum(seen <= nth[:, None], 1), later.size - 1)
+        row_weight = jnp.where(nth < seen[-1], weight.reshape(-1)[pair], 0)
+        return y.at[pair // top_k].add(
+            buf[slot.reshape(-1)[pair]] * row_weight[:, None])
+
+    return lax.fori_loop(0, -(-seen[-1] // step), body, y).astype(out_dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _expert_tiles(x2, experts, pair_weight, order, sizes, top_k, floor):
+def _expert_tiles(x2, experts, pair_weight, key, order, top_k, floor):
     """``sum over a token's pairs of weight x expert_e(x)``
     (:func:`_expert_rows`) for the pairs on held experts: ``x2`` ``(tokens,
     d)``, ``experts`` the held experts' matrices, ``pair_weight``
-    ``(tokens * top_k,)``, ``order`` the pairs sorted by held expert and
-    ``sizes`` the pairs of each; returns ``(tokens, d)`` in ``x2``'s dtype.
+    ``(tokens * top_k,)``, ``key`` a pair's held expert (``held`` where it
+    is absent) and ``order`` the pairs sorted by it; returns ``(tokens,
+    d)`` in ``x2``'s dtype.
 
     ONE loop over the tiles, with a trip count read from the load: a
     tile's rows are gathered, go through their expert's two or three
-    matmuls, and are added to their tokens' rows, so the work follows
-    the pairs and nothing holds a buffer. The loop runs at least ``floor``
-    tiles (the tiles past the occupied ones hold no pair and add
-    nothing). The backward pass is the same loop again: it recomputes a
-    tile's hidden rows and adds its weight gradients into float32 sums in
-    place."""
+    matmuls, and are written as they come, unweighted, to the tile's own
+    rows of the layout (:func:`_tile_layout`), so the work follows the
+    pairs. The loop runs at least ``floor`` tiles (the tiles past the
+    occupied ones hold no pair, and no slot names their rows). After it
+    the rows go back to their tokens, weighted and summed in float32
+    (:func:`_combine`). The backward pass is the same loop again: it
+    recomputes a tile's hidden rows, adds its weight gradients into
+    float32 sums in place, and writes its rows of ``d x`` and of the pair
+    weights' gradient to its slots, which gathers after the loop return
+    to the tokens and pairs."""
+    return _expert_tiles_fwd(x2, experts, pair_weight, key, order, top_k,
+                             floor)[0]
+
+
+def _expert_tiles_fwd(x2, experts, pair_weight, key, order, top_k, floor):
     tokens, d = x2.shape
-    occupied, rows_of = _tile_layout(order, sizes, top_k)
+    held = experts[0].shape[0]
+    rows = _layout_rows(tokens, top_k, held, floor)
+    occupied, rows_of, slot = _tile_layout(key, order, held, top_k, rows)
 
-    def body(t, acc):
-        e, pair, token, live = rows_of(t)
-        out = _expert_rows(x2[token], experts, e)
-        weight = jnp.where(live, pair_weight[pair], 0)
-        return acc.at[token].add(out.astype(jnp.float32) * weight[:, None])
+    def body(t, buf):
+        e, _, token, _ = rows_of(t)
+        return lax.dynamic_update_slice_in_dim(
+            buf, _expert_rows(x2[token], experts, e), t * _TILE, 0)
 
-    return lax.fori_loop(0, jnp.maximum(occupied, floor), body,
-                         jnp.zeros((tokens, d), jnp.float32)
-                         ).astype(x2.dtype)
-
-
-def _expert_tiles_fwd(x2, experts, pair_weight, order, sizes, top_k, floor):
-    return (_expert_tiles(x2, experts, pair_weight, order, sizes, top_k,
-                          floor),
-            (x2, experts, pair_weight, order, sizes))
+    buf = lax.fori_loop(0, jnp.maximum(occupied, floor), body,
+                        _layout_buffer(rows, (d,), x2.dtype, x2))
+    y = _combine(buf, slot.reshape(tokens, top_k),
+                 pair_weight.reshape(tokens, top_k), x2.dtype)
+    return y, (x2, experts, pair_weight, key, order, slot)
 
 
 def _expert_tiles_bwd(top_k, floor, held_back, g):
-    x2, experts, pair_weight, order, sizes = held_back
+    x2, experts, pair_weight, key, order, slot = held_back
     f32 = jnp.float32
-    occupied, rows_of = _tile_layout(order, sizes, top_k)
+    (tokens, d), held = x2.shape, experts[0].shape[0]
+    rows = _layout_rows(tokens, top_k, held, floor)
+    occupied, rows_of, _ = _tile_layout(key, order, held, top_k, rows)
 
     def relu2_body(t, sums):
         d_x, (d_up, d_down), d_weight = sums
@@ -316,13 +394,15 @@ def _expert_tiles_bwd(top_k, floor, held_back, g):
         r = jax.nn.relu(jnp.dot(x, w_up[e]))
         r2 = r * r
         back = jnp.dot(g_t, w_down[e].T)            # d out / d hidden
-        d_weight = d_weight.at[pair].add(jnp.where(
-            live, (r2.astype(f32) * back.astype(f32)).sum(-1), 0))
+        d_weight = lax.dynamic_update_slice_in_dim(
+            d_weight, (r2.astype(f32) * back.astype(f32)).sum(-1),
+            t * _TILE, 0)
         d_down = d_down.at[e].add(jnp.dot(
             (r2 * weight.astype(r2.dtype)).T, g_t, preferred_element_type=f32))
         d_pre = back * (2 * r) * weight.astype(r.dtype)
         d_up = d_up.at[e].add(jnp.dot(x.T, d_pre, preferred_element_type=f32))
-        d_x = d_x.at[token].add(jnp.dot(d_pre, w_up[e].T).astype(f32))
+        d_x = lax.dynamic_update_slice_in_dim(
+            d_x, jnp.dot(d_pre, w_up[e].T).astype(f32), t * _TILE, 0)
         return d_x, (d_up, d_down), d_weight
 
     def swiglu_body(t, sums):
@@ -334,8 +414,9 @@ def _expert_tiles_bwd(top_k, floor, held_back, g):
         a, u = jnp.dot(x, w_gate[e]), jnp.dot(x, w_up[e])
         hidden = jax.nn.silu(a) * u
         back = jnp.dot(g_t, w_down[e].T)            # d out / d hidden
-        d_weight = d_weight.at[pair].add(jnp.where(
-            live, (hidden.astype(f32) * back.astype(f32)).sum(-1), 0))
+        d_weight = lax.dynamic_update_slice_in_dim(
+            d_weight, (hidden.astype(f32) * back.astype(f32)).sum(-1),
+            t * _TILE, 0)
         d_down = d_down.at[e].add(jnp.dot(
             (hidden * weight.astype(hidden.dtype)).T, g_t,
             preferred_element_type=f32))
@@ -347,20 +428,23 @@ def _expert_tiles_bwd(top_k, floor, held_back, g):
         d_up = d_up.at[e].add(jnp.dot(x.T, d_u, preferred_element_type=f32))
         d_gate = d_gate.at[e].add(
             jnp.dot(x.T, d_a, preferred_element_type=f32))
-        d_x = d_x.at[token].add(
-            jnp.dot(d_u, w_up[e].T, preferred_element_type=f32)
-            + jnp.dot(d_a, w_gate[e].T, preferred_element_type=f32))
+        d_x = lax.dynamic_update_slice_in_dim(
+            d_x, jnp.dot(d_u, w_up[e].T, preferred_element_type=f32)
+            + jnp.dot(d_a, w_gate[e].T, preferred_element_type=f32),
+            t * _TILE, 0)
         return d_x, (d_up, d_down, d_gate), d_weight
 
-    d_x, d_experts, d_weight = lax.fori_loop(
+    d_rows, d_experts, d_slots = lax.fori_loop(
         0, jnp.maximum(occupied, floor),
         relu2_body if len(experts) == 2 else swiglu_body,
-        (jnp.zeros(x2.shape, f32),
+        (_layout_buffer(rows, (d,), f32, g),
          tuple(jnp.zeros(w.shape, f32) for w in experts),
-         jnp.zeros(pair_weight.shape, f32)))
-    return (d_x.astype(x2.dtype),
+         _layout_buffer(rows, (), f32, g)))
+    d_x = _combine(d_rows, slot.reshape(tokens, top_k),
+                   jnp.ones((tokens, top_k), f32), x2.dtype)
+    return (d_x,
             tuple(d.astype(w.dtype) for d, w in zip(d_experts, experts)),
-            d_weight.astype(pair_weight.dtype), None, None)
+            d_slots[slot].astype(pair_weight.dtype), None, None)
 
 
 _expert_tiles.defvjp(_expert_tiles_fwd, _expert_tiles_bwd)
@@ -400,10 +484,14 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
     pairs are sorted by expert (those of absent experts last) and laid out
     with each expert's rows padded to whole tiles of ``_TILE``, so that a
     tile's rows share one expert, and one loop runs over the occupied
-    tiles (:func:`_expert_tiles`; at least :func:`floor_tiles` of them).
-    Scopes ``moe.route``, ``moe.routed`` (sort, dispatch, expert products,
-    combine) and ``moe.shared`` (none without a shared expert); with tracing on each call adds 1 to
-    ``moe.layers`` (at trace time)."""
+    tiles (:func:`_expert_tiles`; at least :func:`floor_tiles` of them),
+    writing each tile's rows to its own place in the layout; after the
+    loop they go back, weighted, to their tokens (:func:`_combine`). Scopes
+    ``moe.route``, ``moe.routed`` (sort, dispatch, expert products, the
+    gather that combines) and ``moe.shared`` (none without a shared
+    expert); with tracing on each call adds 1 to ``moe.layers`` and each
+    combine built, forward or backward, 1 to ``moe.combine.gathers`` (at
+    trace time)."""
     b, s, d = x.shape
     tokens, held = b * s, params["w_up"].shape[0]
     if not 1 <= top_k <= n_experts:
@@ -429,12 +517,10 @@ def routed_share_ffn(x: jax.Array, params: Dict[str, Any], n_experts: int,
 
     with jax.named_scope("moe.routed"):
         order = jnp.argsort(key, stable=True)
-        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
-            0, dtype=jnp.int32)
         experts = tuple(params[name].astype(x.dtype) for name in (
             "w_up", "w_down", "w_gate") if name in params)
-        routed = _expert_tiles(x2, experts, weight.reshape(-1), order,
-                               sizes, top_k, floor)
+        routed = _expert_tiles(x2, experts, weight.reshape(-1), key, order,
+                               top_k, floor)
     if "shared_up" not in params:
         return routed.reshape(b, s, d)
 

@@ -345,7 +345,8 @@ def test_counters_hold_their_counts(drawn, traced):
     traced.disable()
     assert counted() == {}
     traced.enable()
-    assert counted() == {"shortconv.layers": 3, "moe.layers": 3}
+    assert counted() == {"shortconv.layers": 3, "moe.layers": 3,
+                         "moe.combine.gathers": 3}
 
 
 def test_scopes_are_in_the_lowered_text(drawn):

@@ -334,11 +334,24 @@ def test_ssd_kernels_run_on_each_chips_rows_of_a_dp_batch(topo):
                    for call in calls), calls[0][:400]
 
 
-# What the compiler counts for the nemotron-3-nano cell's step: 11.509 GiB
-# at batch 2 with the in-projection's product held under ``remat`` (PR 39;
-# 10.561 with nothing of an ``M`` block held, PR 37; 12.010 with the scan
-# as an XLA program, PR 36; 13.349 with every value an ``M`` or ``E`` block
-# names held, the table above ``_REMAT_KEEPS``).
+def _layout_of_the_routed_loops(cfg, batch, seq):
+    """The shape the routed share's backward loop writes its rows of the
+    input's gradient to: the tile layout's rows and the zero row, float32
+    (``models/moe.py`` ``_layout_rows``)."""
+    from mpi_tpu.models import moe
+
+    tokens = batch * seq
+    floor = moe.floor_tiles(tokens, cfg.moe_top_k, cfg.moe_experts_held,
+                            cfg.n_experts)
+    rows = moe._layout_rows(tokens, cfg.moe_top_k, cfg.moe_experts_held,
+                            floor)
+    return f"f32[{rows + 1},{cfg.d_model}]"
+
+
+# What the compiler counts for the nemotron-3-nano cell's step: 11.783 GiB
+# at batch 2 with the in-projection's product held under ``remat`` and the
+# routed share's tile layouts (13.349 with every value an ``M`` or ``E``
+# block names held, the table above ``_REMAT_KEEPS``).
 NEMOTRON_STEP_GIB = 15.25
 
 
@@ -393,9 +406,11 @@ def test_nemotron_cell_train_step_fits_with_room_to_spare(
     assert any("/jvp(" in op for op in products)
     redone = [op for op in products if "rematted_computation" in op]
     assert not redone, redone[0][:300]
+    assert _layout_of_the_routed_loops(cfg, batch, seq) in text
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    print(f"nemotron cell step: the compiler counts {gib:.3f} GiB")
     assert NEMOTRON_STEP_GIB <= CHIP_GIB - 0.5
     assert gib < NEMOTRON_STEP_GIB, (
         f"the compiler counts {gib:.3f} GiB for the step at batch {batch}, "
@@ -422,9 +437,9 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
              names=FLASH_KERNELS)
 
 
-# What the compiler counts for the lfm2-24b-a2b cell's step: 12.162 GiB at
-# batch 2 with ``remat`` on (12.412 with the head tied; PR 40), so the cut
-# is ``layer_types[1:10]`` and not ISSUE 40's fallback (over 15.0).
+# What the compiler counts for the lfm2-24b-a2b cell's step: 12.448 GiB at
+# batch 2 with ``remat`` on and the routed share's tile layouts, so the cut
+# is ``layer_types[1:10]`` and not a shorter stack.
 LFM2_STEP_GIB = 15.25
 
 
@@ -460,6 +475,7 @@ def test_lfm2_cell_train_step_fits_with_room_to_spare(topo, compiled_kernels):
     # ``attn_out`` / ``attn_lse`` are held: one forward kernel a layer.
     calls = len(re.findall(r"%flash_fwd(\.\d+)? = ", text))
     assert calls == cfg.layer_pattern.count("*"), calls
+    assert _layout_of_the_routed_loops(cfg, batch, seq) in text
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
