@@ -1138,6 +1138,75 @@ def _keep_untrained(cfg: TransformerConfig, new, old):
         for n, o in zip(new["blocks"], old["blocks"])])
 
 
+def separates_update(tokens: int, side_in: int, side_out: int) -> bool:
+    """Whether a weight gradient's product, contracted over ``tokens``
+    between its ``side_in`` and ``side_out`` sides, should be written out
+    before its AdamW update instead of having the update fused into it.
+
+    The TPU v5e compiler fuses AdamW's update into the product that makes
+    a weight's gradient. Below a boundary that fusion costs the plain
+    product plus a little and hides the optimizer state's 24 B a
+    parameter under the product's compute; above it the fused op runs
+    well past the product and an AdamW pass of its own. The boundary is
+    a size of the product's smaller operand strip, ``tokens x
+    min(side_in, side_out)`` in bfloat16: between 112 MiB, where the
+    fusion still wins, and 120 MiB, where it no longer does, on a TPU v5e
+    (one leaf and its update alone, ms; PERF.md's Findings have all 24
+    shapes measured):
+
+    ======  ==============  =====  =====  =========
+    tokens  leaf            strip  fused  separated
+    ======  ==============  =====  =====  =========
+    16384   [4096, 11008]   128     11.43     10.26
+    16384   [11008, 4096]   128     11.21     10.25
+    16384   [4096, 4096]    128      4.28      3.67
+    16384   [3840, 12288]   120     12.06     10.39
+    16384   [3584, 12288]   112      8.25      9.49
+    16384   [2688, 16384]    84      8.22      9.54
+    16384   [2048, 11776]    64      4.59      5.28
+    8192    [3072, 12288]    48      3.71      4.87
+    ======  ==============  =====  =====  =========
+
+    One shape under the boundary ran faster separated, 8192 x [12288,
+    3072] (6.32 against 4.91 ms), where its transpose did not; the rule
+    leaves it fused until a step shows the same (PERF.md, Open questions).
+    """
+    return tokens * min(side_in, side_out) * 2 >= 120 * 2 ** 20
+
+
+# A block's dense weights, each by the number of its leading axes that
+# make up one side of its gradient's product (``wo`` ``(h, hd, d)``: h x hd
+# against d). Routed expert stacks ``(E, d, f)`` are not among them: their
+# gradient is a tile loop's sum, not one product over the tokens.
+_DENSE_SIDES = {"w1": 1, "w2": 1, "w3": 1, "wq": 1, "wk": 1, "wv": 1,
+                "wo": 2}
+
+
+def separated_leaves(cfg: TransformerConfig, grads, tokens: int,
+                     mesh: Optional[Mesh] = None) -> List[Tuple[int, str]]:
+    """``(block, name)`` of each dense block leaf of ``grads`` (arrays or
+    shapes) whose update :func:`separates_update` separates from its
+    product at ``tokens`` a microbatch. On a mesh the tokens and the
+    leaf's sides are what one device's product sees: a ``dp`` x ``sp``
+    shard of the tokens, the leaf's shard by ``sane_param_specs``."""
+    if mesh is not None:
+        tokens //= mesh.shape.get("dp", 1) * mesh.shape.get("sp", 1)
+        specs = sane_param_specs(cfg, grads, mesh)
+    picked = []
+    for i, blk in enumerate(grads["blocks"]):
+        for name, lead in _DENSE_SIDES.items():
+            if name not in blk:
+                continue
+            shape = blk[name].shape
+            if mesh is not None:
+                shape = NamedSharding(
+                    mesh, specs["blocks"][i][name]).shard_shape(shape)
+            if separates_update(tokens, math.prod(shape[:lead]),
+                                math.prod(shape[lead:])):
+                picked.append((i, name))
+    return picked
+
+
 def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                      learning_rate: float = 1e-3, grad_accum: int = 1,
                      optimizer: str = "adamw", warmup_steps: int = 0,
@@ -1261,6 +1330,21 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         inv = 1.0 / grad_accum
         return loss_sum * inv, jax.tree.map(lambda g: g * inv, gsum)
 
+    def separated(grads, tokens):
+        """``grads`` with each leaf :func:`separated_leaves` picks, at the
+        tokens a microbatch contracts over, behind an optimization barrier
+        of its own (an identity): the compiler then writes that gradient
+        as a plain product and runs its AdamW update as a pass of its own.
+        One barrier on the whole tree would keep every gradient alive at
+        once. Each picked leaf adds 1 to ``optimizer.separated`` at trace
+        time."""
+        micro = tokens.shape[0] * (tokens.shape[1] - 1) // grad_accum
+        blocks = [dict(blk) for blk in grads["blocks"]]
+        for i, name in separated_leaves(cfg, grads, micro, mesh):
+            blocks[i][name] = lax.optimization_barrier(blocks[i][name])
+            trace.count("optimizer.separated")
+        return dict(grads, blocks=blocks)
+
     def step(state, tokens):
         if fsdp:
             from ..parallel.zero import (constrain_opt_state,
@@ -1274,7 +1358,7 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             fspecs = _fsdp_specs(state["params"])
             params0 = constrain_params(state["params"], fspecs, mesh)
             loss, grads = accumulate(params0, tokens)
-            grads = constrain_params(grads, fspecs, mesh)
+            grads = separated(constrain_params(grads, fspecs, mesh), tokens)
             with jax.named_scope("optimizer"):
                 updates, new_opt = opt.update(grads, state["opt"], params0)
                 new_params = _keep_untrained(
@@ -1284,6 +1368,7 @@ def make_train_parts(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             new_opt = constrain_opt_state(new_opt, zspecs, mesh)
             return {"params": new_params, "opt": new_opt}, loss
         loss, grads = accumulate(state["params"], tokens)
+        grads = separated(grads, tokens)
         with jax.named_scope("optimizer"):
             updates, new_opt = opt.update(grads, state["opt"],
                                           state["params"])

@@ -18,6 +18,7 @@ the test's own process, and all such tests live in this ONE file so a
 single worker owns the library.
 """
 
+import collections
 import contextlib
 import json
 import os
@@ -212,10 +213,34 @@ def test_eva_attention_compiles_at_evabyte_shapes(one_chip):
                  "/eva.merge/"))
 
 
-# What the compiler may count for the EvaByte cell's step: 14.761 GiB with
-# what ``_REMAT_KEEPS`` holds today (13.741 with nothing held).
+# What the compiler may count for the EvaByte cell's step: 14.723 GiB with
+# what ``_REMAT_KEEPS`` holds today and its 28 dense leaves' updates each a
+# pass of its own.
 EVABYTE_STEP_GIB = 15.0
 CHIP_GIB = 15.75
+
+# A fusion that writes three float32 arrays of one shape: an update's new
+# parameter, ``mu`` and ``nu``; ``calls=`` names its fused computation.
+_STATE_TRIPLE = re.compile(
+    r"= \((f32\[[\d,]*\])\{[^}]*\}, \1\{[^}]*\}, \1\{[^}]*\}\) "
+    r"fusion\(.*calls=%([\w.\-]+)")
+
+
+def _state_triples(text):
+    """``{(shape, holds a product): fusions}`` over the compiled text's
+    fusions that write a parameter and its AdamW state."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            bodies[name.lstrip("%")] = []
+        elif name and line.startswith(" "):
+            bodies[name.lstrip("%")].append(line)
+    found = collections.Counter()
+    for m in _STATE_TRIPLE.finditer(text):
+        body = "\n".join(bodies.get(m.group(2), ()))
+        found[m.group(1), "convolution(" in body] += 1
+    return found
 
 
 def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
@@ -225,7 +250,9 @@ def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
     sequence of 16,384 bytes and its target): it compiles, which a program
     too large for the chip does not; what a block keeps under ``remat``
     (``models/transformer.py`` ``_REMAT_KEEPS``) leaves each forward kernel
-    one call a layer; and the compiler's count of its memory stays under
+    one call a layer; no fusion both makes one of the 28 dense leaves'
+    weight gradients and writes its AdamW state (``separates_update``); and
+    the compiler's count of its memory stays under
     ``EVABYTE_STEP_GIB``, so that a later change's extra temporary cannot
     push the cell out of the chip's memory unseen."""
     from mpi_tpu.models import TransformerConfig, make_mesh_nd
@@ -245,6 +272,14 @@ def test_evabyte_cell_train_step_fits_and_runs_forward_kernels_once(
         calls = len(re.findall(rf"%{kernel}(\.\d+)? = ", text))
         assert calls == cfg.n_layers, \
             f"{calls} %{kernel} calls for {cfg.n_layers} layers"
+    # Each dense leaf's weight gradient is a product of its own, and its
+    # AdamW update a pass that holds none (``separates_update``): 8 FFN
+    # matrices of [4096, 11008], 4 of [11008, 4096], 12 q/k/v and 4 wo.
+    triples = _state_triples(text)
+    dense = {"f32[4096,11008]": 8, "f32[11008,4096]": 4,
+             "f32[4096,32,128]": 12, "f32[32,128,4096]": 4}
+    assert {s: triples[s, False] for s in dense} == dense, triples
+    assert not any(triples[s, True] for s in dense), triples
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
