@@ -15,9 +15,11 @@ causal), in increasing tpu-nativeness:
     through VMEM, MXU matmuls with float32 accumulation, running
     (m, l, acc) online-softmax state in VMEM scratch across the key-block
     grid dimension. Backward is the FlashAttention-2 scheme in Pallas
-    too: the forward saves only the log-sum-exp rows, and two kernels
-    (dq over key blocks; dk/dv over query blocks) rebuild the
-    probabilities on the fly — no O(s²) residuals.
+    too: the forward saves only the log-sum-exp rows, and one kernel
+    rebuilds each grid cell's probabilities once and accumulates dq,
+    dk and dv from them — no O(s²) residuals. Where its whole-length
+    accumulators would not fit VMEM, two kernels (dq over key blocks;
+    dk/dv over query blocks) do it instead.
 
 All take ``q, k, v`` shaped ``(batch, seq, heads, head_dim)`` — the layout
 :mod:`mpi_tpu.models.transformer` uses — and return the same shape. The
@@ -148,9 +150,11 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # --------------------------------------------------------------------------
 
 def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
-                prefix=None):
+                prefix=None, keys_first: bool = False):
     """The (block_q, block_k) validity mask for a *crossed* grid cell
-    (qi, ki) (:func:`_cell_kind`; the other kinds never build it).
+    (qi, ki) (:func:`_cell_kind`; the other kinds never build it);
+    ``keys_first`` gives its transpose, (block_k, block_q), for scores
+    built as k·qᵀ.
 
     ``prefix=(q_per, k_per)`` is the window-level mask EVA's summaries
     need (:mod:`mpi_tpu.ops.eva_attention`): rows come in groups of
@@ -160,12 +164,12 @@ def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
     rows share one group and the test is one compare with a scalar.
     No ``col < seq_k`` term: the blocks divide the lengths
     (:func:`_blocks`), so no column lies past the keys."""
-    col = ki * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    shape, q_axis = ((block_k, block_q), 1) if keys_first else (
+        (block_q, block_k), 0)
+    col = ki * block_k + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     valid = None
     if causal:
-        row = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+        row = qi * block_q + lax.broadcasted_iota(jnp.int32, shape, q_axis)
         valid = row >= col
     if prefix is not None:
         before = col < _prefix_end(qi, block_q, prefix)
@@ -220,10 +224,10 @@ def _resident_ki(qi, ki, block_q: int, block_k: int, causal: bool, prefix):
 
 def _resident_qi(qi, ki, nq: int, block_q: int, block_k: int, causal: bool,
                  prefix):
-    """The same for the dk/dv kernel, which walks a column of cells: the
-    query block its q, dO, lse and delta maps name is the cell's own
-    when live, else the column's first live one (the last block in a
-    column with none)."""
+    """The same for the backward kernels that walk a column of cells
+    (the fused one and the split dk/dv): the query block their q, dO,
+    lse and delta maps name is the cell's own when live, else the
+    column's first live one (the last block in a column with none)."""
     if causal:
         qi = jnp.maximum(qi, jnp.minimum(ki * block_k // block_q, nq - 1))
     if prefix is not None:
@@ -258,10 +262,11 @@ def _count_cells(heads: int, nq: int, nk: int, block_q: int, block_k: int,
 
 
 def _for_cell_kind(qi, ki, block_q: int, block_k: int, causal: bool, prefix,
-                   compute):
+                   compute, keys_first: bool = False):
     """Emit ``compute(mask)`` once for each kind of cell that does work
     (:func:`_cell_kind`): for whole cells with ``mask=None`` (no iota,
-    no compare, no ``where``), for crossed cells with the block's mask.
+    no compare, no ``where``), for crossed cells with the block's mask
+    (transposed with ``keys_first``, :func:`_block_mask`).
     Dead cells run nothing: that saves their MXU matmuls, their index
     maps save the fetch. Without a mask there is one kind and no branch."""
     if not causal and prefix is None:
@@ -269,8 +274,8 @@ def _for_cell_kind(qi, ki, block_q: int, block_k: int, causal: bool, prefix,
         return
     live, whole = _cell_kind(qi, ki, block_q, block_k, causal, prefix)
     pl.when(whole)(lambda: compute(None))
-    pl.when(live & ~whole)(lambda: compute(
-        _block_mask(qi, ki, block_q, block_k, causal, prefix)))
+    pl.when(live & ~whole)(lambda: compute(_block_mask(
+        qi, ki, block_q, block_k, causal, prefix, keys_first)))
 
 
 def _block_probs(q_ref, k_ref, lse_ref, mask, scale: float):
@@ -414,6 +419,74 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dqt_scr, dk_scr, dv_scr, *,
+                      causal: bool, scale: float, block_q: int, block_k: int,
+                      prefix=None):
+    """dq, dk and dv from ONE rebuild of each live cell's P, dP and ds.
+    Grid (b·kv_heads, G, nk, nq): query head g of the kv head's group,
+    then key blocks, then query blocks. dk/dv accumulate in scratch of
+    the whole key length over every (g, qi) and are written in the last
+    g; dqᵀ accumulates in scratch of the whole query length, one
+    (d, block_q) slab a query block, over ki, and a block is written at
+    the last ki. The order of every sum is the two split kernels' (dq by
+    ki; dk/dv g-major, then by qi).
+
+    The cell is built keys-first, Pᵀ = exp(k·qᵀ − lse): the (1, block_q)
+    lse and delta rows broadcast as they are stored, Pᵀ·dO and dsᵀ·Q
+    need no transpose of a (block_q, block_k) tile, and dqᵀ += kᵀ·dsᵀ
+    transposes the (block_k, d) k block in its place. dqᵀ's lanes are
+    query rows, so a head_dim of 64 fills them."""
+    gi, ki, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ng, nk, nq = pl.num_programs(1), pl.num_programs(2), pl.num_programs(3)
+    k_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+
+    @pl.when((gi == 0) & (ki == 0) & (qi == 0))
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _():
+        dqt_scr[:] = jnp.zeros_like(dqt_scr)
+
+    def compute(mask):
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0])                   # (block_k, block_q)
+        if mask is not None:
+            pt = jnp.where(mask, pt, 0.0)
+        dv_scr[k_rows] += jax.lax.dot_general(
+            pt.astype(g.dtype), g, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, g, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0]) * scale
+        dk_scr[k_rows] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dqt_scr[qi] += jax.lax.dot_general(
+            k, dst.astype(k.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _for_cell_kind(qi, ki, block_q, block_k, causal, prefix, compute,
+                   keys_first=True)
+
+    # The output maps name one block until it is final (see
+    # ``_flash_bwd_fused``), so each block goes to HBM once.
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = dqt_scr[qi].T.astype(dq_ref.dtype)
+
+    @pl.when((gi == ng - 1) & (qi == nq - 1))
+    def _():
+        dk_ref[0] = dk_scr[k_rows].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[k_rows].astype(dv_ref.dtype)
 
 
 def _pick_block(n: int, preferred: int) -> int:
@@ -631,15 +704,38 @@ def _flash_fwd_res_pallas(q, k, v, causal, block_q, block_k, interpret,
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
 
+# What the fused backward's float32 accumulators may hold of VMEM
+# (:func:`_fused_bwd_bytes`). At or under it :func:`_flash_bwd_fused` runs,
+# over it the split kernels. The train cells' attention takes 6 MiB (cell
+# 1), 12 (nemotron), 10 (LFM2), 3 and 9 (EvaByte's window and summaries
+# passes); 24 MiB is s = t = 16,384 at head_dim 128. The fused call asks for
+# ``_FUSED_VMEM_LIMIT`` of scoped VMEM: beside the accumulators it holds a
+# cell's (block_q, block_k) float32 temporaries and the double-buffered
+# blocks, so the v5e's default 16 MiB refuses the nemotron and LFM2 cells'.
+# The v5e compiler counts about 29.4 MiB used at the budget with 1024 x
+# 1024 blocks.
+_FUSED_BWD_BUDGET = 24 * 2 ** 20
+_FUSED_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _fused_bwd_bytes(s: int, t: int, d: int) -> int:
+    """dqᵀ for the whole query length, (d, s) with query rows on the
+    lanes, and dk, dv for the whole key length, (t, d) with head_dim on
+    the lanes, padded to 128 of them."""
+    return (s * d + 2 * t * (-(-d // 128) * 128)) * 4
+
+
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
                       interpret, prefix=None):
-    """FlashAttention-2 backward: two Pallas passes (dq over key blocks;
-    dk/dv over query blocks), probabilities rebuilt from lse — no O(s²)
-    residuals, float32 accumulation throughout. Grouped (GQA) k/v are
-    handled natively: dq reads each kv head through the group index
-    map, and the dk/dv grid enumerates every (group member, query
-    block) pair so the per-kv-head scratch accumulates the whole
-    group's contributions before one write."""
+    """FlashAttention-2 backward, probabilities rebuilt from lse — no
+    O(s²) residuals, float32 accumulation throughout. One Pallas call
+    (:func:`_flash_bwd_fused`) where its accumulators fit
+    ``_FUSED_BWD_BUDGET``, else two (:func:`_flash_bwd_split`); the
+    path taken adds 1 to ``flash.bwd.fused`` / ``flash.bwd.split`` when
+    tracing is on. Grouped (GQA) k/v are handled natively: the grids
+    walk every query head of a kv head's group, so the per-kv-head
+    scratch accumulates the whole group's contributions before one
+    write."""
     b, s, h, d = q.shape
     t = k.shape[1]
     hk = k.shape[2]
@@ -651,21 +747,88 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     # Same (bh, 1, s) row layout as lse (see _flash_fwd_res_pallas).
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                     -1)[:, None, :]
+    fused = _fused_bwd_bytes(s, t, d) <= _FUSED_BWD_BUDGET
+    trace.count("flash.bwd.fused" if fused else "flash.bwd.split")
+    _count_cells(b * h, s // bq, t // bk, bq, bk, causal, prefix)
+    run = _flash_bwd_fused if fused else _flash_bwd_split
+    dq, dk, dv = run(qf, kf, vf, gf, lse, delta, kv_index, group, h, hk,
+                     bq, bk, causal, _scale(q), interpret, prefix)
+    unflat_q = lambda x: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
+    unflat_kv = lambda x: x.reshape(b, hk, t, d).transpose(0, 2, 1, 3)  # noqa: E731
+    return unflat_q(dq), unflat_kv(dk), unflat_kv(dv)
 
-    common = dict(causal=causal, scale=_scale(q), block_q=bq, block_k=bk,
-                  prefix=prefix)
+
+def _flash_bwd_fused(qf, kf, vf, gf, lse, delta, kv_index, group, h, hk,
+                     bq, bk, causal, scale, interpret, prefix):
+    """:func:`_flash_bwd_kernel` over grid (b·hk, G, nk, nq). k/v stay
+    resident across the query blocks; q, dO, lse and delta are the
+    block :func:`_resident_qi` names, so a dead cell fetches nothing.
+    The output maps hold one block until it is final — dq's at the
+    last key block, dk/dv's in the group's last query head — so no
+    block is written back before its sum is complete."""
+    bhk, t, d = kf.shape
+    s = qf.shape[1]
     nq, nk = s // bq, t // bk
+
+    def q_head(bh, gi):
+        return (bh // hk) * h + (bh % hk) * group + gi
+
+    def q_block(ki, qi):
+        return _resident_qi(qi, ki, nq, bq, bk, causal, prefix)
+
+    qspec = pl.BlockSpec((1, bq, d), lambda bh, gi, ki, qi: (
+        q_head(bh, gi), q_block(ki, qi), 0))
+    rowspec = pl.BlockSpec((1, 1, bq), lambda bh, gi, ki, qi: (
+        q_head(bh, gi), 0, q_block(ki, qi)))
+    kspec = pl.BlockSpec((1, bk, d), lambda bh, gi, ki, qi: (bh, ki, 0))
+    dqspec = pl.BlockSpec((1, bq, d), lambda bh, gi, ki, qi: (
+        q_head(bh, gi), jnp.where(ki == nk - 1, qi, 0), 0))
+    dkspec = pl.BlockSpec((1, bk, d), lambda bh, gi, ki, qi: (
+        bh, jnp.where(gi == group - 1, ki, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, prefix=prefix),
+        grid=(bhk, group, nk, nq),
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=[dqspec, dkspec, dkspec],
+        out_shape=[
+            jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((nq, d, bq), jnp.float32),
+            pltpu.VMEM((t, d), jnp.float32),
+            pltpu.VMEM((t, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+        interpret=interpret,
+        name="flash_bwd" if prefix is None else "eva_remote_bwd",
+    )(qf, kf, vf, gf, lse, delta)
+
+
+def _flash_bwd_split(qf, kf, vf, gf, lse, delta, kv_index, group, h, hk,
+                     bq, bk, causal, scale, interpret, prefix):
+    """Two Pallas passes, each rebuilding the probabilities: dq over key
+    blocks, then dk/dv over query blocks. For a call whose accumulators
+    :func:`_flash_bwd_fused` cannot hold: a long ring-attention chunk, a
+    sequence of 32k and more."""
+    bh_q, s, d = qf.shape
+    bhk, t, _ = kf.shape
+    nq, nk = s // bq, t // bk
+    common = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
+                  prefix=prefix)
     qspec = pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))
     kspec = _kv_spec(bq, bk, d, kv_index, causal, prefix)
     rowspec = pl.BlockSpec((1, 1, bq), lambda bh, qi, ki: (bh, 0, qi))
 
-    _count_cells(b * h, nq, nk, bq, bk, causal, prefix)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(b * h, nq, nk),
+        grid=(bh_q, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq" if prefix is None else "eva_remote_bwd_dq",
@@ -687,15 +850,15 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
     kspec2 = pl.BlockSpec((1, bk, d), lambda bh, ki, gq: (bh, ki, 0))
     rowspec2 = pl.BlockSpec(
         (1, 1, bq), lambda bh, ki, gq: (q_head(bh, gq), 0, q_block(ki, gq)))
-    _count_cells(b * h, nq, nk, bq, bk, causal, prefix)
+    _count_cells(bh_q, nq, nk, bq, bk, causal, prefix)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, nq=nq, **common),
-        grid=(b * hk, nk, group * nq),
+        grid=(bhk, nk, group * nq),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[
-            jax.ShapeDtypeStruct((b * hk, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hk, t, d), v.dtype),
+            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -704,10 +867,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q, block_k,
         interpret=interpret,
         name="flash_bwd_dkv" if prefix is None else "eva_remote_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
-
-    unflat_q = lambda x: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
-    unflat_kv = lambda x: x.reshape(b, hk, t, d).transpose(0, 2, 1, 3)  # noqa: E731
-    return unflat_q(dq), unflat_kv(dk), unflat_kv(dv)
+    return dq, dk, dv
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,

@@ -38,8 +38,10 @@ as :mod:`mpi_tpu.ops.attention`:
 Device ops carry ``jax.named_scope("eva")`` with ``eva.summarize``,
 ``eva.local``, ``eva.remote`` and ``eva.merge`` inside it
 (docs/OBSERVABILITY.md); the summaries' Pallas calls are named
-``eva_remote_fwd`` / ``eva_remote_bwd_dq`` / ``eva_remote_bwd_dkv``, the
-window's keep ``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv``.
+``eva_remote_fwd`` / ``eva_remote_bwd``, the window's keep ``flash_fwd`` /
+``flash_bwd``. At the EvaByte cell's shapes each pass's backward is that one
+fused kernel; over its VMEM budget it would be the split pair,
+``*_bwd_dq`` / ``*_bwd_dkv`` (``ops/attention.py``).
 """
 
 from __future__ import annotations

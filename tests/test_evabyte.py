@@ -151,13 +151,15 @@ def test_window_must_be_whole_chunks():
 
 
 def test_remote_kernels_carry_eva_names():
-    """The summaries' Pallas calls are named for a trace to find them."""
+    """The summaries' Pallas calls are named for a trace to find them:
+    each pass's forward kernel and its one fused backward kernel, and
+    nothing else."""
     x, vec = jnp.zeros((1, 64, 1, 16)), jnp.zeros((1, 16))
     text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(eva_attention(
         q, x, x, vec, vec, 32, 4))))(x))
-    for name in ("eva_remote_fwd", "eva_remote_bwd_dq", "eva_remote_bwd_dkv",
-                 "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert name in text, name
+    names = sorted(re.findall(r"\bname=((?:eva_remote|flash)_\w*)", text))
+    assert names == ["eva_remote_bwd", "eva_remote_fwd", "flash_bwd",
+                     "flash_fwd"], names
 
 
 def test_eva_scopes_in_lowered_program():

@@ -258,14 +258,19 @@ def _products(jaxpr):
     ("eva", ("flash_fwd", "eva_remote_fwd")), ("flash", ("flash_fwd",))])
 def test_remat_runs_forward_kernels_once_a_layer(impl, forward_kernels):
     """The attention op's output and log-sum-exp are held, so the
-    gradient of a block under remat has no second forward kernel."""
+    gradient of a block under remat has no second forward kernel; its
+    backward is one fused kernel a pass (``flash_bwd``, and
+    ``eva_remote_bwd`` for EVA's summaries)."""
     from mpi_tpu.models.transformer import loss_fn
 
     cfg = _remat_cfg(impl, remat=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
     calls = _pallas_calls(jax.make_jaxpr(jax.grad(
         lambda p, t: loss_fn(p, t, cfg)))(params, _tokens()).jaxpr)
-    for name in forward_kernels + ("flash_bwd_dq", "flash_bwd_dkv"):
+    backward_kernels = tuple(name.replace("_fwd", "_bwd")
+                             for name in forward_kernels)
+    assert set(calls) == set(forward_kernels + backward_kernels), dict(calls)
+    for name in forward_kernels + backward_kernels:
         assert calls[name] == cfg.n_layers, (name, dict(calls))
 
 

@@ -65,8 +65,8 @@ def test_flash_grad_matches_dense():
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_bwd_kernel_matches_dense(causal):
-    """The Pallas backward kernels (dq over key blocks, dk/dv over query
-    blocks, probabilities rebuilt from the saved log-sum-exp) agree with
+    """The Pallas backward (one kernel: dq, dk and dv from each cell's
+    probabilities, rebuilt once from the saved log-sum-exp) agrees with
     autodiff through the dense oracle."""
     q, k, v = _qkv(b=2, s=64, h=2, d=16, seed=3)
     g = jax.random.normal(jax.random.PRNGKey(9), q.shape)
@@ -533,8 +533,8 @@ def test_census_of_cell_one_forward(blocks, want, traced):
 @pytest.mark.parametrize("kind", list(_MASKS))
 def test_census_counts_each_kernel_call(kind, traced):
     """64 x 64 at 16 x 16, one head: the forward's call adds the grid's
-    census once, the backward's two kernels once each (causal: 6 dead,
-    4 crossed, 6 whole; the prefix with or without it 10, 3, 3)."""
+    census once, and so does the backward's one fused kernel (causal: 6
+    dead, 4 crossed, 6 whole; the prefix with or without it 10, 3, 3)."""
     want = _tiles_census(_numpy_mask(*_MASKS[kind]), 16, 16)
     assert want == {"causal": (6, 4, 6), "neither": (0, 0, 16)}.get(
         kind, (10, 3, 3))
@@ -546,7 +546,8 @@ def test_census_counts_each_kernel_call(kind, traced):
     lse = jax.ShapeDtypeStruct((1, 1, _S), jnp.float32)
     jax.eval_shape(lambda q, k, v, o, l, g: flash_chunk_bwd(
         q, k, v, o, l, g, causal, 16, 16, prefix=prefix), x, x, x, x, lse, x)
-    assert _cells_counted(traced.counters()) == tuple(3 * n for n in want)
+    assert _cells_counted(traced.counters()) == tuple(2 * n for n in want)
+    assert traced.counters().get("flash.bwd.fused") == 1
 
 
 def test_census_stays_silent_with_tracing_off():
@@ -560,3 +561,115 @@ def test_census_stays_silent_with_tracing_off():
     finally:
         if was:
             trace.enable()
+
+
+# --------------------------------------------------------------------------
+# One fused backward kernel in place of two
+# --------------------------------------------------------------------------
+
+def _bf16_ulps(a, b):
+    """How many bfloat16 steps apart each pair of elements lies: the bit
+    patterns mapped onto one ordered integer line, so that steps across
+    zero count too."""
+    def ordered(x):
+        bits = np.asarray(jax.lax.bitcast_convert_type(
+            jnp.asarray(x, jnp.bfloat16), jnp.int16)).astype(np.int32)
+        return np.where(bits < 0, -32768 - bits, bits)
+    return np.abs(ordered(a) - ordered(b))
+
+
+# (causal, prefix, heads, kv heads, head_dim, block_q, block_k) on 64 x 64
+# scores: every case has dead, crossed and whole cells but "neither".
+_FUSED_CASES = {
+    "causal-g1-d64": (True, None, 2, 2, 64, 16, 8),
+    "causal-g4-d128": (True, None, 4, 1, 128, 8, 16),
+    "causal-g4-d64": (True, None, 8, 2, 64, 16, 16),
+    "neither-g4-d128": (False, None, 4, 1, 128, 16, 8),
+    "neither-g1-d64": (False, None, 2, 2, 64, 16, 16),
+    "prefix-g1-d128": (False, _PREFIX, 2, 2, 128, 16, 8),
+    "prefix-g4-d64": (False, _PREFIX, 4, 1, 64, 8, 16),
+    "causal-prefix-g4-d128": (True, _PREFIX, 4, 1, 128, 16, 16),
+}
+
+
+@pytest.mark.parametrize("case", list(_FUSED_CASES))
+def test_fused_bwd_matches_split_kernels_and_dense(case, monkeypatch,
+                                                   traced):
+    """The fused backward against the two split kernels, in bfloat16 as
+    the train cells run it, and against ``jax.grad`` of materialised
+    attention in float32 at the other flash tests' tolerance.
+
+    The sums run in the split kernels' order (dq by key block; dk, dv
+    g-major, then by query block), but the fused kernel builds a cell's
+    scores keys first, k·qᵀ, and its products from the transposed
+    tiles. XLA's CPU dot, which interpret mode runs, may add a product's
+    terms in another order for transposed operands, so a bfloat16
+    result can round one step apart: held to one bfloat16 ulp. On the
+    v5e the three came out bit for bit at every train cell's shape
+    (PERF.md, PR 46)."""
+    causal, prefix, h, hk, d, bq, bk = _FUSED_CASES[case]
+    key = jax.random.PRNGKey(46)
+    shapes = ((1, _S, h, d), (1, _T, hk, d), (1, _T, hk, d), (1, _S, h, d))
+    q, k, v, g = (jax.random.normal(jax.random.fold_in(key, i), shape)
+                  for i, shape in enumerate(shapes))
+    g = g.at[:, :_PREFIX[0]].set(0.0) if prefix is not None else g
+    mask = jnp.asarray(_numpy_mask(causal, prefix))
+    rep = lambda x: jnp.repeat(x, h // hk, axis=2)  # noqa: E731
+
+    def bwd(q, k, v, g):
+        out, lse = flash_attention_with_lse(q, k, v, causal, bq, bk,
+                                            prefix=prefix)
+        return flash_chunk_bwd(q, k, v, out, lse, g, causal, bq, bk,
+                               prefix=prefix)
+
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v, g)]
+    fused = bwd(*low)
+    dead, crossed, whole = _cells_counted(traced.counters())
+    if causal or prefix is not None:
+        assert min(dead, crossed, whole) > 0
+    assert traced.counters().get("flash.bwd.fused") == 1
+    with monkeypatch.context() as m:   # as a call over the VMEM budget goes
+        m.setattr(attn_mod, "_FUSED_BWD_BUDGET", 0)
+        split = bwd(*low)
+    assert traced.counters().get("flash.bwd.split") == 1
+    for name, a, b in zip(("dq", "dk", "dv"), fused, split):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        assert _bf16_ulps(a, b).max() <= 1, name
+
+    def dense(q, k, v):
+        logits = jnp.einsum("bshk,bthk->bhst", q, rep(k)) * d ** -0.5
+        probs = jax.nn.softmax(
+            jnp.where(mask[None, None], logits, attn_mod.NEG_INF), axis=-1)
+        return jnp.einsum("bhst,bthk->bshk", probs, rep(v))
+
+    got = bwd(q, k, v, g)
+    want = jax.grad(lambda q, k, v: jnp.vdot(dense(q, k, v), g),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, gg, w in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(gg, w, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("seq, heads, kv_heads, path, kernels", [
+    (8192, 32, 2, "fused", ["flash_bwd", "flash_fwd"]),
+    (32768, 2, 1, "split", ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])],
+    ids=["nemotron-cell", "over-the-budget"])
+def test_backward_path_is_picked_from_the_shapes(seq, heads, kv_heads, path,
+                                                 kernels, traced):
+    """The nemotron cell's attention (b 2 x s 8,192, 32 query heads on 2
+    kv heads of 128) wants 4 MiB of dqᵀ and 4 each of dk and dv, under
+    the budget: one ``flash_bwd``. s = t = 32,768 wants 16 + 2 x 16 MiB,
+    over it: the two split kernels. Each counts its path once; traced,
+    nothing runs."""
+    q = jax.ShapeDtypeStruct((2, seq, heads, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, seq, kv_heads, 128), jnp.bfloat16)
+    fits = attn_mod._fused_bwd_bytes(seq, seq, 128) <= \
+        attn_mod._FUSED_BWD_BUDGET
+    assert fits == (path == "fused")
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, True)
+                                .astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, kv, kv)
+    assert sorted(eqn.params["name"] for eqn in jaxpr.jaxpr.eqns
+                  if eqn.primitive.name == "pallas_call") == kernels
+    assert {k: v for k, v in traced.counters().items()
+            if k.startswith("flash.bwd.")} == {f"flash.bwd.{path}": 1}

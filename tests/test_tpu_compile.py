@@ -76,14 +76,30 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(attention, "_should_interpret", lambda: False)
 
 
-FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+def _kernel(name):
+    """A Pallas call ``name=``d so, as an instruction of the compiled text:
+    ``%flash_bwd.1`` in a plain jit, ``%transpose_jvp_flash_bwd__.1`` in a
+    gradient; not ``%..._flash_bwd_dq__.1``, and not the Python frames
+    (``_flash_bwd_kernel``) the text may list."""
+    return re.compile(rf"%(?:\w+_)?{name}_*(?:\.\d+)? = ")
+
+
+# A flash gradient at every shape this file compiles runs the forward
+# kernel and ONE backward kernel, ``flash_bwd``; the split kernels run only
+# over the fused kernel's VMEM budget, and ``_compile`` holds every program
+# here to their absence.
+FLASH_KERNELS = (_kernel("flash_fwd"), _kernel("flash_bwd"))
+SPLIT_KERNELS = tuple(_kernel(name) for name in (
+    "flash_bwd_dq", "flash_bwd_dkv", "eva_remote_bwd_dq",
+    "eva_remote_bwd_dkv"))
 LAYER_SCOPES = ("(attn)/", "(ffn)/", "(logits_loss)/", "/optimizer/")
 
 
-def _compile(fn, *args, names=()):
+def _compile(fn, *args, names=(), absent=SPLIT_KERNELS):
     """Compile for the described chip; ``names`` are what a profiler
     trace of the program is read by (kernel names as the instructions'
-    names, layer scopes inside ``op_name``) and must be in its text."""
+    names, layer scopes inside ``op_name``) and must be in its text,
+    ``absent`` what must not be."""
     # A train step is jitted already, with its state donated: wrapped in
     # another jit it would lose the donation and count its state twice.
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
@@ -91,8 +107,13 @@ def _compile(fn, *args, names=()):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, \
         "no Mosaic kernel in the compiled program"
-    missing = [n for n in names if n not in text]
+    def named(n):
+        return n.search(text) if isinstance(n, re.Pattern) else n in text
+
+    missing = [n for n in names if not named(n)]
     assert not missing, f"not named in the compiled program: {missing}"
+    present = [n for n in absent if named(n)]
+    assert not present, f"named in the compiled program: {present}"
     return compiled
 
 
@@ -188,7 +209,7 @@ def test_flagship_train_step_compiles_four_chips(topo, compiled_kernels):
     assert "all-reduce" in compiled.as_text()  # dp grads / tp partials
 
 
-EVA_KERNELS = ("%eva_remote_fwd", "%eva_remote_bwd_dq", "%eva_remote_bwd_dkv")
+EVA_KERNELS = (_kernel("eva_remote_fwd"), _kernel("eva_remote_bwd"))
 
 
 def test_eva_attention_compiles_at_evabyte_shapes(one_chip):
@@ -295,9 +316,9 @@ SSD_KERNELS = ("%ssd_fwd", "%ssd_bwd")
 
 
 @contextlib.contextmanager
-def _scans_counted():
+def _counted(prefix):
     """Tracing on; yields a dict that holds, on leaving, what the
-    ``ssd.scans.*`` counters rose by inside."""
+    counters named ``prefix...`` rose by inside."""
     from mpi_tpu.utils import trace
 
     was, before, rose = trace.enabled(), dict(trace.counters()), {}
@@ -306,7 +327,7 @@ def _scans_counted():
         yield rose
         rose.update({k: v - before.get(k, 0)
                      for k, v in trace.counters().items()
-                     if k.startswith("ssd.scans.") and v != before.get(k, 0)})
+                     if k.startswith(prefix) and v != before.get(k, 0)})
     finally:
         if not was:
             trace.disable()
@@ -329,7 +350,7 @@ def test_ssd_kernels_compile_at_the_nemotron_cell_shapes(one_chip):
     def loss(*inputs):
         return jnp.sum(ssd_scan(*inputs, chunk).astype(jnp.float32))
 
-    with _scans_counted() as took:
+    with _counted("ssd.scans.") as took:
         compiled = _compile(
             jax.grad(loss, argnums=tuple(range(6))),
             shaped((b, s, h, p), jnp.bfloat16), shaped((b, s, h), jnp.float32),
@@ -413,7 +434,7 @@ def test_nemotron_cell_train_step_fits_with_room_to_spare(
                                    max_seq=seq + 1))
     assert cfg.remat and cfg.layer_pattern == "MEMEM*EME"
     mesh = make_mesh_nd(1, devices=topo.devices[:1])
-    with _scans_counted() as took:
+    with _counted("ssd.scans.") as took:
         compiled = _compile(
             *_step_args(cfg, mesh, batch, seq),
             names=FLASH_KERNELS + SSD_KERNELS + LAYER_SCOPES + (
@@ -470,6 +491,68 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
                               sharding=one_chip)
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
              names=FLASH_KERNELS)
+
+
+# The attention of the four train cells, as the backward is called:
+# (q, k/v shape, causal, blocks, prefix, kernel). EvaByte's window pass has
+# the 8 windows of 2,048 folded into the batch; its summaries pass reads
+# the 1,024 summaries of 16,384 rows under the prefix mask
+# (``ops/eva_attention.py`` ``_LOCAL_BLOCKS`` / ``_REMOTE_BLOCKS``).
+_TRAIN_ATTENTION = {
+    "starcoder2-cell": ((2, 4096, 24, 128), (2, 4096, 2, 128), True,
+                        (None, None), None, _kernel("flash_bwd")),
+    "nemotron-cell": ((2, 8192, 32, 128), (2, 8192, 2, 128), True,
+                      (None, None), None, _kernel("flash_bwd")),
+    "lfm2-cell": ((2, 8192, 32, 64), (2, 8192, 8, 64), True,
+                  (None, None), None, _kernel("flash_bwd")),
+    "evabyte-window": ((8, 2048, 32, 128), (8, 2048, 32, 128), True,
+                       (1024, 1024), None, _kernel("flash_bwd")),
+    "evabyte-summaries": ((1, 16384, 32, 128), (1, 1024, 32, 128), False,
+                          (1024, 512), (2048, 128), _kernel("eva_remote_bwd")),
+}
+
+
+@pytest.mark.parametrize("cell", list(_TRAIN_ATTENTION))
+def test_fused_flash_bwd_compiles_at_the_train_cells_shapes(cell, one_chip):
+    """The backward of every train cell's attention is the one fused
+    kernel on the described v5e: it compiles with the VMEM it asks for,
+    the split kernels are not in the program, and the call counts
+    ``flash.bwd.fused``."""
+    from mpi_tpu.ops.attention import flash_chunk_bwd
+
+    q_shape, kv_shape, causal, blocks, prefix, kernel = _TRAIN_ATTENTION[cell]
+    b, s, h, _ = q_shape
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = shaped(q_shape), shaped(kv_shape)
+    with _counted("flash.bwd.") as took:
+        _compile(lambda q, k, v, o, lse, g: flash_chunk_bwd(
+            q, k, v, o, lse, g, causal, *blocks, interpret=False,
+            prefix=prefix), q, kv, kv, q, shaped((b, h, s), jnp.float32), q,
+            names=[kernel])
+    assert took == {"flash.bwd.fused": 1}
+
+
+def test_split_flash_bwd_compiles_over_the_budget(one_chip):
+    """Over the fused kernel's budget (s = t = 32,768 at head_dim 128: 48
+    MiB of accumulators) the two split kernels run, and compile."""
+    from mpi_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, None, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((1, 32768, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 32768, 1, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    with _counted("flash.bwd.") as took:
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                 names=(FLASH_KERNELS[0],) + SPLIT_KERNELS[:2],
+                 absent=FLASH_KERNELS[1:])
+    assert took == {"flash.bwd.split": 1}
 
 
 # What the compiler counts for the lfm2-24b-a2b cell's step: 12.448 GiB at

@@ -13,6 +13,7 @@ trace is taken once, in a module-scoped fixture.
 """
 
 import functools
+import re
 import subprocess
 import sys
 import time
@@ -299,5 +300,5 @@ def test_flash_kernels_are_named_in_the_jaxpr():
         return jnp.sum(flash_attention(q, k, v, True))
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert name in text, name
+    names = sorted(re.findall(r"\bname=(flash\w*)", text))
+    assert names == ["flash_bwd", "flash_fwd"], names
