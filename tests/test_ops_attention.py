@@ -606,7 +606,7 @@ def test_fused_bwd_matches_split_kernels_and_dense(case, monkeypatch,
     terms in another order for transposed operands, so a bfloat16
     result can round one step apart: held to one bfloat16 ulp. On the
     v5e the three came out bit for bit at every train cell's shape
-    (PERF.md, PR 46)."""
+    (PERF.md, Findings)."""
     causal, prefix, h, hk, d, bq, bk = _FUSED_CASES[case]
     key = jax.random.PRNGKey(46)
     shapes = ((1, _S, h, d), (1, _T, hk, d), (1, _T, hk, d), (1, _S, h, d))
